@@ -251,8 +251,6 @@ def make_round_fn(model_cfg, dist_cfg: DistConfig, mesh: Mesh,
             lag = None
 
         # 2-3. sample + filter, client-parallel over the data axis.
-        from jax.experimental.shard_map import shard_map
-
         def one_client(local_shard, tokens_shard, mask_shard, key_shard,
                        alive_shard, snapshot_rep, tables_rep, stale_rep,
                        lag_shard):
@@ -277,12 +275,12 @@ def make_round_fn(model_cfg, dist_cfg: DistConfig, mesh: Mesh,
 
         spec_local = jax.tree.map(lambda _: P(data_axis), local)
         lag_spec = None if lag is None else {n: P(data_axis) for n in lag}
-        fn = shard_map(
+        fn = jax.shard_map(
             one_client, mesh=mesh,
             in_specs=(spec_local, P(data_axis, None), P(data_axis, None),
                       P(data_axis), P(data_axis), P(), P(), P(), lag_spec),
             out_specs=(spec_local, P(), lag_spec),
-            check_rep=False,
+            check_vma=False,
         )
         keys = jax.random.split(key, n_clients)
         local2, summed, lag = fn(local, tokens, mask, keys, alive, snapshot,
@@ -349,8 +347,6 @@ def make_round_fn(model_cfg, dist_cfg: DistConfig, mesh: Mesh,
 def _project_alg2(stats, rules, aggregates, mesh, model_axis, row_specs):
     """Algorithm 2: rows partitioned over the model axis, projected locally,
     aggregates re-derived with a psum."""
-    from jax.experimental.shard_map import shard_map
-
     agg_outs = {a.out for a in aggregates}
     elem = {n: v for n, v in stats.items() if n not in agg_outs}
 
@@ -368,8 +364,8 @@ def _project_alg2(stats, rules, aggregates, mesh, model_axis, row_specs):
             out[a.out] = jax.lax.psum(out[a.src].sum(a.axis), model_axis)
         return out
 
-    fn = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     result = fn(elem)
     # Preserve non-projected passthrough stats (e.g. theta0).
     for n, v in stats.items():

@@ -246,6 +246,12 @@ class ModelFamily:
             cfg.vocab_size, self.n_outcomes(cfg),
             tile_k=self.sorted_tile_k(cfg))
 
+    def sorted_tile_b(self, cfg) -> int:
+        """The batch tile of the sorted layout and its kernels: the
+        config's, or sized from E and the VMEM budget
+        (``segment.pick_tile_b``) — hoisted layouts MUST use it too."""
+        return cfg.tile_b or segment.pick_tile_b(self.n_outcomes(cfg))
+
     def sorted_tile_k(self, cfg) -> int | None:
         """K-tile size for the fused kernels' staging axis (None = full
         K).  Layout geometry does not depend on it, only kernel VMEM."""
@@ -262,7 +268,7 @@ class ModelFamily:
         return segment.build_chunked_layouts(
             tokens, mask, cfg.vocab_size,
             bounds=segment.chunk_bounds(l, n_chunks),
-            tile_v=self.sorted_tile_v(cfg), tile_b=cfg.tile_b)
+            tile_v=self.sorted_tile_v(cfg), tile_b=self.sorted_tile_b(cfg))
 
     # per-family hooks for the generic chunked sweep ----------------------
     def encode(self, cfg, local) -> Array:
@@ -276,12 +282,14 @@ class ModelFamily:
     def sorted_chunk(self, cfg, shared, tables, stale: Array,
                      lay: segment.SortedLayout, e_sorted: Array,
                      ndk_rows: Array, key: Array, tile_v: int, tile_b: int,
-                     uniforms: tuple[Array, ...] | None = None) -> Array:
+                     uniforms: tuple[Array, ...] | None = None,
+                     fold_in: bool = False) -> Array:
         """Run the family's fused kernel over one sorted chunk.
 
         ``uniforms`` (optional) overrides the chain's internal uniform
         draw with caller-supplied ``(slot, coin, u_mix, u_sparse, u_acc)``
         streams in sorted-stream order — see ``ops.mhw_sweep_sorted``.
+        ``fold_in``: the documents are not counted in ``shared``.
         """
         raise NotImplementedError
 
@@ -294,7 +302,8 @@ class ModelFamily:
     def sweep_sorted(self, cfg, local, shared, tables, stale: Array,
                      tokens: Array, mask: Array, key: Array,
                      layouts: tuple[segment.SortedLayout, ...] | None,
-                     chunk_uniforms=None) -> tuple[Any, dict[str, Array]]:
+                     chunk_uniforms=None, fold_in: bool = False
+                     ) -> tuple[Any, dict[str, Array]]:
         """Token-sorted MHW sweep: fused tile-skipping chains per shard.
 
         The sweep runs as ``cfg.sorted_chunks`` sequential position-chunks.
@@ -311,6 +320,10 @@ class ModelFamily:
         :meth:`sorted_chunk`; the serving engine supplies per-request
         streams here so each document's chain is independent of its
         batch-mates (DESIGN.md §14).
+
+        ``fold_in=True`` is serving: the documents are folded into frozen
+        statistics that do not count them, so the ^{-di} removal applies
+        to their doc rows only, never to ``shared``.
         """
         d, l = tokens.shape
         tile_v = self.sorted_tile_v(cfg)
@@ -328,7 +341,7 @@ class ModelFamily:
             s, e = bounds[c], bounds[c + 1]
             tok_c, mask_c = tokens[:, s:e], mask[:, s:e]
             bc = d * (e - s)
-            tile_b = min(cfg.tile_b, bc)
+            tile_b = min(self.sorted_tile_b(cfg), bc)
             lay = layouts[c] if layouts is not None else segment.build_layout(
                 tok_c, mask_c, cfg.vocab_size, tile_v=tile_v, tile_b=tile_b)
 
@@ -358,7 +371,8 @@ class ModelFamily:
                         if chunk_uniforms is not None else None)
             e_new_s = self.sorted_chunk(cfg, shared, tables, stale, lay,
                                         e_s, ndk, jax.random.fold_in(key, c),
-                                        tile_v, tile_b, uniforms=uniforms)
+                                        tile_v, tile_b, uniforms=uniforms,
+                                        fold_in=fold_in)
 
             e_new_flat = segment.unsort_values(lay, e_new_s, e_flat)
             e_new_c = jnp.where(mask_c, e_new_flat.reshape(d, e - s), e_c)
@@ -405,14 +419,15 @@ class _LMFamilyBase(ModelFamily):
         return local.z
 
     def sorted_chunk(self, cfg, shared, tables, stale, lay, e_sorted,
-                     ndk_rows, key, tile_v, tile_b, uniforms=None) -> Array:
+                     ndk_rows, key, tile_v, tile_b, uniforms=None,
+                     fold_in=False) -> Array:
         return ops.mhw_sweep_sorted(
             tables, stale, shared.n_wk, shared.n_k,
             self.sparse_prior(cfg, shared), lay.rows, e_sorted, ndk_rows,
             lay.vstart, lay.vcount, key, mh_steps=cfg.mh_steps,
             beta=cfg.beta, beta_bar=cfg.beta * cfg.vocab_size,
             tile_v=tile_v, tile_b=tile_b, tile_k=self.sorted_tile_k(cfg),
-            uniforms=uniforms)
+            uniforms=uniforms, fold_in=fold_in)
 
     def _delta_wk(self, cfg, tokens, mask, z_old, z_new) -> Array:
         w_flat = tokens.reshape(-1)
@@ -612,7 +627,8 @@ class PDPFamily(ModelFamily):
         return e % cfg.n_topics
 
     def sorted_chunk(self, cfg, shared, tables, stale, lay, e_sorted,
-                     ndk_rows, key, tile_v, tile_b, uniforms=None) -> Array:
+                     ndk_rows, key, tile_v, tile_b, uniforms=None,
+                     fold_in=False) -> Array:
         stirl = stirling.as_jax(cfg.stirling_n_max, cfg.discount)
         return ops.pdp_sweep_sorted(
             tables, stale, shared.m_wk, shared.s_wk, shared.m_k, shared.s_k,
@@ -622,7 +638,7 @@ class PDPFamily(ModelFamily):
             concentration=cfg.concentration, discount=cfg.discount,
             gamma=cfg.gamma, gamma_bar=cfg.gamma * cfg.vocab_size,
             tile_v=tile_v, tile_b=tile_b, tile_k=self.sorted_tile_k(cfg),
-            uniforms=uniforms)
+            uniforms=uniforms, fold_in=fold_in)
 
     def finalize_sorted(self, cfg, local, e_grid, n_dk, tokens, mask):
         z_new = e_grid % cfg.n_topics

@@ -46,7 +46,7 @@ class HDPConfig:
     # the knob semantics).
     alias_refresh_every: int = 1
     tile_v: int | None = None
-    tile_b: int = 1024
+    tile_b: int | None = None
     tile_k: int | None = None
     sorted_chunks: int = 4
 

@@ -65,9 +65,10 @@ class LDAConfig:
     # tiles from a VMEM budget (segment.pick_tile_vmem) — small models fit
     # in one tile, production vocabularies tile down and skip.  tile_b
     # trades skip granularity against grid size: smaller batch tiles span
-    # fewer vocab tiles (more programs skipped) but launch more programs.
+    # fewer vocab tiles (more programs skipped) but launch more programs;
+    # None sizes it from K and the VMEM budget (segment.pick_tile_b).
     tile_v: int | None = None
-    tile_b: int = 1024
+    tile_b: int | None = None
     # K-tile size for the staging axis of the fused kernels (None = full
     # K, the untiled path).  Must divide K.  With it set, table VMEM
     # residency is (tile_v, tile_k) and the budget-derived tile_v stops

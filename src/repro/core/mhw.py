@@ -36,6 +36,7 @@ Two layouts are supported (DESIGN.md §5):
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, NamedTuple
 
 import jax
@@ -154,20 +155,41 @@ _EPS = 1e-30
 
 
 def _gather_k(mat: Array, idx: Array) -> Array:
-    """mat: (B, E), idx: (B,) int → (B,) mat[b, idx[b]]."""
-    return jnp.take_along_axis(mat, idx[:, None].astype(jnp.int32),
-                               axis=1)[:, 0]
+    """mat: (B, E) or (1, E), idx: (B,) int → (B,) mat[b, idx[b]].
+
+    Written as a one-hot select-and-sum over the E lanes — the form Mosaic
+    lowers inside the fused kernels — and exact, because exactly one term
+    of each row's sum is non-zero."""
+    e = mat.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], e), 1)
+    hit = lane == idx.astype(jnp.int32)[:, None]
+    return jnp.sum(jnp.where(hit, mat, jnp.zeros((), mat.dtype)), axis=-1)
+
+
+def cumsum_lanes(x: Array) -> Array:
+    """Inclusive prefix sum over the last axis by log-step doubling
+    (Hillis-Steele), built from lane rolls — the kernels and the oracles
+    share it, so both add in the same order (Mosaic has no cumsum)."""
+    e = x.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    shift = 1
+    while shift < e:
+        x = x + jnp.where(lane >= shift, jnp.roll(x, shift, axis=-1),
+                          jnp.zeros((), x.dtype))
+        shift *= 2
+    return x
 
 
 def doc_sparse_logp(doc: Array, prior: Array, outcome: Array) -> Array:
     """log of the document-sparse target factor log(doc_e + prior_e) at
-    ``outcome``: doc (B, E), prior (E,), outcome (B,) → (B,).
+    ``outcome``: doc (B, E), prior (E,) or (1, E), outcome (B,) → (B,).
 
     THE single implementation — :func:`mix_chain` (and through it every
     oracle and fused kernel) and the ``ModelFamily.doc_sparse_logp``
     protocol hook all resolve here, so the target math cannot fork.
     """
-    return jnp.log(_gather_k(doc, outcome) + prior[outcome] + _EPS)
+    return jnp.log(_gather_k(doc, outcome)
+                   + _gather_k(prior.reshape(1, -1), outcome) + _EPS)
 
 
 def mix_chain(z0: Array, *, doc: Array, prior: Array, logf: Array,
@@ -186,14 +208,18 @@ def mix_chain(z0: Array, *, doc: Array, prior: Array, logf: Array,
     z0: (B,) chain init over outcomes.
     doc/logf/sparse_w/stale_rows/prob_rows/alias_rows: (B, E) per-token rows
       (own-token ^{-di} removal already applied by the caller).
-    prior: (E,) per-outcome prior mass (α·1 for LDA/PDP, b1·θ0 for HDP).
+    prior: (E,) or (1, E) per-outcome prior mass (α·1 for LDA/PDP, b1·θ0
+      for HDP).
     dense_mass: (B,) stale dense-term mass per token's row.
     slot/coin/u_mix/u_sparse/u_acc: (S, B) per-step uniforms (slot int32 in
       [0, E)).  Returns (B,) int32 final states.
     """
     e_outcomes = doc.shape[-1]
-    cdf = jnp.cumsum(sparse_w, axis=-1)
-    sparse_mass = cdf[:, -1]
+    cdf = cumsum_lanes(sparse_w)
+    # Static slices throughout (lax.index_in_dim): Mosaic lowers those,
+    # not the dynamic_slice that negative or array indexing emits.
+    sparse_mass = jax.lax.index_in_dim(cdf, e_outcomes - 1, 1, keepdims=False)
+    step = functools.partial(jax.lax.index_in_dim, axis=0, keepdims=False)
 
     def log_p(t):
         return doc_sparse_logp(doc, prior, t) + _gather_k(logf, t)
@@ -206,18 +232,19 @@ def mix_chain(z0: Array, *, doc: Array, prior: Array, logf: Array,
     lp_z = log_p(z)
     lq_z = log_q(z)
     for s in range(slot.shape[0]):
-        slot_s = slot[s]
-        dense_draw = jnp.where(coin[s] < _gather_k(prob_rows, slot_s), slot_s,
-                               _gather_k(alias_rows, slot_s))
-        target = u_sparse[s] * sparse_mass
+        slot_s = step(slot, s)
+        dense_draw = jnp.where(step(coin, s) < _gather_k(prob_rows, slot_s),
+                               slot_s, _gather_k(alias_rows, slot_s))
+        target = step(u_sparse, s) * sparse_mass
         sparse_draw = jnp.clip(
             jnp.sum((cdf <= target[:, None]).astype(jnp.int32), axis=-1),
             0, e_outcomes - 1)
-        pick_sparse = u_mix[s] * (sparse_mass + dense_mass) < sparse_mass
+        pick_sparse = (step(u_mix, s) * (sparse_mass + dense_mass)
+                       < sparse_mass)
         cand = jnp.where(pick_sparse, sparse_draw, dense_draw).astype(jnp.int32)
         lp_c = log_p(cand)
         lq_c = log_q(cand)
-        accept = (jnp.log(u_acc[s] + _EPS)
+        accept = (jnp.log(step(u_acc, s) + _EPS)
                   < accept_log_ratio(lp_c, lp_z, lq_z, lq_c))
         z = jnp.where(accept, cand, z)
         lp_z = jnp.where(accept, lp_c, lp_z)

@@ -57,7 +57,7 @@ class PDPConfig:
     # the knob semantics; tiles here cover the 2K joint outcome space).
     alias_refresh_every: int = 1
     tile_v: int | None = None
-    tile_b: int = 1024
+    tile_b: int | None = None
     tile_k: int | None = None
     sorted_chunks: int = 4
 
@@ -150,7 +150,8 @@ def own_contrib(k_topics: int, e0: Array, real: Array
     z0 = e0 % k_topics
     r0 = e0 // k_topics
     karange = jax.lax.broadcasted_iota(jnp.int32, (1, k_topics), 1)
-    own_t = ((karange == z0[:, None]) & real[:, None]).astype(jnp.float32)
+    own_t = ((karange == z0[:, None]).astype(jnp.float32)
+             * real.astype(jnp.float32)[:, None])
     own_r = own_t * (r0[:, None] > 0).astype(jnp.float32)
     return own_t, own_r
 
@@ -165,6 +166,40 @@ def corrected_rows(m_row_raw: Array, s_row_raw: Array, own_t: Array,
     s_row = jnp.where(m_row > 0, jnp.maximum(s_row, 1.0), 0.0)
     s_row = jnp.minimum(s_row, m_row)
     return m_row, s_row
+
+
+def fresh_log_factors(table: Array, m_wk: Array, s_wk: Array, m_k: Array,
+                      s_k: Array, *, b: float, a: float, gamma: float,
+                      gamma_bar: float) -> Array:
+    """(V, 2K) fresh log factors of every token-type row with no token
+    removed — the values :func:`sorted_chain_pdp` computes for every
+    column except a token's own topic (subtracting a zero own
+    contribution leaves every operand bit-identical)."""
+    m_row, s_row = corrected_rows(m_wk, s_wk, 0.0, 0.0)
+    log_f0, log_f1 = log_factors(table, m_row, s_row, m_k[None, :],
+                                 s_k[None, :], b=b, a=a, gamma=gamma,
+                                 gamma_bar=gamma_bar)
+    return jnp.concatenate([log_f0, log_f1], axis=-1)
+
+
+def own_log_factors(table: Array, m_wk: Array, s_wk: Array, m_k: Array,
+                    s_k: Array, rows: Array, e0: Array, *, b: float,
+                    a: float, gamma: float, gamma_bar: float
+                    ) -> tuple[Array, Array]:
+    """Each sorted token's own-topic log factors (r = 0, r = 1) after the
+    ^{-di} removal: (B,) each — the own-topic columns of
+    :func:`sorted_chain_pdp`, evaluated per token (rows ≥ V are padding
+    and remove nothing)."""
+    v, k_topics = m_wk.shape
+    real = (rows < v).astype(jnp.float32)
+    r = jnp.clip(rows, 0, v - 1)
+    z0 = e0 % k_topics
+    own_t = real
+    own_r = own_t * ((e0 // k_topics) > 0).astype(jnp.float32)
+    m_row, s_row = corrected_rows(m_wk[r, z0], s_wk[r, z0], own_t, own_r)
+    return log_factors(table, m_row, s_row, m_k[z0] - own_t,
+                       s_k[z0] - own_r, b=b, a=a, gamma=gamma,
+                       gamma_bar=gamma_bar)
 
 
 def dense_probs(cfg: PDPConfig, shared: SharedStats) -> Array:

@@ -135,8 +135,6 @@ def project_distributed(
     re-derived with a ``psum`` over the shards, which is the SendUpdate of
     Algorithm 1 expressed as a collective.
     """
-    from jax.experimental.shard_map import shard_map
-
     elementwise = {k: v for k, v in stats.items()
                    if not any(a.out == k for a in aggregates)}
     agg_names = [a.out for a in aggregates]
@@ -155,8 +153,8 @@ def project_distributed(
             out[agg.out] = jax.lax.psum(partial_sum, shard_axis)
         return out
 
-    fn = shard_map(local_project, mesh=mesh,
-                   in_specs=(in_specs,), out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(local_project, mesh=mesh, in_specs=(in_specs,),
+                       out_specs=out_specs, check_vma=False)
     result = fn(elementwise)
     return result
 

@@ -96,6 +96,17 @@ def pick_tile_vmem(v: int, k: int, budget_elems: int = 65536,
     return pick_tile(v, max(1, budget_elems // max(cols, 1)))
 
 
+def pick_tile_b(e: int, budget_elems: int = 1 << 18, cap: int = 1024
+                ) -> int:
+    """Batch tile of the fused sorted kernels from their per-token VMEM
+    state: every staged table row and every chain temporary is a
+    (tile_b, E) float32 array, so tile_b·E is held near ``budget_elems``
+    (1 MiB per array at the default) — 1024 up to E=256, 256 at E=1024.
+    Always a multiple of 128, the lane width of the kernels' (1, tile_b)
+    per-token blocks."""
+    return max(128, min(cap, budget_elems // max(e, 1) // 128 * 128))
+
+
 @partial(jax.jit, static_argnames=("vocab_size", "tile_v", "tile_b"))
 def build_layout(tokens: Array, mask: Array, vocab_size: int, *,
                  tile_v: int, tile_b: int) -> SortedLayout:

@@ -262,6 +262,15 @@ def _jitted_round(donate: bool):
                    donate_argnums=donate_argnums)
 
 
+def lower_round(server, model_cfg, rcfg, incremental, *args):
+    """The round program :func:`trainer_round` would dispatch for these
+    arguments, lowered but not run (``jax.stages.Lowered``): for
+    ``compile().memory_analysis()`` and HLO inspection."""
+    donate = jax.default_backend() != "cpu"
+    return _jitted_round(donate).lower(server, model_cfg, rcfg,
+                                       bool(incremental), *args)
+
+
 def trainer_round(server, model_cfg, rcfg, incremental, *args):
     """Dispatch one compiled sync round (see :func:`_round_impl` for the
     argument contract).  ``server`` is the static

@@ -641,15 +641,30 @@ class Trainer:
         do_project = bool(tcfg.project_every
                           and r % tcfg.project_every == 0)
         locals2, self.pstate, residuals2 = round_mod.trainer_round(
-            self.server, self.cfg, self._rcfg, self._incremental,
-            self.pstate, tuple(self.locals_), tuple(self.residuals),
-            tuple(t for t, _ in self.shards),
-            tuple(m for _, m in self.shards),
-            self.layouts, self.key, np.int32(r), rf.alive_mask,
-            rf.push_mask, np.bool_(do_project), np.bool_(do_refresh))
+            *self._round_args(rf.alive_mask, rf.push_mask, do_project,
+                              do_refresh))
         self.locals_ = list(locals2)
         self.residuals = list(residuals2)
         self.round_idx += 1
+
+    def _round_args(self, alive, push_ok, do_project, do_refresh) -> tuple:
+        return (self.server, self.cfg, self._rcfg, self._incremental,
+                self.pstate, tuple(self.locals_), tuple(self.residuals),
+                tuple(t for t, _ in self.shards),
+                tuple(m for _, m in self.shards),
+                self.layouts, self.key, np.int32(self.round_idx), alive,
+                push_ok, np.bool_(do_project), np.bool_(do_refresh))
+
+    def lower_round(self):
+        """The compiled round program ``step`` dispatches, lowered for the
+        current state and not run (``jax.stages.Lowered``) — for
+        ``compile().memory_analysis()`` and HLO inspection.  Needs the
+        alias proposal, so call it after the first ``step``."""
+        if self.remote is not None or not self.tcfg.compiled:
+            raise ValueError("only the in-process compiled round lowers")
+        everyone = np.ones(self.tcfg.n_clients, bool)
+        return round_mod.lower_round(
+            *self._round_args(everyone, everyone, True, True))
 
     def _refresh_alias_tcp(self, refreshed: bool) -> None:
         """Alias maintenance at the client edge of the wire: the proposal

@@ -20,14 +20,15 @@ fused vs. materialize-then-build).
 Incremental rebuilds (the delta-driven producer of the paper's §5.1
 producer/consumer design) use the *rows* variants: only the token-type rows
 whose pushed delta mass drifted are rebuilt — :func:`alias_build_rows` over
-a compacted (R, E) block, and :func:`alias_build_gather_fused`, whose
-scalar-prefetched row indices drive the input index map so the gather, the
-dense-term computation and the table build fuse into one kernel (cost
-scales with R changed rows, not V).
+a compacted (R, E) block, and :func:`alias_build_gather_fused`, which
+gathers the R changed statistics rows and fuses the dense-term computation
+with the table build (cost scales with R changed rows, not V).
 
 Validated against ``repro.kernels.ref`` in interpret mode (CPU); the block
 shapes keep the working set ≤ a few MB of VMEM for production sizes
-(TILE_R=8, K≤4096 → ~1.5 MB including table state).
+(TILE_R=8, K≤4096 → ~1.5 MB including table state).  The pairing loop is
+O(K) lane operations per slot, so a row costs O(K²) — the price of
+lowering without sort or scatter; incremental rebuilds touch few rows.
 """
 
 from __future__ import annotations
@@ -39,6 +40,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import backend
+
 DEFAULT_TILE_R = 8
 
 
@@ -47,82 +50,85 @@ def _build_tile(scaled: jax.Array) -> tuple[jax.Array, jax.Array]:
 
     ``scaled`` is the K-normalized distribution × K (mean 1.0 per row).
     Returns (prob, alias) of shapes (R, K) float32 / int32.
+
+    Bit-identical to the stack machine of ``core.alias._build_one`` without
+    materializing its stacks (no sort, no scatter — forms Mosaic cannot
+    lower).  In that machine the initial smalls leave in ascending index
+    order and the initial larges in descending order, and a large that
+    drops below 1 is pushed onto the small stack and is popped next.  So
+    the state is two membership masks plus a "pending" demoted large, and
+    each pop is a masked min/max over the K lanes; each table write is a
+    one-hot select.
     """
     r, k = scaled.shape
-    idx = jnp.arange(k, dtype=jnp.int32)
-    rows = jnp.arange(r)
+    idx = jax.lax.broadcasted_iota(jnp.int32, (r, k), 1)
+    small = scaled < 1.0
+    none = jnp.full((r, 1), -1, jnp.int32)
 
-    is_small = scaled < 1.0
-    order = jnp.argsort(is_small, axis=-1)            # larges first
-    stack = jnp.broadcast_to(idx, (r, k))
-    stack = jnp.take_along_axis(stack, order, axis=-1).astype(jnp.int32)
-    n_small = jnp.sum(is_small, axis=-1).astype(jnp.int32)   # (R,)
-    n_large = (k - n_small).astype(jnp.int32)
-    large_top = n_large - 1
-    small_top = k - n_small
-
-    prob = jnp.ones((r, k), jnp.float32)
-    alias = jnp.broadcast_to(idx, (r, k)).astype(jnp.int32)
-    assigned = jnp.zeros((r, k), jnp.bool_)
+    def lane_sum(x):
+        return jnp.sum(x, axis=-1, keepdims=True)
 
     def body(_, carry):
-        prob, alias, assigned, scaled, stack, large_top, small_top, n_small, n_large = carry
-        active = (n_small > 0) & (n_large > 0)        # (R,)
+        # (Membership masks ride the loop as int32: Mosaic cannot carry
+        # bool vectors through a loop.)
+        prob, alias, scaled, small_left, large_left, pending, n_small, \
+            n_large = carry
+        active = (n_small > 0) & (n_large > 0)          # (R, 1)
+        has_pending = pending >= 0
+        first_small = jnp.min(jnp.where(small_left > 0, idx, k), axis=-1,
+                              keepdims=True)
+        i = jnp.where(has_pending, pending, first_small)
+        j = jnp.max(jnp.where(large_left > 0, idx, -1), axis=-1,
+                    keepdims=True)
+        at_i = idx == i
+        at_j = idx == j
+        si = lane_sum(jnp.where(at_i, scaled, 0.0))
+        sj = lane_sum(jnp.where(at_j, scaled, 0.0)) - (1.0 - si)
+        j_small = sj < 1.0
 
-        i = stack[rows, jnp.clip(small_top, 0, k - 1)]
-        j = stack[rows, jnp.clip(large_top, 0, k - 1)]
+        write_i = active & at_i
+        prob = jnp.where(write_i, si, prob)
+        alias = jnp.where(write_i, j, alias)
+        scaled = jnp.where(active & at_j, sj, scaled)
+        small_left = jnp.where(write_i & ~has_pending, 0, small_left)
+        large_left = jnp.where(active & j_small & at_j, 0, large_left)
+        pending = jnp.where(active, jnp.where(j_small, j, -1), pending)
+        n_small = jnp.where(active & ~j_small, n_small - 1, n_small)
+        n_large = jnp.where(active & j_small, n_large - 1, n_large)
+        return (prob, alias, scaled, small_left, large_left, pending,
+                n_small, n_large)
 
-        si = scaled[rows, i]
-        prob = jnp.where(active[:, None],
-                         prob.at[rows, i].set(si), prob)
-        alias = jnp.where(active[:, None],
-                          alias.at[rows, i].set(j), alias)
-        assigned = jnp.where(active[:, None],
-                             assigned.at[rows, i].set(True), assigned)
-        sj = scaled[rows, j] - (1.0 - si)
-        scaled = jnp.where(active[:, None],
-                           scaled.at[rows, j].set(sj), scaled)
-
-        j_is_small = sj < 1.0
-        small_top2 = small_top + 1
-        large_top2 = large_top - 1
-        pos = jnp.where(j_is_small, small_top2 - 1, large_top2 + 1)
-        stack = jnp.where(active[:, None],
-                          stack.at[rows, jnp.clip(pos, 0, k - 1)].set(j), stack)
-        small_top3 = jnp.where(active,
-                               jnp.where(j_is_small, small_top2 - 1, small_top2),
-                               small_top)
-        n_small3 = jnp.where(active,
-                             jnp.where(j_is_small, n_small, n_small - 1),
-                             n_small)
-        large_top3 = jnp.where(active,
-                               jnp.where(j_is_small, large_top2, large_top2 + 1),
-                               large_top)
-        n_large3 = jnp.where(active,
-                             jnp.where(j_is_small, n_large - 1, n_large),
-                             n_large)
-        return (prob, alias, assigned, scaled, stack,
-                large_top3, small_top3, n_small3, n_large3)
-
-    init = (prob, alias, assigned, scaled, stack, large_top, small_top,
-            n_small, n_large)
-    prob, alias, assigned, *_ = jax.lax.fori_loop(0, k, body, init)
-    prob = jnp.where(assigned, prob, 1.0)
-    alias = jnp.where(assigned, alias, idx[None, :])
+    small_i = small.astype(jnp.int32)
+    n_small = lane_sum(small_i)
+    init = (jnp.ones((r, k), jnp.float32), idx, scaled, small_i,
+            1 - small_i, none, n_small, k - n_small)
+    prob, alias, *_ = jax.lax.fori_loop(0, k, body, init)
     return prob, alias
+
+
+def _build_rows(p: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """(R, K) unnormalized rows → (prob, alias, mass (R, 1)); all-zero rows
+    fall back to uniform, as in ``core.alias._build_one``."""
+    k = p.shape[-1]
+    mass = jnp.sum(p, axis=-1, keepdims=True)
+    safe = mass > 0
+    pn = jnp.where(safe, p / jnp.where(safe, mass, 1.0),
+                   jnp.full_like(p, 1.0 / k))
+    prob, alias = _build_tile(pn * k)
+    return prob, alias, mass
+
+
+def _flat_mass(outs):
+    """(prob, alias, mass (V, 1)) → (prob, alias, mass (V,)).  Kernels
+    write mass as a (TILE_R, 1) column: Mosaic refuses rank-1 blocks
+    shorter than a lane tile."""
+    prob, alias, mass = outs
+    return prob, alias, mass[:, 0]
 
 
 def _alias_build_kernel(p_ref, prob_ref, alias_ref, mass_ref):
     p = p_ref[...].astype(jnp.float32)                 # (TILE_R, K)
-    k = p.shape[-1]
-    mass = jnp.sum(p, axis=-1)                         # (TILE_R,)
-    safe = mass > 0
-    pn = jnp.where(safe[:, None], p / jnp.where(safe, mass, 1.0)[:, None],
-                   jnp.full_like(p, 1.0 / k))
-    prob, alias = _build_tile(pn * k)
-    prob_ref[...] = prob
-    alias_ref[...] = alias
-    mass_ref[...] = mass.astype(jnp.float32)
+    prob_ref[...], alias_ref[...], mass_ref[...] = _build_rows(p)
 
 
 def _alias_build_fused_kernel(n_wk_ref, n_k_ref, prob_ref, alias_ref,
@@ -131,13 +137,7 @@ def _alias_build_fused_kernel(n_wk_ref, n_k_ref, prob_ref, alias_ref,
     n_wk = n_wk_ref[...].astype(jnp.float32)           # (TILE_R, K)
     n_k = n_k_ref[...].astype(jnp.float32)             # (1, K) broadcast row
     p = alpha * (n_wk + beta) / (n_k + beta_bar)
-    k = p.shape[-1]
-    mass = jnp.sum(p, axis=-1)
-    pn = p / mass[:, None]
-    prob, alias = _build_tile(pn * k)
-    prob_ref[...] = prob
-    alias_ref[...] = alias
-    mass_ref[...] = mass.astype(jnp.float32)
+    prob_ref[...], alias_ref[...], mass_ref[...] = _build_rows(p)
 
 
 def _alias_build_tiled_kernel(p_ref, prob_ref, alias_ref, mass_ref,
@@ -164,17 +164,7 @@ def _alias_build_tiled_kernel(p_ref, prob_ref, alias_ref, mass_ref,
 
     @pl.when((pi == 1) & (ki == 0))
     def _build():
-        p = p_s[...]
-        k = p.shape[-1]
-        mass = jnp.sum(p, axis=-1)
-        safe = mass > 0
-        pn = jnp.where(safe[:, None],
-                       p / jnp.where(safe, mass, 1.0)[:, None],
-                       jnp.full_like(p, 1.0 / k))
-        prob, alias = _build_tile(pn * k)
-        prob_s[...] = prob
-        alias_s[...] = alias
-        mass_ref[...] = mass.astype(jnp.float32)
+        prob_s[...], alias_s[...], mass_ref[...] = _build_rows(p_s[...])
 
     @pl.when(pi == 1)
     def _flush():
@@ -184,7 +174,7 @@ def _alias_build_tiled_kernel(p_ref, prob_ref, alias_ref, mass_ref,
 
 @functools.partial(jax.jit, static_argnames=("tile_r", "tile_k", "interpret"))
 def alias_build(p: jax.Array, *, tile_r: int = DEFAULT_TILE_R,
-                tile_k: int | None = None, interpret: bool = True):
+                tile_k: int | None = None, interpret: bool | None = None):
     """Build alias tables for (V, K) rows. Returns (prob, alias, mass).
 
     ``tile_k`` (None ⇒ K) streams the input and output K dimension in
@@ -196,32 +186,33 @@ def alias_build(p: jax.Array, *, tile_r: int = DEFAULT_TILE_R,
     out_shape = [
         jax.ShapeDtypeStruct((v, k), jnp.float32),
         jax.ShapeDtypeStruct((v, k), jnp.int32),
-        jax.ShapeDtypeStruct((v,), jnp.float32),
+        jax.ShapeDtypeStruct((v, 1), jnp.float32),
     ]
+    interpret = backend.interpret("alias_build", requested=interpret)
     if tile_k is None or tile_k >= k:
-        return pl.pallas_call(
+        return _flat_mass(pl.pallas_call(
             _alias_build_kernel,
             grid=(v // tile_r,),
             in_specs=[pl.BlockSpec((tile_r, k), lambda i: (i, 0))],
             out_specs=[
                 pl.BlockSpec((tile_r, k), lambda i: (i, 0)),
                 pl.BlockSpec((tile_r, k), lambda i: (i, 0)),
-                pl.BlockSpec((tile_r,), lambda i: (i,)),
+                pl.BlockSpec((tile_r, 1), lambda i: (i, 0)),
             ],
             out_shape=out_shape,
             interpret=interpret,
-        )(p)
+        )(p))
     assert k % tile_k == 0, f"K={k} must be a multiple of tile_k={tile_k}"
     nk = k // tile_k
     kernel = functools.partial(_alias_build_tiled_kernel, tile_k=tile_k)
-    return pl.pallas_call(
+    return _flat_mass(pl.pallas_call(
         kernel,
         grid=(v // tile_r, 2, nk),
         in_specs=[pl.BlockSpec((tile_r, tile_k), lambda i, pi, ki: (i, ki))],
         out_specs=[
             pl.BlockSpec((tile_r, tile_k), lambda i, pi, ki: (i, ki)),
             pl.BlockSpec((tile_r, tile_k), lambda i, pi, ki: (i, ki)),
-            pl.BlockSpec((tile_r,), lambda i, pi, ki: (i,)),
+            pl.BlockSpec((tile_r, 1), lambda i, pi, ki: (i, 0)),
         ],
         out_shape=out_shape,
         scratch_shapes=[
@@ -230,7 +221,7 @@ def alias_build(p: jax.Array, *, tile_r: int = DEFAULT_TILE_R,
             pltpu.VMEM((tile_r, k), jnp.int32),     # built alias rows
         ],
         interpret=interpret,
-    )(p)
+    )(p))
 
 
 def _alias_build_fused_tiled_kernel(n_wk_ref, n_k_ref, prob_ref, alias_ref,
@@ -253,13 +244,7 @@ def _alias_build_fused_tiled_kernel(n_wk_ref, n_k_ref, prob_ref, alias_ref,
     @pl.when((pi == 1) & (ki == 0))
     def _build():
         p = alpha * (nwk_s[...] + beta) / (nk_s[...] + beta_bar)
-        k = p.shape[-1]
-        mass = jnp.sum(p, axis=-1)
-        pn = p / mass[:, None]
-        prob, alias = _build_tile(pn * k)
-        prob_s[...] = prob
-        alias_s[...] = alias
-        mass_ref[...] = mass.astype(jnp.float32)
+        prob_s[...], alias_s[...], mass_ref[...] = _build_rows(p)
 
     @pl.when(pi == 1)
     def _flush():
@@ -273,7 +258,7 @@ def _alias_build_fused_tiled_kernel(n_wk_ref, n_k_ref, prob_ref, alias_ref,
 def alias_build_fused(n_wk: jax.Array, n_k: jax.Array, *, alpha: float,
                       beta: float, vocab_size: int,
                       tile_r: int = DEFAULT_TILE_R,
-                      tile_k: int | None = None, interpret: bool = True):
+                      tile_k: int | None = None, interpret: bool | None = None):
     """Fused dense-term + alias build from raw LDA statistics.
 
     ``tile_k`` (None ⇒ K) streams inputs and outputs in k-tiles as in
@@ -284,12 +269,13 @@ def alias_build_fused(n_wk: jax.Array, n_k: jax.Array, *, alpha: float,
     out_shape = [
         jax.ShapeDtypeStruct((v, k), jnp.float32),
         jax.ShapeDtypeStruct((v, k), jnp.int32),
-        jax.ShapeDtypeStruct((v,), jnp.float32),
+        jax.ShapeDtypeStruct((v, 1), jnp.float32),
     ]
+    interpret = backend.interpret("alias_build_fused", requested=interpret)
     if tile_k is None or tile_k >= k:
         kernel = functools.partial(_alias_build_fused_kernel, alpha=alpha,
                                    beta=beta, beta_bar=beta * vocab_size)
-        return pl.pallas_call(
+        return _flat_mass(pl.pallas_call(
             kernel,
             grid=(v // tile_r,),
             in_specs=[
@@ -299,17 +285,17 @@ def alias_build_fused(n_wk: jax.Array, n_k: jax.Array, *, alpha: float,
             out_specs=[
                 pl.BlockSpec((tile_r, k), lambda i: (i, 0)),
                 pl.BlockSpec((tile_r, k), lambda i: (i, 0)),
-                pl.BlockSpec((tile_r,), lambda i: (i,)),
+                pl.BlockSpec((tile_r, 1), lambda i: (i, 0)),
             ],
             out_shape=out_shape,
             interpret=interpret,
-        )(n_wk, n_k.reshape(1, -1))
+        )(n_wk, n_k.reshape(1, -1)))
     assert k % tile_k == 0, f"K={k} must be a multiple of tile_k={tile_k}"
     nk = k // tile_k
     kernel = functools.partial(_alias_build_fused_tiled_kernel,
                                tile_k=tile_k, alpha=alpha, beta=beta,
                                beta_bar=beta * vocab_size)
-    return pl.pallas_call(
+    return _flat_mass(pl.pallas_call(
         kernel,
         grid=(v // tile_r, 2, nk),
         in_specs=[
@@ -319,7 +305,7 @@ def alias_build_fused(n_wk: jax.Array, n_k: jax.Array, *, alpha: float,
         out_specs=[
             pl.BlockSpec((tile_r, tile_k), lambda i, pi, ki: (i, ki)),
             pl.BlockSpec((tile_r, tile_k), lambda i, pi, ki: (i, ki)),
-            pl.BlockSpec((tile_r,), lambda i, pi, ki: (i,)),
+            pl.BlockSpec((tile_r, 1), lambda i, pi, ki: (i, 0)),
         ],
         out_shape=out_shape,
         scratch_shapes=[
@@ -329,12 +315,12 @@ def alias_build_fused(n_wk: jax.Array, n_k: jax.Array, *, alpha: float,
             pltpu.VMEM((tile_r, k), jnp.int32),     # built alias rows
         ],
         interpret=interpret,
-    )(n_wk, n_k.reshape(1, -1))
+    )(n_wk, n_k.reshape(1, -1)))
 
 
 @functools.partial(jax.jit, static_argnames=("tile_r", "tile_k", "interpret"))
 def alias_build_rows(p: jax.Array, *, tile_r: int = DEFAULT_TILE_R,
-                     tile_k: int | None = None, interpret: bool = True):
+                     tile_k: int | None = None, interpret: bool | None = None):
     """Alias build over a compacted (R, K) row block — the gathered changed
     rows of an incremental rebuild.  R need not be a tile_r multiple (rows
     are padded with zero mass, which the kernel's uniform fallback absorbs,
@@ -347,30 +333,19 @@ def alias_build_rows(p: jax.Array, *, tile_r: int = DEFAULT_TILE_R,
     return prob[:r], alias[:r], mass[:r]
 
 
-def _alias_build_gather_kernel(rows_ref, n_wk_ref, n_k_ref, prior_ref,
-                               prob_ref, alias_ref, mass_ref, stale_ref,
-                               *, beta, beta_bar):
-    """One gathered row per program: the scalar-prefetched row index drives
-    the n_wk index map (the gather *is* the DMA), the dense term
-    prior_e·(n_wk+β)/(n_k+β̄) is computed in-register, and the freshly built
-    table row plus the dense row (the stale-snapshot update) are written to
-    the compacted outputs."""
-    del rows_ref  # consumed by the index maps
+def _alias_build_dense_row_kernel(n_wk_ref, n_k_ref, prior_ref, prob_ref,
+                                  alias_ref, mass_ref, stale_ref, *, beta,
+                                  beta_bar):
+    """One gathered statistics row per program: the dense term
+    prior_e·(n_wk+β)/(n_k+β̄) computed in-register, then the table build;
+    the dense row is written too (the stale-snapshot update)."""
     n_wk = n_wk_ref[...].astype(jnp.float32)           # (1, K) gathered row
     n_k = n_k_ref[...].astype(jnp.float32)             # (1, K)
     # prior · (LM row), division grouped first — the exact operation order
     # of the families' dense_probs, so partial rebuilds are bit-identical
     # to a full rebuild of the same statistics.
     p = prior_ref[...] * ((n_wk + beta) / (n_k + beta_bar))
-    k = p.shape[-1]
-    mass = jnp.sum(p, axis=-1)                         # (1,)
-    safe = mass > 0
-    pn = jnp.where(safe[:, None], p / jnp.where(safe, mass, 1.0)[:, None],
-                   jnp.full_like(p, 1.0 / k))
-    prob, alias = _build_tile(pn * k)
-    prob_ref[...] = prob
-    alias_ref[...] = alias
-    mass_ref[...] = mass.astype(jnp.float32)
+    prob_ref[...], alias_ref[...], mass_ref[...] = _build_rows(p)
     stale_ref[...] = p
 
 
@@ -379,7 +354,7 @@ def _alias_build_gather_kernel(rows_ref, n_wk_ref, n_k_ref, prior_ref,
 def alias_build_gather_fused(n_wk: jax.Array, n_k: jax.Array,
                              prior: jax.Array, rows: jax.Array, *,
                              beta: float, beta_bar: float,
-                             interpret: bool = True):
+                             interpret: bool | None = None):
     """Gather → fused dense-term + alias build over changed rows only.
 
     ``prior`` is the (K,) per-topic prior-mass vector of the dense proposal
@@ -388,35 +363,33 @@ def alias_build_gather_fused(n_wk: jax.Array, n_k: jax.Array,
     changed-row selection.  Returns compacted (prob, alias, mass, dense)
     rows of shapes (R, K)/(R, K)/(R,)/(R, K) for the caller to scatter
     (``repro.core.alias.update_rows``).
+
+    The R statistics rows are gathered by XLA (R·K elements) into an
+    (R, 1, K) block array: one row per program, with the row dimension
+    squeezed — the one-row block Mosaic accepts — and each row reduced
+    exactly as the full build reduces it, so partial and full rebuilds of
+    the same statistics agree bit-for-bit.
     """
-    v, k = n_wk.shape
+    k = n_wk.shape[1]
     r = rows.shape[0]
-    kernel = functools.partial(_alias_build_gather_kernel, beta=beta,
+    kernel = functools.partial(_alias_build_dense_row_kernel, beta=beta,
                                beta_bar=beta_bar)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(r,),
-        in_specs=[
-            pl.BlockSpec((1, k), lambda i, rows: (rows[i], 0)),
-            pl.BlockSpec((1, k), lambda i, rows: (0, 0)),
-            pl.BlockSpec((1, k), lambda i, rows: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, k), lambda i, rows: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, rows: (i, 0)),
-            pl.BlockSpec((1,), lambda i, rows: (i,)),
-            pl.BlockSpec((1, k), lambda i, rows: (i, 0)),
-        ],
-    )
-    return pl.pallas_call(
+    sq = pl.Squeezed()
+    row = pl.BlockSpec((sq, 1, k), lambda i: (i, 0, 0))
+    full_row = pl.BlockSpec((1, k), lambda i: (0, 0))
+    prob, alias, mass, dense = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
+        grid=(r,),
+        in_specs=[row, full_row, full_row],
+        out_specs=[row, row, pl.BlockSpec((sq, 1, 1), lambda i: (i, 0, 0)),
+                   row],
         out_shape=[
-            jax.ShapeDtypeStruct((r, k), jnp.float32),
-            jax.ShapeDtypeStruct((r, k), jnp.int32),
-            jax.ShapeDtypeStruct((r,), jnp.float32),
-            jax.ShapeDtypeStruct((r, k), jnp.float32),
+            jax.ShapeDtypeStruct((r, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((r, 1, k), jnp.int32),
+            jax.ShapeDtypeStruct((r, 1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((r, 1, k), jnp.float32),
         ],
-        interpret=interpret,
-    )(rows.astype(jnp.int32), n_wk, n_k.reshape(1, -1),
-      prior.reshape(1, -1))
+        interpret=backend.interpret("alias_build_gather_fused",
+                                    requested=interpret),
+    )(n_wk[rows][:, None, :], n_k.reshape(1, -1), prior.reshape(1, -1))
+    return prob[:, 0], alias[:, 0], mass[:, 0, 0], dense[:, 0]

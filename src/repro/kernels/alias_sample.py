@@ -36,6 +36,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import backend
+
 DEFAULT_TILE_V = 64
 DEFAULT_TILE_B = 1024
 
@@ -72,7 +74,7 @@ def alias_sample(prob: jax.Array, alias: jax.Array, rows: jax.Array,
                  slot: jax.Array, coin: jax.Array, *,
                  tile_v: int = DEFAULT_TILE_V,
                  tile_b: int = DEFAULT_TILE_B,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool | None = None) -> jax.Array:
     """Blocked alias draws (full tile scan).
 
     prob/alias: (V, K) tables; rows/slot/coin: (B,) per-draw row id, slot
@@ -99,7 +101,8 @@ def alias_sample(prob: jax.Array, alias: jax.Array, rows: jax.Array,
         ],
         out_specs=pl.BlockSpec((tile_b,), lambda vi, bi: (bi,)),
         out_shape=jax.ShapeDtypeStruct((b,), jnp.int32),
-        interpret=interpret,
+        interpret=backend.interpret("alias_sample", requested=interpret,
+                                    lowers=False),
     )(rows, slot, coin, prob, alias)
 
 
@@ -139,7 +142,7 @@ def alias_sample_sorted(prob: jax.Array, alias: jax.Array, rows: jax.Array,
                         vcount: jax.Array, *,
                         tile_v: int = DEFAULT_TILE_V,
                         tile_b: int = DEFAULT_TILE_B,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool | None = None) -> jax.Array:
     """Tile-skipping alias draws over a token-sorted stream.
 
     rows must be sorted ascending (``segment.build_layout``); entries ≥ V
@@ -178,5 +181,6 @@ def alias_sample_sorted(prob: jax.Array, alias: jax.Array, rows: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b,), jnp.int32),
-        interpret=interpret,
+        interpret=backend.interpret("alias_sample_sorted", requested=interpret,
+                                    lowers=False),
     )(vstart, vcount, rows, slot, coin, prob, alias)

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import backend
+
 DEFAULT_TILE_B = 4096
 
 
@@ -34,7 +36,7 @@ def _mh_accept_kernel(z_ref, cand_ref, lp_z_ref, lp_c_ref, lq_z_ref,
 
 @functools.partial(jax.jit, static_argnames=("tile_b", "interpret"))
 def mh_accept(z, cand, log_p_z, log_p_cand, log_q_z, log_q_cand, u, *,
-              tile_b: int = DEFAULT_TILE_B, interpret: bool = True):
+              tile_b: int = DEFAULT_TILE_B, interpret: bool | None = None):
     """Fused accept/reject: all inputs (B,); returns (B,) int32 new states."""
     b = z.shape[0]
     tile_b = min(tile_b, b)
@@ -47,5 +49,6 @@ def mh_accept(z, cand, log_p_z, log_p_cand, log_q_z, log_q_cand, u, *,
         in_specs=[spec] * 7,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((b,), jnp.int32),
-        interpret=interpret,
+        interpret=backend.interpret("mh_accept", requested=interpret,
+                                    lowers=False),
     )(z, cand, log_p_z, log_p_cand, log_q_z, log_q_cand, u)

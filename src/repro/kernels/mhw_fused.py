@@ -17,7 +17,15 @@ paper §3 retires in a single residency:
 Unfused, steps 2–3 are five HBM round trips per MH step (proposal draw,
 two q gathers, two p gathers) plus a fresh statistics gather per token;
 fused they are VMEM reads.  Grid programs outside a batch tile's vocab
-window are skipped via scalar prefetch exactly as in ``alias_sample_sorted``.
+window are skipped via scalar prefetch: the table index map re-points at
+the last resident tile (no DMA) and ``pl.when`` skips the body.
+
+Every data-dependent read is written in a form Mosaic lowers: the per-token
+table rows are a one-hot (TILE_B, TILE_V) × (TILE_V, tile_k) MXU
+contraction at HIGHEST precision, and every lane read inside the chain is a
+one-hot select-and-sum (``mhw._gather_k``).  Both are exact — one non-zero
+term per output — so interpreted (CPU) and lowered (TPU) kernels read the
+same values the oracles gather.
 
 Two kernels instantiate the ``ModelFamily`` dense-proposal factorization
 (p(e) ∝ (doc_e + prior_e)·f_e, see ``repro.core.mhw``):
@@ -26,9 +34,12 @@ Two kernels instantiate the ``ModelFamily`` dense-proposal factorization
   f = (n_wk − own + β)/(n_k − own + β̄), per-topic ``prior`` vector
   (α·1 for LDA, b1·θ0 for HDP).  Oracle: ``mhw.sorted_chain``.
 * :func:`pdp_sweep_fused` — PDP: E = 2K joint (topic, table-indicator)
-  outcomes, f = the generalized-Stirling-ratio factors of paper eqs. (5)-(6)
-  computed from resident (m_wk, s_wk) tiles plus the VMEM-resident
-  log-Stirling table.  Oracle: ``pdp.sorted_chain_pdp``.
+  outcomes, f = the generalized-Stirling-ratio factors of paper eqs. (5)-(6).
+  The factors of every vocabulary row are evaluated once per call by XLA
+  (the Stirling table lookups are 2-D gathers Mosaic cannot lower) and
+  stream through VMEM like the alias tiles; the kernel swaps in each
+  token's own-topic column, which the ^{-di} removal changes.  Oracle:
+  ``pdp.sorted_chain_pdp``.
 
 Both kernels delegate the chain itself to ``mhw.mix_chain`` — the same
 function their oracles call — so kernel and oracle are bit-identical given
@@ -47,26 +58,36 @@ from jax.experimental.pallas import tpu as pltpu
 # Shared with the oracles: the bit-exactness contract requires kernels and
 # oracles to run the identical chain math on identical factor values.
 from repro.core.mhw import _EPS, mix_chain
-from repro.core.pdp import corrected_rows, log_factors, own_contrib
-from repro.kernels.alias_sample import DEFAULT_TILE_B, DEFAULT_TILE_V
+from repro.core.pdp import fresh_log_factors, own_contrib, own_log_factors
+from repro.kernels import backend
+
+# Live (tile_b, E) float32 arrays the compiler keeps in VMEM for one chain
+# body: four staged tables, the double-buffered ndk block and the chain's
+# temporaries.  Sizes ``vmem_limit_bytes``; ``segment.pick_tile_b`` keeps
+# tile_b·E small enough that this stays well inside v5e's 128 MiB.
+_LIVE_ROWS = 24
+_VMEM_FLOOR = 32 * 2 ** 20
+_VMEM_CEIL = 100 * 2 ** 20
 
 
-def _index_maps(nv: int, nk: int):
+def _vmem_limit(tile_b: int, e: int, tile_v: int, tile_k: int) -> int:
+    need = 4 * (_LIVE_ROWS * tile_b * e + 16 * tile_v * tile_k)
+    return int(min(max(2 * need, _VMEM_FLOOR), _VMEM_CEIL))
+
+
+def _index_maps(nv: int):
     """BlockSpec index maps shared by both sorted-layout kernels: per-batch
-    blocks, per-step uniform blocks, whole-array residents, the
-    scalar-prefetched vocab-tile-window maps (the tile-skip re-point), and
-    the K-tile maps of the ``tile_k`` staging axis (grid axis 2, minor)."""
+    blocks, per-step uniform blocks, whole-array residents, and the
+    scalar-prefetched vocab-tile-window maps (the tile-skip re-point) over
+    the K-tile staging axis (grid axis 2, minor)."""
     def vtile(bi, vi, vs, vc):
         return jnp.clip(vs[bi] + jnp.minimum(vi, vc[bi] - 1), 0, nv - 1)
 
     def bmap(bi, vi, ki, vs, vc):
-        return (bi,)
+        return (0, bi)
 
     def bmap2(bi, vi, ki, vs, vc):
         return (bi, 0)
-
-    def smap(bi, vi, ki, vs, vc):
-        return (0, bi)
 
     def fullmap(bi, vi, ki, vs, vc):
         return (0, 0)
@@ -76,16 +97,43 @@ def _index_maps(nv: int, nk: int):
         # that replaces the (tile_v, K) one.
         return (vtile(bi, vi, vs, vc), ki)
 
-    def vmapk_clip(bi, vi, ki, vs, vc):
-        # (V, K) statistics under a 2K-outcome e-tile axis: k-tiles exist
-        # only for the first nk e-tiles; later steps re-fetch the last one
-        # (the kernel guards the stage, the map just has to stay in range).
-        return (vtile(bi, vi, vs, vc), jnp.minimum(ki, nk - 1))
+    def vmap_mass(bi, vi, ki, vs, vc):
+        # mass viewed as (nv, 1, tile_v): one lane-major row per vocab tile.
+        return (vtile(bi, vi, vs, vc), 0, 0)
 
-    def vmap1(bi, vi, ki, vs, vc):
-        return (vtile(bi, vi, vs, vc),)
+    return bmap, bmap2, fullmap, vmapk, vmap_mass
 
-    return bmap, bmap2, smap, fullmap, vmapk, vmapk_clip, vmap1
+
+def _vec(ref):
+    """A (1, TILE_B) per-token block as a (TILE_B,) vector.  Per-token
+    operands travel as (1, B) rows: XLA tiles a rank-1 HBM array by 1024
+    elements, which no (TILE_B,) block shorter than that can match."""
+    return jax.lax.index_in_dim(ref[...], 0, 0, keepdims=False)
+
+
+def _tile_onehot(rows, row_lo, tile_v: int):
+    """(TILE_B,) sorted rows → (in_tile, one-hot (TILE_B, TILE_V) bool)."""
+    local = rows - row_lo
+    in_tile = (local >= 0) & (local < tile_v)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows.shape[0], tile_v), 1)
+    return in_tile, lane == local[:, None]
+
+
+def _gather_rows(onehot, tile):
+    """Rows of a resident (TILE_V, C) table tile picked by a one-hot
+    (TILE_B, TILE_V) matrix: an MXU contraction with one non-zero product
+    per output, exact in f32 at HIGHEST precision (integer tables are
+    carried as exact f32 and cast back)."""
+    out = jnp.dot(onehot.astype(jnp.float32), tile.astype(jnp.float32),
+                  precision=jax.lax.Precision.HIGHEST,
+                  preferred_element_type=jnp.float32)
+    return out.astype(tile.dtype)
+
+
+def _dense_mass(onehot, mass_row):
+    """Per-token stale dense mass: one-hot select-and-sum over the tile's
+    (1, TILE_V) mass row."""
+    return jnp.sum(jnp.where(onehot, mass_row, 0.0), axis=-1)
 
 
 def _mhw_fused_kernel(vstart_ref, vcount_ref, rows_ref, z_ref, ndk_ref,
@@ -93,18 +141,14 @@ def _mhw_fused_kernel(vstart_ref, vcount_ref, rows_ref, z_ref, ndk_ref,
                       prob_ref, alias_ref, mass_ref, stale_ref, nwk_ref,
                       nk_ref, prior_ref, out_ref, nwk_s, stale_s, prob_s,
                       alias_s, *, tile_v: int, n_vtiles: int, tile_k: int,
-                      n_ktiles: int, beta: float, beta_bar: float):
+                      n_ktiles: int, beta: float, beta_bar: float,
+                      fold_in: bool):
     bi = pl.program_id(0)
     vi = pl.program_id(1)
     ki = pl.program_id(2)
     tid = jnp.clip(vstart_ref[bi] + jnp.minimum(vi, vcount_ref[bi] - 1),
                    0, n_vtiles - 1)
-    row_lo = tid * tile_v
-
-    rows = rows_ref[...]                           # (TILE_B,) sorted rows
-    local = rows - row_lo
-    in_tile = (local >= 0) & (local < tile_v)
-    lidx = jnp.clip(local, 0, tile_v - 1)
+    in_tile, onehot = _tile_onehot(_vec(rows_ref), tid * tile_v, tile_v)
 
     @pl.when((vi == 0) & (ki == 0))
     def _init():
@@ -112,56 +156,70 @@ def _mhw_fused_kernel(vstart_ref, vcount_ref, rows_ref, z_ref, ndk_ref,
 
     @pl.when(vi < vcount_ref[bi])
     def _stage():
-        # Stage this (tile_v, tile_k) table block's per-token gathers into
-        # the full-K VMEM scratch.  Pure data movement: column tiles of
-        # the same gathered rows concatenate to exactly the rows the
-        # untiled kernel gathers, so tiling cannot perturb the chain.
+        # Stage this (tile_v, tile_k) table block's per-token rows into the
+        # full-K VMEM scratch.  Pure data movement: column tiles of the
+        # same gathered rows concatenate to exactly the rows the untiled
+        # kernel gathers, so tiling cannot perturb the chain.
         ksl = pl.ds(ki * tile_k, tile_k)
-        nwk_s[:, ksl] = nwk_ref[...][lidx]
-        stale_s[:, ksl] = stale_ref[...][lidx]
-        prob_s[:, ksl] = prob_ref[...][lidx]
-        alias_s[:, ksl] = alias_ref[...][lidx]
+        nwk_s[:, ksl] = _gather_rows(onehot, nwk_ref[...])
+        stale_s[:, ksl] = _gather_rows(onehot, stale_ref[...])
+        prob_s[:, ksl] = _gather_rows(onehot, prob_ref[...])
+        alias_s[:, ksl] = _gather_rows(onehot, alias_ref[...])
 
     @pl.when((vi < vcount_ref[bi]) & (ki == n_ktiles - 1))
     def _body():
-        z0 = z_ref[...]                            # (TILE_B,) chain init
+        z0 = _vec(z_ref)                           # (TILE_B,) chain init
         k_topics = ndk_ref.shape[-1]
 
         # ^{-di} correction in-kernel: remove the token's own contribution
         # from its doc row, its n_wk row and the topic totals (as in the
-        # scan path) — callers pass *raw* gathered n_dk rows.
+        # scan path) — callers pass *raw* gathered n_dk rows.  A folded-in
+        # document is not counted in the frozen n_wk / n_k, so there only
+        # its doc row loses the token.
         karange = jax.lax.broadcasted_iota(jnp.int32, (1, k_topics), 1)
-        own = ((karange == z0[:, None]) & in_tile[:, None]).astype(jnp.float32)
+        # (Mosaic cannot broadcast a 1-D bool into a column: widen first.)
+        own = ((karange == z0[:, None]).astype(jnp.float32)
+               * in_tile.astype(jnp.float32)[:, None])
         ndk = ndk_ref[...] - own                   # (TILE_B, K)
         rows_wk = nwk_s[...]                       # (TILE_B, K) staged
-        lm = (rows_wk - own + beta) / (nk_ref[...] - own + beta_bar)
+        own_wk = 0.0 if fold_in else own
+        lm = (rows_wk - own_wk + beta) / (nk_ref[...] - own_wk + beta_bar)
 
         z = mix_chain(
-            z0, doc=ndk, prior=prior_ref[...][0], logf=jnp.log(lm + _EPS),
+            z0, doc=ndk, prior=prior_ref[...], logf=jnp.log(lm + _EPS),
             sparse_w=ndk * lm, stale_rows=stale_s[...],
             prob_rows=prob_s[...], alias_rows=alias_s[...],
-            dense_mass=mass_ref[...][lidx], slot=slot_ref[...],
-            coin=coin_ref[...], u_mix=umix_ref[...], u_sparse=usp_ref[...],
-            u_acc=uacc_ref[...])
+            dense_mass=_dense_mass(onehot, mass_ref[...]),
+            slot=slot_ref[...], coin=coin_ref[...], u_mix=umix_ref[...],
+            u_sparse=usp_ref[...], u_acc=uacc_ref[...])
 
-        out_ref[...] = jnp.where(in_tile, z.astype(jnp.int32), out_ref[...])
+        out_ref[...] = jnp.where(in_tile, z.astype(jnp.int32),
+                                 _vec(out_ref))[None, :]
+
+
+def _check_tiles(v, e, b, tile_v, tile_b, tile_k):
+    tile_v = min(tile_v, v)
+    tile_b = min(tile_b, b)
+    tile_k = e if tile_k is None else min(tile_k, e)
+    assert v % tile_v == 0 and b % tile_b == 0, (v, tile_v, b, tile_b)
+    assert e % tile_k == 0, f"E={e} must be a multiple of tile_k={tile_k}"
+    return tile_v, tile_b, tile_k
 
 
 @functools.partial(jax.jit,
                    static_argnames=("tile_v", "tile_b", "tile_k", "n_steps",
-                                    "beta", "beta_bar", "interpret"))
+                                    "beta", "beta_bar", "fold_in",
+                                    "interpret"))
 def mhw_sweep_fused(prob: jax.Array, alias: jax.Array, mass: jax.Array,
                     stale: jax.Array, n_wk: jax.Array, n_k: jax.Array,
                     prior: jax.Array, rows: jax.Array, z0: jax.Array,
                     ndk: jax.Array, slot: jax.Array, coin: jax.Array,
                     u_mix: jax.Array, u_sparse: jax.Array, u_acc: jax.Array,
                     vstart: jax.Array, vcount: jax.Array, *,
-                    tile_v: int = DEFAULT_TILE_V,
-                    tile_b: int = DEFAULT_TILE_B,
-                    tile_k: int | None = None,
+                    tile_v: int, tile_b: int, tile_k: int | None = None,
                     n_steps: int = 2, beta: float = 0.01,
-                    beta_bar: float | None = None,
-                    interpret: bool = True) -> jax.Array:
+                    beta_bar: float | None = None, fold_in: bool = False,
+                    interpret: bool | None = None) -> jax.Array:
     """Fused sorted-layout MHW chain for one sweep — lm families (LDA/HDP).
 
     prob/alias/stale/n_wk: (V, K); mass: (V,); n_k: (K,); prior: (K,)
@@ -175,20 +233,19 @@ def mhw_sweep_fused(prob: jax.Array, alias: jax.Array, mass: jax.Array,
 
     ``tile_k`` (None ⇒ K) adds the K-tile *staging* axis: the (V, K)
     tables stream through VMEM in (tile_v, tile_k) blocks whose per-token
-    gathers accumulate into full-K scratch; the chain itself — which
-    needs the full K row per token (cumsum proposal CDF, arbitrary-index
-    gathers) — runs once per (batch, vocab) tile on the staged scratch,
+    rows accumulate into full-K scratch; the chain itself — which needs
+    the full K row per token (prefix-sum proposal CDF, arbitrary-index
+    reads) — runs once per (batch, vocab) tile on the staged scratch,
     bit-identical to the untiled kernel.  Table VMEM residency drops from
     (tile_v, K) to (tile_v, tile_k); the (tile_b, K) per-token state is
-    the floor, so shrink ``tile_b`` as K grows (``segment.pick_tile_vmem``).
+    the floor, so tile_b shrinks as K grows (``segment.pick_tile_b``).
+    ``fold_in`` marks documents the statistics do not count (serving):
+    the ^{-di} removal then touches only their doc rows.
+    ``interpret`` None ⇒ the platform decides (``kernels.backend``).
     """
     v, k = prob.shape
     b = rows.shape[0]
-    tile_v = min(tile_v, v)
-    tile_b = min(tile_b, b)
-    tile_k = k if tile_k is None else min(tile_k, k)
-    assert v % tile_v == 0 and b % tile_b == 0
-    assert k % tile_k == 0, f"K={k} must be a multiple of tile_k={tile_k}"
+    tile_v, tile_b, tile_k = _check_tiles(v, k, b, tile_v, tile_b, tile_k)
     nb, nv, nk = b // tile_b, v // tile_v, k // tile_k
     assert vstart.shape == (nb,) and vcount.shape == (nb,)
     if beta_bar is None:
@@ -196,45 +253,47 @@ def mhw_sweep_fused(prob: jax.Array, alias: jax.Array, mass: jax.Array,
 
     kernel = functools.partial(_mhw_fused_kernel, tile_v=tile_v, n_vtiles=nv,
                                tile_k=tile_k, n_ktiles=nk,
-                               beta=beta, beta_bar=beta_bar)
-    bmap, bmap2, smap, fullmap, vmapk, _, vmap1 = _index_maps(nv, nk)
+                               beta=beta, beta_bar=beta_bar, fold_in=fold_in)
+    bmap, bmap2, fullmap, vmapk, vmap_mass = _index_maps(nv)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(nb, nv, nk),
         in_specs=[
-            pl.BlockSpec((tile_b,), bmap),           # rows
-            pl.BlockSpec((tile_b,), bmap),           # z0
+            pl.BlockSpec((1, tile_b), bmap),           # rows
+            pl.BlockSpec((1, tile_b), bmap),           # z0
             pl.BlockSpec((tile_b, k), bmap2),        # ndk
-            pl.BlockSpec((n_steps, tile_b), smap),   # slot
-            pl.BlockSpec((n_steps, tile_b), smap),   # coin
-            pl.BlockSpec((n_steps, tile_b), smap),   # u_mix
-            pl.BlockSpec((n_steps, tile_b), smap),   # u_sparse
-            pl.BlockSpec((n_steps, tile_b), smap),   # u_acc
+            pl.BlockSpec((n_steps, tile_b), bmap),   # slot
+            pl.BlockSpec((n_steps, tile_b), bmap),   # coin
+            pl.BlockSpec((n_steps, tile_b), bmap),   # u_mix
+            pl.BlockSpec((n_steps, tile_b), bmap),   # u_sparse
+            pl.BlockSpec((n_steps, tile_b), bmap),   # u_acc
             pl.BlockSpec((tile_v, tile_k), vmapk),   # prob
             pl.BlockSpec((tile_v, tile_k), vmapk),   # alias
-            pl.BlockSpec((tile_v,), vmap1),          # mass
+            pl.BlockSpec((pl.Squeezed(), 1, tile_v), vmap_mass),  # mass
             pl.BlockSpec((tile_v, tile_k), vmapk),   # stale
             pl.BlockSpec((tile_v, tile_k), vmapk),   # n_wk
             pl.BlockSpec((1, k), fullmap),           # n_k
             pl.BlockSpec((1, k), fullmap),           # prior
         ],
-        out_specs=pl.BlockSpec((tile_b,), bmap),
+        out_specs=pl.BlockSpec((1, tile_b), bmap),
         scratch_shapes=[
-            pltpu.VMEM((tile_b, k), jnp.float32),    # staged n_wk gathers
-            pltpu.VMEM((tile_b, k), jnp.float32),    # staged stale gathers
-            pltpu.VMEM((tile_b, k), jnp.float32),    # staged prob gathers
-            pltpu.VMEM((tile_b, k), jnp.int32),      # staged alias gathers
+            pltpu.VMEM((tile_b, k), jnp.float32),    # staged n_wk rows
+            pltpu.VMEM((tile_b, k), jnp.float32),    # staged stale rows
+            pltpu.VMEM((tile_b, k), jnp.float32),    # staged prob rows
+            pltpu.VMEM((tile_b, k), jnp.int32),      # staged alias rows
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b,), jnp.int32),
-        interpret=interpret,
-    )(vstart, vcount, rows, z0, ndk, slot, coin, u_mix, u_sparse, u_acc,
-      prob, alias, mass, stale, n_wk, n_k.reshape(1, -1),
-      prior.reshape(1, -1))
+        out_shape=jax.ShapeDtypeStruct((1, b), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(tile_b, k, tile_v, tile_k)),
+        interpret=backend.interpret("mhw_sweep_fused", requested=interpret),
+    )(vstart, vcount, rows.reshape(1, b), z0.reshape(1, b), ndk, slot, coin,
+      u_mix, u_sparse, u_acc, prob, alias, mass.reshape(nv, 1, tile_v),
+      stale, n_wk, n_k.reshape(1, -1), prior.reshape(1, -1))[0]
 
 
 # ---------------------------------------------------------------------------
@@ -242,83 +301,70 @@ def mhw_sweep_fused(prob: jax.Array, alias: jax.Array, mass: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _pdp_fused_kernel(vstart_ref, vcount_ref, rows_ref, e_ref, ndk_ref,
-                      slot_ref, coin_ref, umix_ref, usp_ref, uacc_ref,
-                      prob_ref, alias_ref, mass_ref, stale_ref, mwk_ref,
-                      swk_ref, mk_ref, sk_ref, prior_ref, stirl_ref, out_ref,
-                      mwk_s, swk_s, stale_s, prob_s, alias_s,
-                      *, tile_v: int, n_vtiles: int, tile_k: int,
-                      n_ktiles: int, b: float, a: float,
-                      gamma: float, gamma_bar: float):
+def _pdp_fused_kernel(vstart_ref, vcount_ref, rows_ref, e_ref, ownf0_ref,
+                      ownf1_ref, ndk_ref, slot_ref, coin_ref, umix_ref,
+                      usp_ref, uacc_ref, prob_ref, alias_ref, mass_ref,
+                      stale_ref, logf_ref, prior_ref, out_ref, logf_s,
+                      stale_s, prob_s, alias_s, *, tile_v: int,
+                      n_vtiles: int, tile_k: int, n_etiles: int,
+                      fold_in: bool):
     bi = pl.program_id(0)
     vi = pl.program_id(1)
     ei = pl.program_id(2)          # e-tile over the 2K joint outcomes
-    n_etiles = 2 * n_ktiles
     tid = jnp.clip(vstart_ref[bi] + jnp.minimum(vi, vcount_ref[bi] - 1),
                    0, n_vtiles - 1)
-    row_lo = tid * tile_v
-
-    rows = rows_ref[...]
-    local = rows - row_lo
-    in_tile = (local >= 0) & (local < tile_v)
-    lidx = jnp.clip(local, 0, tile_v - 1)
+    in_tile, onehot = _tile_onehot(_vec(rows_ref), tid * tile_v, tile_v)
 
     @pl.when((vi == 0) & (ei == 0))
     def _init():
         out_ref[...] = e_ref[...]
 
     @pl.when(vi < vcount_ref[bi])
-    def _stage_e():
+    def _stage():
         # The (V, 2K) joint-outcome tables stream one e-tile per step.
         esl = pl.ds(ei * tile_k, tile_k)
-        stale_s[:, esl] = stale_ref[...][lidx]
-        prob_s[:, esl] = prob_ref[...][lidx]
-        alias_s[:, esl] = alias_ref[...][lidx]
-
-    @pl.when((vi < vcount_ref[bi]) & (ei < n_ktiles))
-    def _stage_k():
-        # The (V, K) customer/table counts only have k-tiles for the
-        # first half of the e axis (their index map clips past it).
-        ksl = pl.ds(ei * tile_k, tile_k)
-        mwk_s[:, ksl] = mwk_ref[...][lidx]
-        swk_s[:, ksl] = swk_ref[...][lidx]
+        logf_s[:, esl] = _gather_rows(onehot, logf_ref[...])
+        stale_s[:, esl] = _gather_rows(onehot, stale_ref[...])
+        prob_s[:, esl] = _gather_rows(onehot, prob_ref[...])
+        alias_s[:, esl] = _gather_rows(onehot, alias_ref[...])
 
     @pl.when((vi < vcount_ref[bi]) & (ei == n_etiles - 1))
     def _body():
-        e0 = e_ref[...]                            # (TILE_B,) joint outcome
+        e0 = _vec(e_ref)                           # (TILE_B,) joint outcome
         k_topics = ndk_ref.shape[-1]
 
-        # ^{-di}: remove the token's own customer/table contribution from
-        # the gathered rows, the aggregates and its doc row, with the CRP
-        # bookkeeping repair — same functions as the oracle.
-        own_t, own_r = own_contrib(k_topics, e0, in_tile)
-        m_row, s_row = corrected_rows(mwk_s[...], swk_s[...],
-                                      own_t, own_r)
-        m_k_m = mk_ref[...] - own_t                # (TILE_B, K) via broadcast
-        s_k_m = sk_ref[...] - own_r
-
-        log_f0, log_f1 = log_factors(stirl_ref[...], m_row, s_row, m_k_m,
-                                     s_k_m, b=b, a=a, gamma=gamma,
-                                     gamma_bar=gamma_bar)
-        log_f = jnp.concatenate([log_f0, log_f1], axis=-1)   # (TILE_B, 2K)
+        # ^{-di}: the staged rows hold every topic's factors without the
+        # token's own removal; its own topic's pair of columns (r = 0, 1)
+        # takes the precomputed corrected factors instead — except for a
+        # folded-in document, which the statistics do not count.
+        own_t, _ = own_contrib(k_topics, e0, in_tile)
+        log_f = logf_s[...]                                   # (TILE_B, 2K)
+        if not fold_in:
+            own_e = jnp.concatenate([own_t, own_t], axis=-1) > 0
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, 2 * k_topics), 1)
+            own_f = jnp.where(lane < k_topics, _vec(ownf0_ref)[:, None],
+                              _vec(ownf1_ref)[:, None])
+            log_f = jnp.where(own_e, own_f, log_f)
         ndk_m = ndk_ref[...] - own_t
         ndk_ext = jnp.concatenate([ndk_m, ndk_m], axis=-1)
 
         e = mix_chain(
-            e0, doc=ndk_ext, prior=prior_ref[...][0], logf=log_f,
+            e0, doc=ndk_ext, prior=prior_ref[...], logf=log_f,
             sparse_w=ndk_ext * jnp.exp(log_f),
             stale_rows=stale_s[...], prob_rows=prob_s[...],
-            alias_rows=alias_s[...], dense_mass=mass_ref[...][lidx],
+            alias_rows=alias_s[...],
+            dense_mass=_dense_mass(onehot, mass_ref[...]),
             slot=slot_ref[...], coin=coin_ref[...], u_mix=umix_ref[...],
             u_sparse=usp_ref[...], u_acc=uacc_ref[...])
 
-        out_ref[...] = jnp.where(in_tile, e.astype(jnp.int32), out_ref[...])
+        out_ref[...] = jnp.where(in_tile, e.astype(jnp.int32),
+                                 _vec(out_ref))[None, :]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("tile_v", "tile_b", "tile_k", "n_steps",
                                     "b_conc", "a_disc", "gamma", "gamma_bar",
-                                    "interpret"))
+                                    "fold_in", "interpret"))
 def pdp_sweep_fused(prob: jax.Array, alias: jax.Array, mass: jax.Array,
                     stale: jax.Array, m_wk: jax.Array, s_wk: jax.Array,
                     m_k: jax.Array, s_k: jax.Array, stirl: jax.Array,
@@ -326,76 +372,72 @@ def pdp_sweep_fused(prob: jax.Array, alias: jax.Array, mass: jax.Array,
                     ndk: jax.Array, slot: jax.Array, coin: jax.Array,
                     u_mix: jax.Array, u_sparse: jax.Array, u_acc: jax.Array,
                     vstart: jax.Array, vcount: jax.Array, *,
-                    tile_v: int = DEFAULT_TILE_V,
-                    tile_b: int = DEFAULT_TILE_B,
-                    tile_k: int | None = None, n_steps: int = 2,
-                    b_conc: float = 10.0, a_disc: float = 0.1,
-                    gamma: float = 0.5, gamma_bar: float | None = None,
-                    interpret: bool = True) -> jax.Array:
+                    tile_v: int, tile_b: int, tile_k: int | None = None,
+                    n_steps: int = 2, b_conc: float = 10.0,
+                    a_disc: float = 0.1, gamma: float = 0.5,
+                    gamma_bar: float | None = None, fold_in: bool = False,
+                    interpret: bool | None = None) -> jax.Array:
     """Fused sorted-layout MHW chain for one PDP sweep (2K outcomes).
 
     prob/alias/stale: (V, 2K) joint-outcome tables; mass: (V,);
     m_wk/s_wk: (V, K) customer/table counts; m_k/s_k: (K,); stirl: the
-    log-Stirling table (resident in VMEM — ≤ (513, 513) fp32 ≈ 1 MB);
-    prior: (2K,) = α·1.  rows/e0: (B,) sorted token-types and joint-outcome
-    chain init; ndk: (B, K) raw gathered doc rows; uniforms (n_steps, B),
-    slot int32 in [0, 2K).  Returns (B,) int32 final joint outcomes.
+    log-Stirling table; prior: (2K,) = α·1.  rows/e0: (B,) sorted
+    token-types and joint-outcome chain init; ndk: (B, K) raw gathered doc
+    rows; uniforms (n_steps, B), slot int32 in [0, 2K).  Returns (B,)
+    int32 final joint outcomes.
 
-    ``tile_k`` (None ⇒ K) adds the staging axis as in
-    :func:`mhw_sweep_fused`, here over ``2K/tile_k`` e-tiles: the (V, 2K)
-    joint tables stage one (tile_v, tile_k) block per step, the (V, K)
-    customer/table counts only during the first K/tile_k steps; the chain
-    runs on the staged full-width scratch at the last e-tile, bit-exact
-    with the untiled kernel.
+    The fresh Stirling-ratio factors of every row (no ^{-di} removal) and
+    each token's corrected own-topic pair are evaluated here by XLA
+    (``pdp.fresh_log_factors`` / ``pdp.own_log_factors`` — the oracle's
+    functions on the same values); the kernel stages the (V, 2K) factor
+    table with the alias tiles.  ``tile_k`` (None ⇒ 2K) sets the e-tile
+    width of that staging axis; results are bit-exact for every tile_k.
+    ``fold_in`` as in :func:`mhw_sweep_fused`: no own-topic correction of
+    the statistics' factors.
     """
     v, e_out = prob.shape
     k = m_wk.shape[1]
     assert e_out == 2 * k
     bsz = rows.shape[0]
-    tile_v = min(tile_v, v)
-    tile_b = min(tile_b, bsz)
-    tile_k = k if tile_k is None else min(tile_k, k)
-    assert v % tile_v == 0 and bsz % tile_b == 0
-    assert k % tile_k == 0, f"K={k} must be a multiple of tile_k={tile_k}"
-    nb, nv, nk = bsz // tile_b, v // tile_v, k // tile_k
+    tile_v, tile_b, tile_k = _check_tiles(v, e_out, bsz, tile_v, tile_b,
+                                          tile_k)
+    nb, nv, ne = bsz // tile_b, v // tile_v, e_out // tile_k
     assert vstart.shape == (nb,) and vcount.shape == (nb,)
     if gamma_bar is None:
         gamma_bar = gamma * v
+    hyper = dict(b=b_conc, a=a_disc, gamma=gamma, gamma_bar=gamma_bar)
+    log_f = fresh_log_factors(stirl, m_wk, s_wk, m_k, s_k, **hyper)
+    own_f0, own_f1 = own_log_factors(stirl, m_wk, s_wk, m_k, s_k, rows, e0,
+                                     **hyper)
 
     kernel = functools.partial(_pdp_fused_kernel, tile_v=tile_v, n_vtiles=nv,
-                               tile_k=tile_k, n_ktiles=nk,
-                               b=b_conc, a=a_disc, gamma=gamma,
-                               gamma_bar=gamma_bar)
-    bmap, bmap2, smap, fullmap, vmapk, vmapk_clip, vmap1 = _index_maps(nv, nk)
+                               tile_k=tile_k, n_etiles=ne, fold_in=fold_in)
+    bmap, bmap2, fullmap, vmapk, vmap_mass = _index_maps(nv)
 
-    s_dim = stirl.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(nb, nv, 2 * nk),
+        grid=(nb, nv, ne),
         in_specs=[
-            pl.BlockSpec((tile_b,), bmap),            # rows
-            pl.BlockSpec((tile_b,), bmap),            # e0
+            pl.BlockSpec((1, tile_b), bmap),            # rows
+            pl.BlockSpec((1, tile_b), bmap),            # e0
+            pl.BlockSpec((1, tile_b), bmap),            # own-topic log f (r=0)
+            pl.BlockSpec((1, tile_b), bmap),            # own-topic log f (r=1)
             pl.BlockSpec((tile_b, k), bmap2),         # ndk
-            pl.BlockSpec((n_steps, tile_b), smap),    # slot
-            pl.BlockSpec((n_steps, tile_b), smap),    # coin
-            pl.BlockSpec((n_steps, tile_b), smap),    # u_mix
-            pl.BlockSpec((n_steps, tile_b), smap),    # u_sparse
-            pl.BlockSpec((n_steps, tile_b), smap),    # u_acc
+            pl.BlockSpec((n_steps, tile_b), bmap),    # slot
+            pl.BlockSpec((n_steps, tile_b), bmap),    # coin
+            pl.BlockSpec((n_steps, tile_b), bmap),    # u_mix
+            pl.BlockSpec((n_steps, tile_b), bmap),    # u_sparse
+            pl.BlockSpec((n_steps, tile_b), bmap),    # u_acc
             pl.BlockSpec((tile_v, tile_k), vmapk),    # prob (e-tiles)
             pl.BlockSpec((tile_v, tile_k), vmapk),    # alias (e-tiles)
-            pl.BlockSpec((tile_v,), vmap1),           # mass
+            pl.BlockSpec((pl.Squeezed(), 1, tile_v), vmap_mass),  # mass
             pl.BlockSpec((tile_v, tile_k), vmapk),    # stale (e-tiles)
-            pl.BlockSpec((tile_v, tile_k), vmapk_clip),  # m_wk (k-tiles)
-            pl.BlockSpec((tile_v, tile_k), vmapk_clip),  # s_wk (k-tiles)
-            pl.BlockSpec((1, k), fullmap),            # m_k
-            pl.BlockSpec((1, k), fullmap),            # s_k
+            pl.BlockSpec((tile_v, tile_k), vmapk),    # fresh log f (e-tiles)
             pl.BlockSpec((1, e_out), fullmap),        # prior
-            pl.BlockSpec((s_dim, s_dim), fullmap),    # stirling table
         ],
-        out_specs=pl.BlockSpec((tile_b,), bmap),
+        out_specs=pl.BlockSpec((1, tile_b), bmap),
         scratch_shapes=[
-            pltpu.VMEM((tile_b, k), jnp.float32),     # staged m_wk gathers
-            pltpu.VMEM((tile_b, k), jnp.float32),     # staged s_wk gathers
+            pltpu.VMEM((tile_b, e_out), jnp.float32),  # staged log f
             pltpu.VMEM((tile_b, e_out), jnp.float32),  # staged stale
             pltpu.VMEM((tile_b, e_out), jnp.float32),  # staged prob
             pltpu.VMEM((tile_b, e_out), jnp.int32),   # staged alias
@@ -404,8 +446,10 @@ def pdp_sweep_fused(prob: jax.Array, alias: jax.Array, mass: jax.Array,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz,), jnp.int32),
-        interpret=interpret,
-    )(vstart, vcount, rows, e0, ndk, slot, coin, u_mix, u_sparse, u_acc,
-      prob, alias, mass, stale, m_wk, s_wk, m_k.reshape(1, -1),
-      s_k.reshape(1, -1), prior.reshape(1, -1), stirl)
+        out_shape=jax.ShapeDtypeStruct((1, bsz), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(tile_b, e_out, tile_v, tile_k)),
+        interpret=backend.interpret("pdp_sweep_fused", requested=interpret),
+    )(vstart, vcount, *(x.reshape(1, bsz) for x in (rows, e0, own_f0, own_f1)),
+      ndk, slot, coin, u_mix, u_sparse, u_acc, prob, alias,
+      mass.reshape(nv, 1, tile_v), stale, log_f, prior.reshape(1, -1))[0]

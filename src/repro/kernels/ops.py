@@ -1,8 +1,7 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True so the kernels validate on CPU; on a real TPU
-runtime set ``repro.kernels.ops.INTERPRET = False`` (or pass explicitly) and
-the same BlockSpecs lower to Mosaic.
+Whether a kernel is interpreted is the platform's decision
+(``repro.kernels.backend``): interpreted on CPU, lowered to Mosaic on TPU.
 """
 
 from __future__ import annotations
@@ -16,59 +15,47 @@ from repro.kernels import alias_sample as _sample
 from repro.kernels import mh_accept as _accept
 from repro.kernels import mhw_fused as _fused
 
-INTERPRET = True
 
-
-def build_tables(p: jax.Array, *, tile_r: int = 8,
-                 interpret: bool | None = None) -> AliasTable:
+def build_tables(p: jax.Array, *, tile_r: int = 8) -> AliasTable:
     """Kernel-backed replacement for ``repro.core.alias.build`` (2-D input)."""
-    prob, alias, mass = _build.alias_build(
-        p, tile_r=tile_r,
-        interpret=INTERPRET if interpret is None else interpret)
+    prob, alias, mass = _build.alias_build(p, tile_r=tile_r)
     return AliasTable(prob=prob, alias=alias, mass=mass)
 
 
 def build_tables_fused_lda(n_wk: jax.Array, n_k: jax.Array, *, alpha: float,
-                           beta: float, vocab_size: int, tile_r: int = 8,
-                           interpret: bool | None = None
+                           beta: float, vocab_size: int, tile_r: int = 8
                            ) -> tuple[AliasTable, jax.Array]:
     """Fused dense-term + alias build; also returns the dense term mass-
     consistent stale matrix (recomputed cheaply for MH point evaluation)."""
     prob, alias, mass = _build.alias_build_fused(
         n_wk, n_k, alpha=alpha, beta=beta, vocab_size=vocab_size,
-        tile_r=tile_r, interpret=INTERPRET if interpret is None else interpret)
+        tile_r=tile_r)
     stale_dense = alpha * (n_wk + beta) / (n_k[None, :] + beta * vocab_size)
     return AliasTable(prob=prob, alias=alias, mass=mass), stale_dense
 
 
-def build_tables_rows(p_rows: jax.Array, *, tile_r: int = 8,
-                      interpret: bool | None = None) -> AliasTable:
+def build_tables_rows(p_rows: jax.Array, *, tile_r: int = 8) -> AliasTable:
     """Alias build over a compacted (R, K) block of gathered changed rows
     (the incremental producer's generic path; see alias_build_rows)."""
-    prob, alias, mass = _build.alias_build_rows(
-        p_rows, tile_r=tile_r,
-        interpret=INTERPRET if interpret is None else interpret)
+    prob, alias, mass = _build.alias_build_rows(p_rows, tile_r=tile_r)
     return AliasTable(prob=prob, alias=alias, mass=mass)
 
 
 def build_tables_gather_fused(n_wk: jax.Array, n_k: jax.Array,
                               prior: jax.Array, rows: jax.Array, *,
-                              beta: float, beta_bar: float,
-                              interpret: bool | None = None
+                              beta: float, beta_bar: float
                               ) -> tuple[AliasTable, jax.Array]:
     """Gather → fused dense-term + alias build over changed rows only, for
     the LM-dense families (prior_e · (n_wk+β)/(n_k+β̄)).  Returns the
     compacted sub-table plus the matching dense rows for the stale-snapshot
     scatter (``repro.core.alias.update_rows``)."""
     prob, alias, mass, dense = _build.alias_build_gather_fused(
-        n_wk, n_k, prior, rows, beta=beta, beta_bar=beta_bar,
-        interpret=INTERPRET if interpret is None else interpret)
+        n_wk, n_k, prior, rows, beta=beta, beta_bar=beta_bar)
     return AliasTable(prob=prob, alias=alias, mass=mass), dense
 
 
 def sample_rows(tables: AliasTable, rows: jax.Array, key: jax.Array, *,
-                tile_v: int = 64, tile_b: int = 1024,
-                interpret: bool | None = None) -> jax.Array:
+                tile_v: int = 64, tile_b: int = 1024) -> jax.Array:
     """Kernel-backed replacement for ``repro.core.alias.sample_rows``."""
     k = tables.prob.shape[-1]
     k_slot, k_coin = jax.random.split(key)
@@ -76,14 +63,13 @@ def sample_rows(tables: AliasTable, rows: jax.Array, key: jax.Array, *,
     coin = jax.random.uniform(k_coin, rows.shape)
     return _sample.alias_sample(
         tables.prob, tables.alias, rows, slot, coin, tile_v=tile_v,
-        tile_b=tile_b, interpret=INTERPRET if interpret is None else interpret)
+        tile_b=tile_b)
 
 
 def sample_rows_sorted(tables: AliasTable, rows: jax.Array,
                        vstart: jax.Array, vcount: jax.Array, key: jax.Array,
                        *, tile_v: int = _sample.DEFAULT_TILE_V,
-                       tile_b: int = _sample.DEFAULT_TILE_B,
-                       interpret: bool | None = None) -> jax.Array:
+                       tile_b: int = _sample.DEFAULT_TILE_B) -> jax.Array:
     """Tile-skipping draws over a token-sorted stream (``segment`` layout).
 
     ``rows`` must be ascending with padding sentinels ≥ V at the end;
@@ -96,8 +82,7 @@ def sample_rows_sorted(tables: AliasTable, rows: jax.Array,
     coin = jax.random.uniform(k_coin, rows.shape)
     return _sample.alias_sample_sorted(
         tables.prob, tables.alias, rows, slot, coin, vstart, vcount,
-        tile_v=tile_v, tile_b=tile_b,
-        interpret=INTERPRET if interpret is None else interpret)
+        tile_v=tile_v, tile_b=tile_b)
 
 
 def _step_uniforms(key: jax.Array, n_outcomes: int, mh_steps: int, b: int):
@@ -113,12 +98,10 @@ def mhw_sweep_sorted(tables: AliasTable, stale: jax.Array, n_wk: jax.Array,
                      n_k: jax.Array, prior: jax.Array, rows: jax.Array,
                      z0: jax.Array, ndk: jax.Array, vstart: jax.Array,
                      vcount: jax.Array, key: jax.Array, *, mh_steps: int,
-                     beta: float, beta_bar: float,
-                     tile_v: int = _sample.DEFAULT_TILE_V,
-                     tile_b: int = _sample.DEFAULT_TILE_B,
+                     beta: float, beta_bar: float, tile_v: int, tile_b: int,
                      tile_k: int | None = None,
                      uniforms: tuple[jax.Array, ...] | None = None,
-                     interpret: bool | None = None) -> jax.Array:
+                     fold_in: bool = False) -> jax.Array:
     """Fused sorted-layout MHW chain for the lm families (LDA: prior = α·1,
     HDP: prior = b1·θ0): draws the per-step uniforms and runs
     ``kernels.mhw_fused.mhw_sweep_fused`` (see that module's docstring).
@@ -127,7 +110,8 @@ def mhw_sweep_sorted(tables: AliasTable, stale: jax.Array, n_wk: jax.Array,
     ``(slot, coin, u_mix, u_sparse, u_acc)`` streams, each ``(mh_steps, b)``
     in sorted-stream order; ``key`` is then unused.  The serving engine uses
     this to keep each document's chain a pure function of its own request
-    seed regardless of which slots it shares a batch with.
+    seed regardless of which slots it shares a batch with.  ``fold_in``:
+    the documents are not counted in ``n_wk``/``n_k`` (serving).
     """
     k = tables.prob.shape[-1]
     b = rows.shape[0]
@@ -138,8 +122,7 @@ def mhw_sweep_sorted(tables: AliasTable, stale: jax.Array, n_wk: jax.Array,
         tables.prob, tables.alias, tables.mass, stale, n_wk, n_k, prior,
         rows, z0, ndk, slot, coin, u_mix, u_sparse, u_acc, vstart, vcount,
         tile_v=tile_v, tile_b=tile_b, tile_k=tile_k, n_steps=mh_steps,
-        beta=beta, beta_bar=beta_bar,
-        interpret=INTERPRET if interpret is None else interpret)
+        beta=beta, beta_bar=beta_bar, fold_in=fold_in)
 
 
 def pdp_sweep_sorted(tables: AliasTable, stale: jax.Array, m_wk: jax.Array,
@@ -148,12 +131,10 @@ def pdp_sweep_sorted(tables: AliasTable, stale: jax.Array, m_wk: jax.Array,
                      e0: jax.Array, ndk: jax.Array, vstart: jax.Array,
                      vcount: jax.Array, key: jax.Array, *, mh_steps: int,
                      concentration: float, discount: float, gamma: float,
-                     gamma_bar: float,
-                     tile_v: int = _sample.DEFAULT_TILE_V,
-                     tile_b: int = _sample.DEFAULT_TILE_B,
+                     gamma_bar: float, tile_v: int, tile_b: int,
                      tile_k: int | None = None,
                      uniforms: tuple[jax.Array, ...] | None = None,
-                     interpret: bool | None = None) -> jax.Array:
+                     fold_in: bool = False) -> jax.Array:
     """Fused sorted-layout MHW chain for PDP's joint 2K outcome space:
     draws the per-step uniforms (slot over [0, 2K)) and runs
     ``kernels.mhw_fused.pdp_sweep_fused``.  ``uniforms`` overrides the
@@ -168,14 +149,13 @@ def pdp_sweep_sorted(tables: AliasTable, stale: jax.Array, m_wk: jax.Array,
         stirl, prior, rows, e0, ndk, slot, coin, u_mix, u_sparse, u_acc,
         vstart, vcount, tile_v=tile_v, tile_b=tile_b, tile_k=tile_k,
         n_steps=mh_steps, b_conc=concentration, a_disc=discount,
-        gamma=gamma, gamma_bar=gamma_bar,
-        interpret=INTERPRET if interpret is None else interpret)
+        gamma=gamma, gamma_bar=gamma_bar, fold_in=fold_in)
 
 
 def mh_accept(z, cand, log_p_z, log_p_cand, log_q_z, log_q_cand, key, *,
-              tile_b: int = 4096, interpret: bool | None = None):
+              tile_b: int = 4096):
     """Kernel-backed fused MH accept step."""
     u = jax.random.uniform(key, z.shape)
     return _accept.mh_accept(
         z, cand, log_p_z, log_p_cand, log_q_z, log_q_cand, u,
-        tile_b=tile_b, interpret=INTERPRET if interpret is None else interpret)
+        tile_b=tile_b)

@@ -35,6 +35,7 @@ from repro.configs.base import INPUT_SHAPES
 from repro.configs.registry import ARCHITECTURES
 from repro.launch import roofline as rl
 from repro.launch import specs as specs_lib
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 
 
@@ -111,6 +112,7 @@ def main(argv: list[str] | None = None) -> int:
                     help="megatron = paper-faithful baseline; zero_seq = "
                          "ZeRO-3 + sequence-parallel (§Perf optimization)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     archs = [args.arch] if args.arch else sorted(ARCHITECTURES)
     shapes = [args.shape] if args.shape else list(INPUT_SHAPES)

@@ -24,6 +24,12 @@ Fault tolerance (DESIGN.md §13) adds two layers on top:
   the killed worker with ``--restore`` (locals reloaded from its
   trainer snapshot, servers caught up through idempotent replay).
 
+Every process of this topology runs on the host (``JAX_PLATFORMS=cpu``):
+the shard servers are numpy protocol code, and the sampler processes are
+many per machine, where one chip could serve only one of them — so the
+parity smokes compare CPU processes against a CPU reference (DESIGN.md
+§11).
+
 On abnormal exit the launcher dumps diagnostics into the result: the
 last stderr lines of every failed process plus each live shard's STATS
 frame (per-connection RPC counters) — enough to see *which* connection
@@ -48,6 +54,8 @@ import tempfile
 import time
 from dataclasses import dataclass, field
 from typing import Any
+
+from repro.launch.cache import enable_compile_cache
 
 
 @dataclass
@@ -91,10 +99,14 @@ def _python() -> list[str]:
 
 
 def _env() -> dict[str, str]:
+    """Child environment: the package on the path, JAX pinned to the host
+    (several processes of this topology would otherwise reach for one
+    chip)."""
     env = dict(os.environ)
     src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__)))))
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"
     return env
 
 
@@ -684,6 +696,10 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--consistency", default="bsp")
     ap.add_argument("--timeout", type=float, default=300.0)
     args = ap.parse_args(argv)
+    # The in-process references run where the children do: on the host.
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+    enable_compile_cache()
 
     if args.smoke:
         return _smoke()

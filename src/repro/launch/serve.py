@@ -2,9 +2,14 @@
 
 Spawns 1 inference-server process (``python -m repro.serve.server``) and
 M concurrent client processes (``python -m repro.serve.client``) on
-127.0.0.1, against a snapshot trained in-process and persisted through
-``checkpoint.ckpt`` — the serving deployment shape in miniature: a
+127.0.0.1, against a snapshot trained by a child process and persisted
+through ``checkpoint.ckpt`` — the serving deployment shape in miniature: a
 frozen model behind a socket, folded into by many concurrent users.
+
+One process at a time holds the accelerator: the training child exits
+before the server starts, the clients run on CPU (they only frame and
+send requests), and the launcher itself touches no device until every
+child has exited (DESIGN.md §11).
 
 ``--smoke`` is the CI end-to-end check: train a small LDA model, save
 its Trainer snapshot, serve it from a separate process, fold the same
@@ -28,6 +33,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Any
 
+from repro.launch.cache import enable_compile_cache
+
 
 @dataclass
 class ProcResult:
@@ -43,29 +50,34 @@ class ProcResult:
 @dataclass
 class ServeLaunchResult:
     address: str
+    trainer: ProcResult | None = None
     server: ProcResult | None = None
     clients: list[ProcResult] = field(default_factory=list)
     server_stats: dict[str, Any] = field(default_factory=dict)
 
+    def _procs(self) -> list[ProcResult]:
+        return [p for p in (self.trainer, self.server) if p] + self.clients
+
     @property
     def ok(self) -> bool:
-        procs = ([self.server] if self.server else []) + self.clients
-        return all(p.returncode == 0 for p in procs)
+        return all(p.returncode == 0 for p in self._procs())
 
     def failures(self) -> list[ProcResult]:
-        procs = ([self.server] if self.server else []) + self.clients
-        return [p for p in procs if p.returncode != 0]
+        return [p for p in self._procs() if p.returncode != 0]
 
 
 def _python() -> list[str]:
     return [sys.executable]
 
 
-def _env() -> dict[str, str]:
+def _env(*, cpu: bool = False) -> dict[str, str]:
+    """Child environment; ``cpu`` pins JAX to the host (the clients)."""
     env = dict(os.environ)
     src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__)))))
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    if cpu:
+        env["JAX_PLATFORMS"] = "cpu"
     return env
 
 
@@ -127,11 +139,10 @@ def _shutdown_server(address: str, timeout: float = 10.0
 
 def train_snapshot(workdir: str, *, family: str, vocab_size: int,
                    n_topics: int, n_docs: int = 64, doc_len: int = 48,
-                   n_rounds: int = 5, seed: int = 0):
-    """Train a small model in-process and persist its Trainer snapshot —
-    the model the launched server process will freeze and serve.
-    Returns the model config (the serving side rebuilds the same one
-    from CLI flags)."""
+                   n_rounds: int = 5, seed: int = 0) -> None:
+    """Train a small model and persist its Trainer snapshot — the model
+    the launched server process will freeze and serve.  Runs in the
+    training child (``--train-snapshot``)."""
     import jax
 
     from repro.core import family as family_mod
@@ -148,7 +159,23 @@ def train_snapshot(workdir: str, *, family: str, vocab_size: int,
                       key=jax.random.PRNGKey(seed))
     trainer.run(n_rounds, eval_every=n_rounds + 1)
     trainer.save_snapshot()
-    return cfg
+
+
+def _train_in_child(workdir: str, *, family: str, vocab_size: int,
+                    n_topics: int, n_rounds: int, seed: int,
+                    timeout: float) -> ProcResult:
+    """Run :func:`train_snapshot` in a child process that exits before
+    the server starts, so the two never hold the accelerator at once."""
+    args = _python() + ["-m", "repro.launch.serve",
+                        "--train-snapshot", workdir,
+                        "--family", family,
+                        "--vocab-size", str(vocab_size),
+                        "--n-topics", str(n_topics),
+                        "--train-rounds", str(n_rounds),
+                        "--corpus-seed", str(seed)]
+    proc = subprocess.Popen(args, stdout=subprocess.PIPE,
+                            stderr=subprocess.PIPE, text=True, env=_env())
+    return _finish(proc, "trainer", args, timeout)
 
 
 def launch_serve(*, family: str = "lda", vocab_size: int = 400,
@@ -160,13 +187,21 @@ def launch_serve(*, family: str = "lda", vocab_size: int = 400,
                  ) -> tuple[ServeLaunchResult, Any]:
     """Train → snapshot → serve from a separate process → M concurrent
     client processes.  Returns (launch result, model config)."""
+    from repro.core import family as family_mod
+
+    cfg = family_mod.get(family).config_cls(n_topics=n_topics,
+                                            vocab_size=vocab_size)
     own_dir = workdir is None
     tmp = tempfile.TemporaryDirectory() if own_dir else None
     workdir = tmp.name if own_dir else workdir
     try:
-        cfg = train_snapshot(workdir, family=family,
-                             vocab_size=vocab_size, n_topics=n_topics,
-                             n_rounds=train_rounds, seed=corpus_seed)
+        trained = _train_in_child(workdir, family=family,
+                                  vocab_size=vocab_size, n_topics=n_topics,
+                                  n_rounds=train_rounds, seed=corpus_seed,
+                                  timeout=timeout)
+        result = ServeLaunchResult(address="", trainer=trained)
+        if trained.returncode != 0:
+            return result, cfg
         addr_file = os.path.join(workdir, "serve_addr.json")
         srv_args = _python() + ["-m", "repro.serve.server",
                                 "--family", family,
@@ -180,7 +215,6 @@ def launch_serve(*, family: str = "lda", vocab_size: int = 400,
         srv = subprocess.Popen(srv_args, stdout=subprocess.PIPE,
                                stderr=subprocess.PIPE, text=True,
                                env=_env())
-        result = ServeLaunchResult(address="")
         try:
             result.address = _wait_address_file(addr_file, srv,
                                                 timeout=60.0)
@@ -203,7 +237,7 @@ def launch_serve(*, family: str = "lda", vocab_size: int = 400,
             client_procs.append(
                 (subprocess.Popen(cargs, stdout=subprocess.PIPE,
                                   stderr=subprocess.PIPE, text=True,
-                                  env=_env()), cargs, out))
+                                  env=_env(cpu=True)), cargs, out))
         for i, (proc, cargs, out) in enumerate(client_procs):
             pr = _finish(proc, f"client{i}", cargs, timeout)
             if pr.returncode == 0 and os.path.exists(out):
@@ -301,11 +335,24 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--seed-base", type=int, default=1000)
     ap.add_argument("--train-rounds", type=int, default=5)
     ap.add_argument("--timeout", type=float, default=420.0)
+    ap.add_argument("--train-snapshot", metavar="DIR", default=None,
+                    help="(the launcher's training child) train and save "
+                         "a snapshot into DIR, then exit")
+    ap.add_argument("--vocab-size", type=int, default=None)
+    ap.add_argument("--n-topics", type=int, default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
+    vocab, topics = args.vocab_size, args.n_topics
     if args.reduced:
         args.vocab_size, args.n_topics, args.max_len = 400, 8, 48
     else:
         args.vocab_size, args.n_topics, args.max_len = 4096, 32, 128
+    if args.train_snapshot:
+        train_snapshot(args.train_snapshot, family=args.family,
+                       vocab_size=vocab or args.vocab_size,
+                       n_topics=topics or args.n_topics,
+                       n_rounds=args.train_rounds, seed=args.corpus_seed)
+        return 0
 
     if args.smoke:
         return _smoke(args)

@@ -23,6 +23,7 @@ from repro.checkpoint import ckpt
 from repro.configs.base import reduced
 from repro.configs.registry import ARCHITECTURES
 from repro.data.synthetic import lm_batches
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import model as model_lib
 from repro.optim import adamw
@@ -58,6 +59,7 @@ def main(argv=None) -> None:
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = ARCHITECTURES[args.arch]
     if args.reduced:

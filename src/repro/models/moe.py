@@ -130,7 +130,6 @@ def _moe_a2a(cfg: ModelConfig, p: Params, xg: Array, gateg: Array,
     compute happens there, results return — two all-to-alls of exactly
     the dispatched bytes, nothing replicated.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     g, tg, d = xg.shape
@@ -148,12 +147,12 @@ def _moe_a2a(cfg: ModelConfig, p: Params, xg: Array, gateg: Array,
                                   tiled=True)              # (E/m, m·C, D)
         return buf2, slot[None], keep[None], st[None], sg[None]
 
-    buf2, slot, keep, st, sg = shard_map(
+    buf2, slot, keep, st, sg = jax.shard_map(
         dispatch, mesh=mesh,
         in_specs=(g_spec, g_spec, g_spec),
         out_specs=(P("model", rest, None), meta_spec, meta_spec, meta_spec,
                    meta_spec),
-        check_rep=False,
+        check_vma=False,
     )(xg, gateg, idsg)
 
     # Expert MLPs: buf2 (E@model, CC@rest, D) × weights (E@model, ·, ·) —
@@ -172,12 +171,12 @@ def _moe_a2a(cfg: ModelConfig, p: Params, xg: Array, gateg: Array,
         out = _combine(back, sl[0], kp[0], (stt[0], sgg[0]), tg, dtype)
         return out[None]
 
-    out = shard_map(
+    out = jax.shard_map(
         combine, mesh=mesh,
         in_specs=(P("model", rest, None), meta_spec, meta_spec, meta_spec,
                   meta_spec),
         out_specs=g_spec,
-        check_rep=False,
+        check_vma=False,
     )(out_buf, slot, keep, st, sg)
     return out
 

@@ -100,6 +100,7 @@ from repro.checkpoint import ckpt
 from repro.core import family as family_mod
 from repro.core import projection
 from repro.core import server as server_mod
+from repro.launch.cache import enable_compile_cache
 from repro.net import protocol
 from repro.net.protocol import MsgType, ProtocolError
 
@@ -1105,6 +1106,7 @@ def main(argv: list[str] | None = None) -> int:
                     help="write the bound addresses as JSON (the launcher "
                          "polls this instead of parsing stdout)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     ports = None
     if args.ports:

@@ -25,10 +25,14 @@ order (``ops._step_uniforms``); the engine draws each slot's streams
 under the *single-document* layout geometry with the slot's own
 ``fold_in(fold_in(PRNGKey(seed), sweep), chunk)`` key and permutes them
 into the batched sorted order.  The result is bit-identical to
-:func:`reference_fold_in` — the Trainer path (``family.sweep`` with
-``layout="sorted"``) run on a one-document shard with its pushes
-dropped — which is exactly what tests/test_serve_engine.py asserts per
-family.
+:func:`reference_fold_in` — the Trainer's sorted sweep run on a
+one-document shard with its pushes dropped — which is exactly what
+tests/test_serve_engine.py asserts per family.
+
+A folded-in document is not counted in the frozen statistics, so its
+chain removes each token's own contribution from its doc row only
+(``sweep_sorted(fold_in=True)``); removing it from ``n_wk`` as training
+does would subtract a count that was never added.
 """
 
 from __future__ import annotations
@@ -251,7 +255,8 @@ class FoldInEngine:
             self.cfg, self._local, self.snap.shared, self.snap.tables,
             self.snap.stale, self._tokens, self._mask,
             jax.random.PRNGKey(0),  # unused: every chunk gets uniforms
-            self._layouts, chunk_uniforms=self._chunk_uniforms)
+            self._layouts, chunk_uniforms=self._chunk_uniforms,
+            fold_in=True)
         self._local = self.fam.local_project(local2)
         n = 0
         for slot in self._slots:
@@ -306,9 +311,10 @@ class FoldInEngine:
 def reference_fold_in(snap: InferenceSnapshot, tokens: Sequence[int],
                       seed: int, *, n_sweeps: int,
                       max_len: int) -> tuple[Any, np.ndarray, np.ndarray]:
-    """Fold one document in via the training code path: ``family.sweep``
-    (the jitted per-family entry Trainer calls) on a one-document shard
-    with ``layout="sorted"``, deltas dropped — i.e. pushes disabled.
+    """Fold one document in via the training code path: the sorted sweep
+    Trainer runs (``ModelFamily.sweep_sorted``) on a one-document shard,
+    with ``fold_in=True`` (the frozen statistics do not count the
+    document) and its deltas dropped — i.e. pushes disabled.
 
     Returns ``(local_state, theta, assignments)``.  ``max_len`` must
     match the engine's slot width: chunk boundaries are derived from the
@@ -327,10 +333,9 @@ def reference_fold_in(snap: InferenceSnapshot, tokens: Sequence[int],
     local, _ = fam.init_state(cfg, tok1, mask1, key)
     layouts = fam.build_sorted_layouts(cfg, tok1, mask1)
     for s in range(n_sweeps):
-        local, _deltas = fam.sweep(
+        local, _deltas = fam.sweep_sorted(
             cfg, local, snap.shared, snap.tables, snap.stale, tok1, mask1,
-            jax.random.fold_in(key, s), method="mhw", layout="sorted",
-            sorted_layouts=layouts)
+            jax.random.fold_in(key, s), layouts, fold_in=True)
         local = fam.local_project(local)
     n_dk = np.asarray(local.n_dk[0])
     prior = np.asarray(snap.topic_prior(), np.float32)

@@ -45,6 +45,7 @@ from typing import Any
 
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro.net import protocol
 from repro.net.protocol import MsgType, ProtocolError
 from repro.serve import snapshot as snapshot_mod
@@ -354,6 +355,7 @@ def main(argv: list[str] | None = None) -> int:
                     help="write the bound address as JSON (the launcher "
                          "polls this instead of parsing stdout)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from repro.core import family as family_mod
     fam = family_mod.get(args.family)

@@ -14,6 +14,13 @@ import numpy as np
 import pytest
 
 
+def pytest_configure(config):
+    # Registered so the mark is known; nothing deselects it.
+    config.addinivalue_line(
+        "markers", "slow: a test that takes minutes (multi-device "
+        "subprocesses, whole-model lowerings)")
+
+
 def make_synthetic_corpus(n_topics, vocab, n_docs, doc_len, seed=0,
                           theta_conc=0.2, phi_conc=0.4):
     """Block-structured synthetic corpus with known topics: each true topic

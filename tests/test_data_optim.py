@@ -36,6 +36,16 @@ class TestCorpus:
         top10 = counts[:10].sum() / counts.sum()
         assert top10 > 0.25, f"not heavy-tailed: top-10 share {top10:.3f}"
 
+    def test_words_follow_true_phi(self):
+        """With one topic every token is a draw from ``true_phi[0]``: the
+        empirical word distribution matches it (total variation within a
+        few multiples of its sampling noise, ~sqrt(V/N)/2 ≈ 0.03 here)."""
+        cfg = CorpusConfig(n_topics=1, vocab_size=64, n_docs=256,
+                           doc_len=64)
+        tokens, mask, phi = make_topic_corpus(cfg)
+        emp = np.bincount(tokens[mask], minlength=64) / mask.sum()
+        assert 0.5 * np.abs(emp - phi[0]).sum() < 0.08
+
     def test_sharding_partition(self):
         cfg = CorpusConfig(n_topics=4, vocab_size=64, n_docs=16, doc_len=8)
         tokens, mask, _ = make_topic_corpus(cfg)

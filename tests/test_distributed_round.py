@@ -28,7 +28,8 @@ SCRIPT = textwrap.dedent("""
 
     assert len(jax.devices()) == 8
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     tokens, mask, _ = make_topic_corpus(CorpusConfig(
         n_topics=8, vocab_size=128, n_docs=64, doc_len=32, seed=0))
     tokens, mask = jnp.asarray(tokens), jnp.asarray(mask)

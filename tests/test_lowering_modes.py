@@ -20,7 +20,8 @@ SCRIPT = textwrap.dedent("""
     from repro.configs.registry import ARCHITECTURES
     from repro.launch import specs as specs_lib
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     shape_train = InputShape("tiny_train", seq_len=64, global_batch=8,
                              kind="train")
     shape_decode = InputShape("tiny_decode", seq_len=64, global_batch=8,

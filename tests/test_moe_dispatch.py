@@ -83,7 +83,8 @@ SHARD_MAP_SCRIPT = textwrap.dedent("""
     # Reference: single-device global dispatch (no mesh, no act spec).
     ref, _ = moe_mod.moe_block(cfg.replace(moe_groups=0), p, x)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     layers.set_activation_spec(P(("data", "model"), None, None), None, mesh)
     try:
         with mesh:

@@ -121,8 +121,8 @@ def test_admit_validation(snapshot):
 def test_fold_in_bit_identical_to_trainer_path(snapshot):
     """Acceptance: a document folded in through the batched engine is
     bit-identical — assignments AND theta — to the same document swept
-    through the training path (``family.sweep``, layout="sorted") with
-    pushes disabled."""
+    through the training path (``ModelFamily.sweep_sorted`` with
+    ``fold_in=True``) with pushes disabled."""
     eng = FoldInEngine(snapshot, scfg())
     reqs = make_reqs(snapshot, 5, seed=11)
     results = eng.run(reqs)

@@ -2,8 +2,9 @@
 # CI entry point: tier-1 tests + quick-mode throughput benchmark.
 #
 # Runs entirely on CPU — the Pallas kernels execute in interpret mode
-# (repro.kernels.ops.INTERPRET defaults to True), so this validates kernel
-# semantics and the benchmark pipeline without TPU hardware.
+# there (repro.kernels.backend: the platform decides), so this validates
+# kernel semantics and the benchmark pipeline without TPU hardware.  On a
+# TPU host, `python chip_smoke.py` is the end-to-end check.
 #
 # Usage: tools/ci.sh  (from the repo root)
 set -euo pipefail
