@@ -620,15 +620,17 @@ class Trainer:
         blocks only to serialize the buffers it writes while further
         rounds keep dispatching.
         """
-        if self.remote is not None:
-            self._step_remote()
-        elif not self.tcfg.compiled:
-            self._step_python()
-        else:
-            self._step_compiled()
-        if self.tcfg.snapshot_every and self.tcfg.snapshot_dir \
-                and self.round_idx % self.tcfg.snapshot_every == 0:
-            self.save_snapshot()
+        with jax.profiler.TraceAnnotation("repro.train.step",
+                                          round=self.round_idx):
+            if self.remote is not None:
+                self._step_remote()
+            elif not self.tcfg.compiled:
+                self._step_python()
+            else:
+                self._step_compiled()
+            if self.tcfg.snapshot_every and self.tcfg.snapshot_dir \
+                    and self.round_idx % self.tcfg.snapshot_every == 0:
+                self.save_snapshot()
 
     def _step_compiled(self) -> None:
         tcfg = self.tcfg

@@ -200,6 +200,7 @@ def alias_build(p: jax.Array, *, tile_r: int = DEFAULT_TILE_R,
                 pl.BlockSpec((tile_r, 1), lambda i: (i, 0)),
             ],
             out_shape=out_shape,
+            name="alias_build",
             interpret=interpret,
         )(p))
     assert k % tile_k == 0, f"K={k} must be a multiple of tile_k={tile_k}"
@@ -220,6 +221,7 @@ def alias_build(p: jax.Array, *, tile_r: int = DEFAULT_TILE_R,
             pltpu.VMEM((tile_r, k), jnp.float32),   # built prob rows
             pltpu.VMEM((tile_r, k), jnp.int32),     # built alias rows
         ],
+        name="alias_build",
         interpret=interpret,
     )(p))
 
@@ -288,6 +290,7 @@ def alias_build_fused(n_wk: jax.Array, n_k: jax.Array, *, alpha: float,
                 pl.BlockSpec((tile_r, 1), lambda i: (i, 0)),
             ],
             out_shape=out_shape,
+            name="alias_build_fused",
             interpret=interpret,
         )(n_wk, n_k.reshape(1, -1)))
     assert k % tile_k == 0, f"K={k} must be a multiple of tile_k={tile_k}"
@@ -314,6 +317,7 @@ def alias_build_fused(n_wk: jax.Array, n_k: jax.Array, *, alpha: float,
             pltpu.VMEM((tile_r, k), jnp.float32),   # built prob rows
             pltpu.VMEM((tile_r, k), jnp.int32),     # built alias rows
         ],
+        name="alias_build_fused",
         interpret=interpret,
     )(n_wk, n_k.reshape(1, -1)))
 
@@ -389,6 +393,7 @@ def alias_build_gather_fused(n_wk: jax.Array, n_k: jax.Array,
             jax.ShapeDtypeStruct((r, 1, 1), jnp.float32),
             jax.ShapeDtypeStruct((r, 1, k), jnp.float32),
         ],
+        name="alias_build_gather_fused",
         interpret=backend.interpret("alias_build_gather_fused",
                                     requested=interpret),
     )(n_wk[rows][:, None, :], n_k.reshape(1, -1), prior.reshape(1, -1))

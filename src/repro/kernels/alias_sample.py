@@ -101,6 +101,7 @@ def alias_sample(prob: jax.Array, alias: jax.Array, rows: jax.Array,
         ],
         out_specs=pl.BlockSpec((tile_b,), lambda vi, bi: (bi,)),
         out_shape=jax.ShapeDtypeStruct((b,), jnp.int32),
+        name="alias_sample",
         interpret=backend.interpret("alias_sample", requested=interpret,
                                     lowers=False),
     )(rows, slot, coin, prob, alias)
@@ -181,6 +182,7 @@ def alias_sample_sorted(prob: jax.Array, alias: jax.Array, rows: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b,), jnp.int32),
+        name="alias_sample_sorted",
         interpret=backend.interpret("alias_sample_sorted", requested=interpret,
                                     lowers=False),
     )(vstart, vcount, rows, slot, coin, prob, alias)

@@ -49,6 +49,7 @@ def mh_accept(z, cand, log_p_z, log_p_cand, log_q_z, log_q_cand, u, *,
         in_specs=[spec] * 7,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((b,), jnp.int32),
+        name="mh_accept",
         interpret=backend.interpret("mh_accept", requested=interpret,
                                     lowers=False),
     )(z, cand, log_p_z, log_p_cand, log_q_z, log_q_cand, u)

@@ -290,6 +290,7 @@ def mhw_sweep_fused(prob: jax.Array, alias: jax.Array, mass: jax.Array,
         out_shape=jax.ShapeDtypeStruct((1, b), jnp.int32),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(tile_b, k, tile_v, tile_k)),
+        name="mhw_sweep_fused",
         interpret=backend.interpret("mhw_sweep_fused", requested=interpret),
     )(vstart, vcount, rows.reshape(1, b), z0.reshape(1, b), ndk, slot, coin,
       u_mix, u_sparse, u_acc, prob, alias, mass.reshape(nv, 1, tile_v),
@@ -449,6 +450,7 @@ def pdp_sweep_fused(prob: jax.Array, alias: jax.Array, mass: jax.Array,
         out_shape=jax.ShapeDtypeStruct((1, bsz), jnp.int32),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(tile_b, e_out, tile_v, tile_k)),
+        name="pdp_sweep_fused",
         interpret=backend.interpret("pdp_sweep_fused", requested=interpret),
     )(vstart, vcount, *(x.reshape(1, bsz) for x in (rows, e0, own_f0, own_f1)),
       ndk, slot, coin, u_mix, u_sparse, u_acc, prob, alias,

@@ -207,38 +207,40 @@ class FoldInEngine:
         mapped through its single-doc sorted order, then permuted into the
         batched sorted order.  Empty slots get neutral values (their
         outputs are masked away)."""
-        s_chunk, e_chunk = self._bounds[c], self._bounds[c + 1]
-        clen = e_chunk - s_chunk
-        e_out = self.fam.n_outcomes(self.cfg)
-        mh = self.cfg.mh_steps
-        cols = []
-        for slot in self._slots:
-            if slot is None:
-                cols.append((np.zeros((mh, clen), np.int32),)
-                            + tuple(np.full((mh, clen), 0.5, np.float32)
-                                    for _ in range(4)))
-                continue
-            ck = jax.random.fold_in(
-                jax.random.fold_in(slot.key, slot.age), c)
-            u = ops._step_uniforms(ck, e_out, mh, slot.widths[c])
-            order = slot.orders[c]
-            inv = np.empty(clen, np.int64)
-            inv[order] = np.arange(clen)
-            cols.append(tuple(np.asarray(a)[:, inv] for a in u))
-        # (mh, max_slots*clen) flat streams, slot-major like the grid.
-        flat = [np.concatenate([col[i] for col in cols], axis=1)
-                for i in range(5)]
-        order_b = np.asarray(lay.order)
-        pad = int(lay.rows.shape[0]) - order_b.shape[0]
-        out = []
-        for i, f in enumerate(flat):
-            g = f[:, order_b]
-            if pad:
-                fill = np.zeros((mh, pad), np.int32) if i == 0 else \
-                    np.full((mh, pad), 0.5, np.float32)
-                g = np.concatenate([g, fill], axis=1)
-            out.append(jnp.asarray(g))
-        return tuple(out)
+        with jax.profiler.TraceAnnotation("repro.serve.uniforms",
+                                          chunk=c):
+            s_chunk, e_chunk = self._bounds[c], self._bounds[c + 1]
+            clen = e_chunk - s_chunk
+            e_out = self.fam.n_outcomes(self.cfg)
+            mh = self.cfg.mh_steps
+            cols = []
+            for slot in self._slots:
+                if slot is None:
+                    cols.append((np.zeros((mh, clen), np.int32),)
+                                + tuple(np.full((mh, clen), 0.5, np.float32)
+                                        for _ in range(4)))
+                    continue
+                ck = jax.random.fold_in(
+                    jax.random.fold_in(slot.key, slot.age), c)
+                u = ops._step_uniforms(ck, e_out, mh, slot.widths[c])
+                order = slot.orders[c]
+                inv = np.empty(clen, np.int64)
+                inv[order] = np.arange(clen)
+                cols.append(tuple(np.asarray(a)[:, inv] for a in u))
+            # (mh, max_slots*clen) flat streams, slot-major like the grid.
+            flat = [np.concatenate([col[i] for col in cols], axis=1)
+                    for i in range(5)]
+            order_b = np.asarray(lay.order)
+            pad = int(lay.rows.shape[0]) - order_b.shape[0]
+            out = []
+            for i, f in enumerate(flat):
+                g = f[:, order_b]
+                if pad:
+                    fill = np.zeros((mh, pad), np.int32) if i == 0 else \
+                        np.full((mh, pad), 0.5, np.float32)
+                    g = np.concatenate([g, fill], axis=1)
+                out.append(jnp.asarray(g))
+            return tuple(out)
 
     # ---------------------------------------------------------------- step
     def step(self) -> int:
@@ -246,46 +248,51 @@ class FoldInEngine:
         statistics are read-only; the returned deltas are dropped on the
         floor (fold-in never pushes).  Returns the number of live slots
         swept (0 = nothing to do)."""
-        if self.live == 0:
+        live = self.live
+        if live == 0:
             return 0
-        if self._layouts is None:
-            self._layouts = self.fam.build_sorted_layouts(
-                self.cfg, self._tokens, self._mask)
-        local2, _deltas = self.fam.sweep_sorted(
-            self.cfg, self._local, self.snap.shared, self.snap.tables,
-            self.snap.stale, self._tokens, self._mask,
-            jax.random.PRNGKey(0),  # unused: every chunk gets uniforms
-            self._layouts, chunk_uniforms=self._chunk_uniforms,
-            fold_in=True)
-        self._local = self.fam.local_project(local2)
-        n = 0
-        for slot in self._slots:
-            if slot is not None:
-                slot.age += 1
-                n += 1
-        self.sweeps_run += 1
-        return n
+        with jax.profiler.TraceAnnotation("repro.serve.step", live=live):
+            if self._layouts is None:
+                self._layouts = self.fam.build_sorted_layouts(
+                    self.cfg, self._tokens, self._mask)
+            local2, _deltas = self.fam.sweep_sorted(
+                self.cfg, self._local, self.snap.shared, self.snap.tables,
+                self.snap.stale, self._tokens, self._mask,
+                jax.random.PRNGKey(0),  # unused: every chunk gets uniforms
+                self._layouts, chunk_uniforms=self._chunk_uniforms,
+                fold_in=True)
+            self._local = self.fam.local_project(local2)
+            for slot in self._slots:
+                if slot is not None:
+                    slot.age += 1
+            self.sweeps_run += 1
+        return live
 
     # ------------------------------------------------------------- harvest
     def harvest(self) -> list[InferResult]:
         """Free every slot whose chain has mixed ``n_sweeps`` sweeps and
         return its topic proportions + final assignments."""
+        done = [j for j, slot in enumerate(self._slots)
+                if slot is not None and slot.age >= self.scfg.n_sweeps]
         out = []
-        ld = self.fam.local_dict(self._local)
-        n_dk = np.asarray(ld["n_dk"])
-        z = np.asarray(ld["z"])
-        for j, slot in enumerate(self._slots):
-            if slot is None or slot.age < self.scfg.n_sweeps:
-                continue
-            out.append(InferResult(
-                uid=slot.uid,
-                theta=_theta(self._prior, n_dk[j], slot.length),
-                assignments=z[j, :slot.length].copy(),
-                n_sweeps=slot.age))
-            self._slots[j] = None
-            self._mask = self._mask.at[j].set(False)
-            self._layouts = None
-            self.docs_harvested += 1
+        with jax.profiler.TraceAnnotation("repro.serve.harvest",
+                                          done=len(done)):
+            ld = self.fam.local_dict(self._local)
+            # The host waits here for the sweep's results on the device.
+            with jax.profiler.TraceAnnotation("repro.serve.fetch"):
+                n_dk = np.asarray(ld["n_dk"])
+                z = np.asarray(ld["z"])
+            for j in done:
+                slot = self._slots[j]
+                out.append(InferResult(
+                    uid=slot.uid,
+                    theta=_theta(self._prior, n_dk[j], slot.length),
+                    assignments=z[j, :slot.length].copy(),
+                    n_sweeps=slot.age))
+                self._slots[j] = None
+                self._mask = self._mask.at[j].set(False)
+                self._layouts = None
+                self.docs_harvested += 1
         return out
 
     # ----------------------------------------------------------------- run

@@ -43,6 +43,7 @@ import threading
 import time
 from typing import Any
 
+import jax
 import numpy as np
 
 from repro.launch.cache import enable_compile_cache
@@ -56,12 +57,14 @@ class _Ticket:
     """One in-flight request: the handler blocks on ``event`` while the
     batcher folds the document in."""
 
-    __slots__ = ("uid", "tokens", "seed", "event", "result", "error")
+    __slots__ = ("uid", "tokens", "seed", "queued", "event", "result",
+                 "error")
 
     def __init__(self, uid: int, tokens: np.ndarray, seed: int):
         self.uid = uid
         self.tokens = tokens
         self.seed = seed
+        self.queued = 0.0        # perf_counter() when enqueued
         self.event = threading.Event()
         self.result = None
         self.error: str | None = None
@@ -156,42 +159,43 @@ class InferenceServer:
         live: dict[int, _Ticket] = {}
         while not self._stop:
             if not pending and not live:
-                try:
-                    pending.append(self._queue.get(timeout=0.1))
-                except queue_mod.Empty:
-                    continue
-                # Batching window: give the rest of a concurrent burst a
-                # chance to share the first fused sweep.
-                deadline = time.monotonic() + self.max_batch_delay
-                while True:
-                    left = deadline - time.monotonic()
-                    if left <= 0:
-                        break
+                with jax.profiler.TraceAnnotation("repro.serve.wait"):
                     try:
-                        pending.append(self._queue.get(timeout=left))
+                        pending.append(self._queue.get(timeout=0.1))
                     except queue_mod.Empty:
-                        break
+                        continue
+                    # Batching window: give the rest of a concurrent burst
+                    # a chance to share the first fused sweep.
+                    deadline = time.monotonic() + self.max_batch_delay
+                    while True:
+                        left = deadline - time.monotonic()
+                        if left <= 0:
+                            break
+                        try:
+                            pending.append(self._queue.get(timeout=left))
+                        except queue_mod.Empty:
+                            break
             # Continuous admission: drain whatever fits right now.
             while self.engine.free_slots() > len(pending):
                 try:
                     pending.append(self._queue.get_nowait())
                 except queue_mod.Empty:
                     break
-            while pending:
-                t = pending[0]
+            while pending and self.engine.free_slots():
+                t = pending.popleft()
+                wait_us = (time.perf_counter() - t.queued) * 1e6
                 try:
-                    ok = self.engine.admit(InferRequest(
-                        uid=id(t), tokens=t.tokens, seed=t.seed))
+                    with jax.profiler.TraceAnnotation(
+                            "repro.serve.admit", uid=t.uid,
+                            queue_wait_us=wait_us):
+                        self.engine.admit(InferRequest(
+                            uid=id(t), tokens=t.tokens, seed=t.seed))
                 except ValueError as e:
                     # Backstop — handlers validate before enqueueing.
                     t.error = str(e)
                     t.event.set()
-                    pending.popleft()
                     continue
-                if not ok:
-                    break
                 live[id(t)] = t
-                pending.popleft()
             if not live:
                 continue
             self.engine.step()
@@ -287,6 +291,7 @@ class InferenceServer:
                     conn.send(MsgType.ERROR,
                               {"error": f"ValueError: {e}"})
                     break
+                ticket.queued = time.perf_counter()
                 try:
                     self._queue.put_nowait(ticket)
                 except queue_mod.Full:
