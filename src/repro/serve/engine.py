@@ -21,13 +21,18 @@ parity contract): a document's chain is a pure function of (snapshot,
 tokens, request seed) — independent of which slots it happens to share
 batches with.  The fused kernels make this possible because every
 per-token MH step consumes explicit uniform streams in sorted-stream
-order (``ops._step_uniforms``); the engine draws each slot's streams
-under the *single-document* layout geometry with the slot's own
-``fold_in(fold_in(PRNGKey(seed), sweep), chunk)`` key and permutes them
-into the batched sorted order.  The result is bit-identical to
-:func:`reference_fold_in` — the Trainer's sorted sweep run on a
-one-document shard with its pushes dropped — which is exactly what
-tests/test_serve_engine.py asserts per family.
+order (``ops._step_uniforms``).  Per chunk, one compiled program
+(:func:`_draw_uniforms`) draws every slot's streams at once: ``vmap`` of
+``ops._step_uniforms`` over the slots' keys, each slot under its own
+``fold_in(fold_in(PRNGKey(seed), sweep), chunk)`` key and at the
+*single-document* layout width, then one gather routes each batched
+sorted position to its slot's column through the slot's inverse
+single-document order.  The draw width depends only on the chunk's
+geometry, so it is the same for every slot and the streams stay on the
+device.  The result is bit-identical to :func:`reference_fold_in` — the
+Trainer's sorted sweep run on a one-document shard with its pushes
+dropped — which is exactly what tests/test_serve_engine.py asserts per
+family.
 
 A folded-in document is not counted in the frozen statistics, so its
 chain removes each token's own contribution from its doc row only
@@ -38,6 +43,7 @@ does would subtract a count that was never added.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 from typing import Any, Iterable, Sequence
 
@@ -90,13 +96,7 @@ class InferResult:
 class _Slot:
     uid: int
     length: int
-    key: Array               # PRNGKey(seed) — the request's chain root
     age: int                 # completed sweeps
-    # Per-chunk single-document geometry (order, padded width) — the
-    # layout reference_fold_in's sweep derives for a (1, L) shard, under
-    # which this slot's uniform streams are drawn.
-    orders: tuple[np.ndarray, ...]
-    widths: tuple[int, ...]
 
 
 def _theta(prior: np.ndarray, n_dk_row: np.ndarray, length: int
@@ -113,6 +113,58 @@ def result_checksum(res: InferResult) -> str:
     h.update(np.ascontiguousarray(res.assignments, np.int32).tobytes())
     h.update(np.ascontiguousarray(res.theta, np.float32).tobytes())
     return h.hexdigest()
+
+
+# Trace counts of the compiled draw, keyed by its signature: the
+# compile-stability guard (steady-state serving must not grow them).
+_UNIFORM_TRACES: dict[tuple[int, ...], int] = {}
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_outcomes", "mh_steps", "width", "clen", "bp"))
+def _draw_uniforms(keys: Array, ages: Array, live: Array, inv: Array,
+                   order: Array, c: Array, *, n_outcomes: int,
+                   mh_steps: int, width: int, clen: int, bp: int
+                   ) -> tuple[Array, ...]:
+    """Every slot's uniform streams for batched chunk ``c``, in the
+    batched sorted order.
+
+    keys (S, 2), ages (S,) and live (S,) describe the slots; ``inv``
+    (S, clen) holds each slot's inverse single-document order of the
+    chunk and ``order`` (S·clen,) the batched layout's.  Slot j draws
+    ``ops._step_uniforms`` at the single-document ``width`` under
+    ``fold_in(fold_in(key_j, age_j), c)``; batched position p takes
+    column ``inv[j, t]`` of slot j for ``q = order[p]``, j = q // clen,
+    t = q % clen.  Empty slots and the tail padding up to ``bp`` read
+    0 (slot stream) and 0.5 (the four uniforms); their outputs are
+    masked away."""
+    sig = (keys.shape[0], n_outcomes, mh_steps, width, clen, bp)
+    _UNIFORM_TRACES[sig] = _UNIFORM_TRACES.get(sig, 0) + 1
+
+    def draw(key, age):
+        ck = jax.random.fold_in(jax.random.fold_in(key, age), c)
+        return ops._step_uniforms(ck, n_outcomes, mh_steps, width)
+
+    slot = order // clen
+    col = slot * width + inv[slot, order % clen]
+    keep = live[slot]
+    out = []
+    for i, a in enumerate(jax.vmap(draw)(keys, ages)):   # (S, mh, width)
+        fill = 0 if i == 0 else 0.5
+        flat = jnp.swapaxes(a, 0, 1).reshape(mh_steps, -1)
+        g = jnp.where(keep, flat[:, col], fill)
+        out.append(jnp.pad(g, ((0, 0), (0, bp - order.shape[0])),
+                           constant_values=fill))
+    return tuple(out)
+
+
+@jax.jit
+def _admit_draw_state(keys: Array, inv: list[Array], j: Array, key: Array,
+                      orders: list[Array]) -> tuple[Array, list[Array]]:
+    """Slot ``j``'s rows of the draw state: its key and, per chunk, the
+    inverse of its single-document sorted order."""
+    return (keys.at[j].set(key),
+            [x.at[j].set(jnp.argsort(o)) for x, o in zip(inv, orders)])
 
 
 class FoldInEngine:
@@ -137,10 +189,30 @@ class FoldInEngine:
         self._prior = np.asarray(snap.topic_prior(), np.float32)
         n_chunks = max(1, min(self.cfg.sorted_chunks, l))
         self._bounds = segment.chunk_bounds(l, n_chunks)
+        clens = [e - b for b, e in zip(self._bounds, self._bounds[1:])]
+        # Per-chunk width of a (1, max_len) document's sorted layout: the
+        # chunk length padded to its batch tile.  A slot's streams are
+        # drawn at this width, the same for every slot.
+        tiles = [min(self.fam.sorted_tile_b(self.cfg), n) for n in clens]
+        self._widths = tuple(-(-n // t) * t for n, t in zip(clens, tiles))
+        # Device-side draw state: each slot's key and, per chunk, its
+        # inverse single-document order; rows are rewritten at admit.
+        key0 = jax.random.PRNGKey(0)
+        self._keys = jnp.zeros((s,) + key0.shape, key0.dtype)
+        self._inv = [jnp.zeros((s, n), jnp.int32) for n in clens]
+        self._draw_sigs: set[tuple[int, ...]] = set()
         # Counters for the benchmark/service layer.
         self.sweeps_run = 0
         self.docs_admitted = 0
         self.docs_harvested = 0
+
+    @property
+    def uniform_traces(self) -> int:
+        """Traces of the compiled draw over this engine's chunk shapes:
+        one per distinct shape, never more as live sets, lengths and ages
+        change.  The jit cache is shared, so another engine with equal
+        shapes reuses the traces."""
+        return sum(_UNIFORM_TRACES.get(sig, 0) for sig in self._draw_sigs)
 
     # ------------------------------------------------------------ occupancy
     @property
@@ -191,10 +263,10 @@ class FoldInEngine:
         # Single-doc sorted geometry per chunk: the inverse of these
         # orders routes the slot's uniform columns to flat positions.
         lays = self.fam.build_sorted_layouts(self.cfg, tok1, mask1)
-        self._slots[j] = _Slot(
-            uid=req.uid, length=int(toks.size), key=key, age=0,
-            orders=tuple(np.asarray(la.order) for la in lays),
-            widths=tuple(int(la.rows.shape[0]) for la in lays))
+        assert tuple(la.rows.shape[0] for la in lays) == self._widths
+        self._keys, self._inv = _admit_draw_state(
+            self._keys, self._inv, j, key, [la.order for la in lays])
+        self._slots[j] = _Slot(uid=req.uid, length=int(toks.size), age=0)
         self._layouts = None
         self.docs_admitted += 1
         return True
@@ -205,42 +277,20 @@ class FoldInEngine:
         """Per-request uniform streams for batched chunk ``c``: each live
         slot's streams are drawn under ITS single-doc geometry and key,
         mapped through its single-doc sorted order, then permuted into the
-        batched sorted order.  Empty slots get neutral values (their
-        outputs are masked away)."""
+        batched sorted order — one compiled program, no host round trip.
+        Empty slots get neutral values (their outputs are masked away)."""
         with jax.profiler.TraceAnnotation("repro.serve.uniforms",
                                           chunk=c):
-            s_chunk, e_chunk = self._bounds[c], self._bounds[c + 1]
-            clen = e_chunk - s_chunk
-            e_out = self.fam.n_outcomes(self.cfg)
-            mh = self.cfg.mh_steps
-            cols = []
-            for slot in self._slots:
-                if slot is None:
-                    cols.append((np.zeros((mh, clen), np.int32),)
-                                + tuple(np.full((mh, clen), 0.5, np.float32)
-                                        for _ in range(4)))
-                    continue
-                ck = jax.random.fold_in(
-                    jax.random.fold_in(slot.key, slot.age), c)
-                u = ops._step_uniforms(ck, e_out, mh, slot.widths[c])
-                order = slot.orders[c]
-                inv = np.empty(clen, np.int64)
-                inv[order] = np.arange(clen)
-                cols.append(tuple(np.asarray(a)[:, inv] for a in u))
-            # (mh, max_slots*clen) flat streams, slot-major like the grid.
-            flat = [np.concatenate([col[i] for col in cols], axis=1)
-                    for i in range(5)]
-            order_b = np.asarray(lay.order)
-            pad = int(lay.rows.shape[0]) - order_b.shape[0]
-            out = []
-            for i, f in enumerate(flat):
-                g = f[:, order_b]
-                if pad:
-                    fill = np.zeros((mh, pad), np.int32) if i == 0 else \
-                        np.full((mh, pad), 0.5, np.float32)
-                    g = np.concatenate([g, fill], axis=1)
-                out.append(jnp.asarray(g))
-            return tuple(out)
+            ages = np.asarray([0 if s is None else s.age
+                               for s in self._slots], np.int32)
+            live = np.asarray([s is not None for s in self._slots])
+            static = dict(n_outcomes=self.fam.n_outcomes(self.cfg),
+                          mh_steps=self.cfg.mh_steps, width=self._widths[c],
+                          clen=self._bounds[c + 1] - self._bounds[c],
+                          bp=lay.rows.shape[0])
+            self._draw_sigs.add((len(self._slots),) + tuple(static.values()))
+            return _draw_uniforms(self._keys, ages, live, self._inv[c],
+                                  lay.order, c, **static)
 
     # ---------------------------------------------------------------- step
     def step(self) -> int:

@@ -1,15 +1,22 @@
 """Fold-in serving engine tests (DESIGN.md §14): slot lifecycle,
 continuous batching, the bit-exact determinism contract vs the training
-code path, and batch-composition independence."""
+code path, batch-composition independence, and the compiled per-chunk
+uniform draw."""
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax._src.array import ArrayImpl
 
 from repro.core import family as fam_mod
+from repro.data import segment
 from repro.data.synthetic import CorpusConfig, make_topic_corpus
+from repro.kernels import ops
 from repro.serve import (FoldInEngine, InferRequest, ServeConfig,
                          fold_in_perplexity, freeze, reference_fold_in,
                          result_checksum)
@@ -166,6 +173,125 @@ def test_seed_changes_chain(snapshot):
     b = FoldInEngine(snapshot, scfg()).run(
         [InferRequest(uid=0, tokens=toks, seed=2)])[0]
     assert not np.array_equal(a.assignments, b.assignments)
+
+
+# ---------------------------------------------------------------------------
+# The compiled per-chunk uniform draw
+# ---------------------------------------------------------------------------
+
+def per_slot_uniforms(eng, requests, c, lay):
+    """The eager per-slot draw the compiled one replaces: each live slot's
+    streams drawn under its own key at its single-document layout width,
+    mapped through the inverse of its single-document sorted order,
+    concatenated slot-major and gathered into the batched sorted order."""
+    fam, cfg, l = eng.fam, eng.cfg, eng.scfg.max_len
+    bounds = segment.chunk_bounds(l, max(1, min(cfg.sorted_chunks, l)))
+    clen = bounds[c + 1] - bounds[c]
+    e_out, mh = fam.n_outcomes(cfg), cfg.mh_steps
+    cols = []
+    for slot in eng._slots:
+        if slot is None:
+            cols.append((np.zeros((mh, clen), np.int32),)
+                        + tuple(np.full((mh, clen), 0.5, np.float32)
+                                for _ in range(4)))
+            continue
+        req = requests[slot.uid]
+        row_tok = np.zeros((1, l), np.int32)
+        row_tok[0, :len(req.tokens)] = req.tokens
+        row_mask = np.zeros((1, l), bool)
+        row_mask[0, :len(req.tokens)] = True
+        lay1 = fam.build_sorted_layouts(cfg, jnp.asarray(row_tok),
+                                        jnp.asarray(row_mask))[c]
+        key = jax.random.fold_in(jax.random.fold_in(
+            jax.random.PRNGKey(req.seed), slot.age), c)
+        u = ops._step_uniforms(key, e_out, mh, int(lay1.rows.shape[0]))
+        inv = np.empty(clen, np.int64)
+        inv[np.asarray(lay1.order)] = np.arange(clen)
+        cols.append(tuple(np.asarray(a)[:, inv] for a in u))
+    order_b = np.asarray(lay.order)
+    pad = int(lay.rows.shape[0]) - order_b.shape[0]
+    out = []
+    for i in range(5):
+        g = np.concatenate([col[i] for col in cols], axis=1)[:, order_b]
+        fill = 0 if i == 0 else 0.5
+        out.append(np.concatenate(
+            [g, np.full((mh, pad), fill, g.dtype)], axis=1))
+    return out
+
+
+@pytest.mark.parametrize("max_len,tile_b", [(MAX_LEN, None), (30, None),
+                                            (30, 4)],
+                         ids=["equal_chunks", "unequal_chunks",
+                              "padded_width"])
+def test_chunk_uniforms_match_per_slot_draws(snapshot, max_len, tile_b):
+    """The compiled draw is bit-identical to the eager per-slot draw, on
+    every chunk, with empty slots, mixed ages and mixed lengths: chunks of
+    8 positions, of 8, 7, 7, 8 (max_len 30), and of widths padded past
+    the chunk length (single-document tile 4 over 7 positions → 8)."""
+    snap = snapshot if tile_b is None else freeze(
+        dataclasses.replace(snapshot.cfg, tile_b=tile_b), snapshot.shared)
+    eng = FoldInEngine(snap, scfg(max_slots=4, max_len=max_len,
+                                  n_sweeps=2))
+    reqs = {r.uid: r for r in make_reqs(snap, 4, seed=31, max_len=max_len)}
+
+    def check():
+        lays = eng.fam.build_sorted_layouts(eng.cfg, eng._tokens, eng._mask)
+        for c, lay in enumerate(lays):
+            got = eng._chunk_uniforms(c, lay, 0)
+            want = per_slot_uniforms(eng, reqs, c, lay)
+            for g, w in zip(got, want):
+                assert g.dtype == w.dtype
+                np.testing.assert_array_equal(np.asarray(g), w)
+
+    assert eng.admit(reqs[0]) and eng.admit(reqs[1])
+    check()                                  # ages 0, 0; two empty slots
+    eng.step()
+    assert eng.admit(reqs[2])
+    check()                                  # ages 1, 1, 0; one empty
+    eng.step()
+    assert sorted(r.uid for r in eng.harvest()) == [0, 1]
+    assert eng.admit(reqs[3])
+    check()                                  # slot 0 age 0, slot 2 age 1
+
+
+def test_uniform_traces_one_per_chunk_shape(snapshot):
+    """Changing live sets, lengths and ages never retrace the draw: after
+    many admit/step/harvest cycles there is one trace per distinct chunk
+    shape (max_len 30: chunks of 8 and 7 positions → 2)."""
+    eng = FoldInEngine(snapshot, scfg(max_slots=3, max_len=30, n_sweeps=2))
+    assert eng.uniform_traces == 0
+    eng.run(make_reqs(snapshot, 7, seed=41, min_len=1, max_len=30))
+    assert eng.sweeps_run >= 6
+    assert eng.uniform_traces == 2
+    eng.run(make_reqs(snapshot, 2, seed=42, max_len=30))
+    assert eng.uniform_traces == 2
+
+
+def test_step_makes_no_device_to_host_transfer(snapshot, monkeypatch):
+    """A step queues its work and returns: no stream data comes back to
+    the host.  The CPU backend hands an array to numpy through the buffer
+    protocol, which the transfer guard does not see, so numpy's
+    conversions and the array's own host value are made to raise too."""
+    eng = FoldInEngine(snapshot, scfg())
+    for req in make_reqs(snapshot, 3, seed=51):
+        assert eng.admit(req)
+    eng.step()                               # compile outside the guard
+
+    def refuse(convert):
+        def guarded(a, *args, **kw):
+            if isinstance(a, jax.Array):
+                raise AssertionError("device-to-host copy inside step")
+            return convert(a, *args, **kw)
+        return guarded
+
+    monkeypatch.setattr(np, "asarray", refuse(np.asarray))
+    monkeypatch.setattr(np, "array", refuse(np.array))
+    monkeypatch.setattr(ArrayImpl, "_value",
+                        property(refuse(ArrayImpl._value.fget)))
+    with jax.transfer_guard_device_to_host("disallow"):
+        assert eng.step() == 3
+    monkeypatch.undo()
+    assert eng.harvest() == [] and eng.sweeps_run == 2
 
 
 # ---------------------------------------------------------------------------
