@@ -256,7 +256,7 @@ def sorted_chain(prob: Array, alias: Array, mass: Array, stale: Array,
                  n_wk: Array, n_k: Array, prior: Array, rows: Array,
                  z0: Array, ndk: Array, slot: Array, coin: Array,
                  u_mix: Array, u_sparse: Array, u_acc: Array, *, beta: float,
-                 beta_bar: float) -> Array:
+                 beta_bar: float, fold_in: bool = False) -> Array:
     """Whole-shard MH chain over the token-sorted stream — lm families.
 
     Pure-jnp reference semantics of ``kernels.mhw_fused.mhw_sweep_fused``
@@ -269,7 +269,9 @@ def sorted_chain(prob: Array, alias: Array, mass: Array, stale: Array,
     prob/alias/stale/n_wk: (V, K); mass: (V,); n_k/prior: (K,); rows/z0:
     (B,); ndk: (B, K) *raw* gathered doc rows (the ^{-di} own-token removal
     happens here, as in the kernel); slot/coin/u_mix/u_sparse/u_acc:
-    (S, B) per-step uniforms.  Returns (B,) int32.
+    (S, B) per-step uniforms.  ``fold_in`` (serving): the documents are not
+    counted in n_wk / n_k, so the own-token removal touches ndk only.
+    Returns (B,) int32.
     """
     v, k_topics = prob.shape
     real = rows < v
@@ -279,7 +281,8 @@ def sorted_chain(prob: Array, alias: Array, mass: Array, stale: Array,
     own = ((karange == z0[:, None]) & real[:, None]).astype(jnp.float32)
     ndk = ndk - own
     rows_wk = n_wk[r]
-    lm = (rows_wk - own + beta) / (n_k[None, :] - own + beta_bar)
+    own_wk = 0.0 if fold_in else own
+    lm = (rows_wk - own_wk + beta) / (n_k[None, :] - own_wk + beta_bar)
 
     z = mix_chain(z0, doc=ndk, prior=prior, logf=jnp.log(lm + _EPS),
                   sparse_w=ndk * lm, stale_rows=stale[r], prob_rows=prob[r],

@@ -5,9 +5,9 @@ the corpus *word-major*: all draws of token-type ``w`` are resolved while
 ``n_wk[w]`` (and its alias table row) is hot.  On TPU the same idea becomes
 a **sorted layout**: flatten a shard's (D, L) token grid, sort the flat
 stream by token-type once per sweep, and hand the kernels a per-batch-tile
-*vocab-tile window* so every (vocab-tile, batch-tile) grid program whose
-tile holds zero resident draws is skipped via scalar prefetch
-(DESIGN.md §5).
+*vocab-tile window*, from which they walk only the (batch-tile,
+vocab-tile) pairs that hold draws (``kernels.mhw_fused.work_list``,
+DESIGN.md §5).
 
 Because the sort key is the token-type, the vocab tiles touched by any one
 batch tile of the sorted stream form a contiguous range — ``vstart[bi]`` to

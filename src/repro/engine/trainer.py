@@ -103,6 +103,7 @@ from repro.core import ps
 from repro.core import server as server_mod
 from repro.data.synthetic import shard_corpus
 from repro.engine import round as round_mod
+from repro.kernels import mhw_fused
 
 Array = jax.Array
 
@@ -353,6 +354,20 @@ class Trainer:
                 self.family.build_sorted_layouts(model_cfg, t, m)
                 if c in local_set else None
                 for c, (t, m) in enumerate(self.shards))
+        # Static counters of the fused sweep kernel's grid over one round's
+        # chunks: the steps it walks, and the (batch tile, vocab tile)
+        # pairs among them that hold draws.
+        self.sweep_grid_steps = self.sweep_live_pairs = 0
+        if self.layouts is not None:
+            e = self.family.n_outcomes(model_cfg)
+            n_ktiles = e // min(self.family.sorted_tile_k(model_cfg) or e, e)
+            lays = [lay for ls in self.layouts if ls is not None
+                    for lay in ls]
+            self.sweep_grid_steps = n_ktiles * sum(
+                mhw_fused.n_pairs(lay.vstart.shape[0], lay.hist.shape[0])
+                for lay in lays)
+            self.sweep_live_pairs = int(sum(jnp.sum(lay.vcount)
+                                            for lay in lays))
 
         self.alias_refresh_every = (
             config.alias_refresh_every

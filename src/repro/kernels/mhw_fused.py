@@ -16,9 +16,14 @@ paper §3 retires in a single residency:
 
 Unfused, steps 2–3 are five HBM round trips per MH step (proposal draw,
 two q gathers, two p gathers) plus a fresh statistics gather per token;
-fused they are VMEM reads.  Grid programs outside a batch tile's vocab
-window are skipped via scalar prefetch: the table index map re-points at
-the last resident tile (no DMA) and ``pl.when`` skips the body.
+fused they are VMEM reads.  The grid does not walk the dense (batch tile,
+vocab tile) product, almost all of whose pairs hold no draw at a large
+vocabulary: it walks a scalar-prefetched work list of the pairs inside
+each batch tile's vocab window (:func:`work_list`), with the K-tile
+staging axis minor.  Its non-live entries (one per all-padding batch tile,
+and the fill past the end) keep the previous step's table block — same
+vocab tile, last K tile — so the pipeline issues no copy for them, and
+``pl.when`` skips their body.
 
 Every data-dependent read is written in a form Mosaic lowers: the per-token
 table rows are a one-hot (TILE_B, TILE_V) × (TILE_V, tile_k) MXU
@@ -75,31 +80,65 @@ def _vmem_limit(tile_b: int, e: int, tile_v: int, tile_k: int) -> int:
     return int(min(max(2 * need, _VMEM_FLOOR), _VMEM_CEIL))
 
 
-def _index_maps(nv: int):
-    """BlockSpec index maps shared by both sorted-layout kernels: per-batch
-    blocks, per-step uniform blocks, whole-array residents, and the
-    scalar-prefetched vocab-tile-window maps (the tile-skip re-point) over
-    the K-tile staging axis (grid axis 2, minor)."""
-    def vtile(bi, vi, vs, vc):
-        return jnp.clip(vs[bi] + jnp.minimum(vi, vc[bi] - 1), 0, nv - 1)
+def n_pairs(nb: int, nv: int) -> int:
+    """Static length of :func:`work_list` for nb batch tiles over nv vocab
+    tiles.  Rows are sorted, so consecutive batch tiles' windows share at
+    most their boundary vocab tile: Σ max(vcount, 1) ≤ nb + nv − 1."""
+    return nb + nv
 
-    def bmap(bi, vi, ki, vs, vc):
-        return (0, bi)
 
-    def bmap2(bi, vi, ki, vs, vc):
-        return (bi, 0)
+def work_list(vstart: jax.Array, vcount: jax.Array, nv: int):
+    """The fused kernels' grid: one entry per (batch tile, vocab tile) pair
+    inside a batch tile's window, batch-tile major, then non-live fill up
+    to :func:`n_pairs`.
 
-    def fullmap(bi, vi, ki, vs, vc):
+    Returns four (n_pairs,) int32 arrays: ``pair_b`` (batch tile),
+    ``pair_t`` (vocab tile), ``live`` (1 where the pair holds draws) and
+    ``first`` (1 on a batch tile's first entry, where its output block is
+    initialised).  A batch tile with ``vcount == 0`` (all padding) gets
+    one non-live entry, so its output still starts from the chain init.
+    A non-live entry carries the vocab tile of the entry before it, so
+    its table blocks do not change and the pipeline copies nothing.
+    Requires windows of a sorted stream (``segment.build_layout``).
+    """
+    nb = vstart.shape[0]
+    p = jnp.arange(n_pairs(nb, nv), dtype=jnp.int32)
+    cnt = jnp.maximum(vcount, 1)
+    ends = jnp.cumsum(cnt)
+    bi = jnp.minimum(jnp.searchsorted(ends, p, side="right"),
+                     nb - 1).astype(jnp.int32)
+    j = p - (ends[bi] - cnt[bi])                  # entry within its tile
+    in_list = p < ends[-1]
+    live = in_list & (j < vcount[bi])
+    first = in_list & (j == 0)
+    t = jnp.clip(vstart[bi] + jnp.minimum(j, vcount[bi] - 1), 0, nv - 1)
+    t = t[jax.lax.cummax(jnp.where(live, p, 0))]  # non-live: carry forward
+    return (bi, t.astype(jnp.int32), live.astype(jnp.int32),
+            first.astype(jnp.int32))
+
+
+def _index_maps(nk: int):
+    """BlockSpec index maps shared by both sorted-layout kernels over the
+    (work-list entry, K tile) grid: per-batch-tile blocks, whole-array
+    residents, and the (vocab tile, K tile) table blocks, which a non-live
+    entry holds at the previous step's block."""
+    def bmap(p, ki, pb, pt, live, first):
+        return (0, pb[p])
+
+    def bmap2(p, ki, pb, pt, live, first):
+        return (pb[p], 0)
+
+    def fullmap(p, ki, pb, pt, live, first):
         return (0, 0)
 
-    def vmapk(bi, vi, ki, vs, vc):
+    def vmapk(p, ki, pb, pt, live, first):
         # (vocab-tile, k-tile) table block — the (tile_v, tile_k) residency
         # that replaces the (tile_v, K) one.
-        return (vtile(bi, vi, vs, vc), ki)
+        return (pt[p], jnp.where(live[p] > 0, ki, nk - 1))
 
-    def vmap_mass(bi, vi, ki, vs, vc):
+    def vmap_mass(p, ki, pb, pt, live, first):
         # mass viewed as (nv, 1, tile_v): one lane-major row per vocab tile.
-        return (vtile(bi, vi, vs, vc), 0, 0)
+        return (pt[p], 0, 0)
 
     return bmap, bmap2, fullmap, vmapk, vmap_mass
 
@@ -136,25 +175,23 @@ def _dense_mass(onehot, mass_row):
     return jnp.sum(jnp.where(onehot, mass_row, 0.0), axis=-1)
 
 
-def _mhw_fused_kernel(vstart_ref, vcount_ref, rows_ref, z_ref, ndk_ref,
-                      slot_ref, coin_ref, umix_ref, usp_ref, uacc_ref,
-                      prob_ref, alias_ref, mass_ref, stale_ref, nwk_ref,
-                      nk_ref, prior_ref, out_ref, nwk_s, stale_s, prob_s,
-                      alias_s, *, tile_v: int, n_vtiles: int, tile_k: int,
+def _mhw_fused_kernel(pb_ref, pt_ref, live_ref, first_ref, rows_ref, z_ref,
+                      ndk_ref, slot_ref, coin_ref, umix_ref, usp_ref,
+                      uacc_ref, prob_ref, alias_ref, mass_ref, stale_ref,
+                      nwk_ref, nk_ref, prior_ref, out_ref, nwk_s, stale_s,
+                      prob_s, alias_s, *, tile_v: int, tile_k: int,
                       n_ktiles: int, beta: float, beta_bar: float,
                       fold_in: bool):
-    bi = pl.program_id(0)
-    vi = pl.program_id(1)
-    ki = pl.program_id(2)
-    tid = jnp.clip(vstart_ref[bi] + jnp.minimum(vi, vcount_ref[bi] - 1),
-                   0, n_vtiles - 1)
-    in_tile, onehot = _tile_onehot(_vec(rows_ref), tid * tile_v, tile_v)
+    p = pl.program_id(0)
+    ki = pl.program_id(1)
+    live = live_ref[p] > 0
+    in_tile, onehot = _tile_onehot(_vec(rows_ref), pt_ref[p] * tile_v, tile_v)
 
-    @pl.when((vi == 0) & (ki == 0))
+    @pl.when((first_ref[p] > 0) & (ki == 0))
     def _init():
         out_ref[...] = z_ref[...]
 
-    @pl.when(vi < vcount_ref[bi])
+    @pl.when(live)
     def _stage():
         # Stage this (tile_v, tile_k) table block's per-token rows into the
         # full-K VMEM scratch.  Pure data movement: column tiles of the
@@ -166,7 +203,7 @@ def _mhw_fused_kernel(vstart_ref, vcount_ref, rows_ref, z_ref, ndk_ref,
         prob_s[:, ksl] = _gather_rows(onehot, prob_ref[...])
         alias_s[:, ksl] = _gather_rows(onehot, alias_ref[...])
 
-    @pl.when((vi < vcount_ref[bi]) & (ki == n_ktiles - 1))
+    @pl.when(live & (ki == n_ktiles - 1))
     def _body():
         z0 = _vec(z_ref)                           # (TILE_B,) chain init
         k_topics = ndk_ref.shape[-1]
@@ -229,7 +266,8 @@ def mhw_sweep_fused(prob: jax.Array, alias: jax.Array, mass: jax.Array,
     ^{-di} removal happens in-kernel).  slot/coin/u_mix/u_sparse/u_acc:
     (n_steps, B) per-MH-step uniforms (slot is int32 in [0, K)).
     vstart/vcount: (B/tile_b,) vocab-tile windows from
-    ``segment.build_layout``.  Returns (B,) int32 final states.
+    ``segment.build_layout``; the grid walks their :func:`work_list`.
+    Returns (B,) int32 final states.
 
     ``tile_k`` (None ⇒ K) adds the K-tile *staging* axis: the (V, K)
     tables stream through VMEM in (tile_v, tile_k) blocks whose per-token
@@ -251,14 +289,14 @@ def mhw_sweep_fused(prob: jax.Array, alias: jax.Array, mass: jax.Array,
     if beta_bar is None:
         beta_bar = beta * v
 
-    kernel = functools.partial(_mhw_fused_kernel, tile_v=tile_v, n_vtiles=nv,
+    kernel = functools.partial(_mhw_fused_kernel, tile_v=tile_v,
                                tile_k=tile_k, n_ktiles=nk,
                                beta=beta, beta_bar=beta_bar, fold_in=fold_in)
-    bmap, bmap2, fullmap, vmapk, vmap_mass = _index_maps(nv)
+    bmap, bmap2, fullmap, vmapk, vmap_mass = _index_maps(nk)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(nb, nv, nk),
+        num_scalar_prefetch=4,
+        grid=(n_pairs(nb, nv), nk),
         in_specs=[
             pl.BlockSpec((1, tile_b), bmap),           # rows
             pl.BlockSpec((1, tile_b), bmap),           # z0
@@ -292,7 +330,8 @@ def mhw_sweep_fused(prob: jax.Array, alias: jax.Array, mass: jax.Array,
             vmem_limit_bytes=_vmem_limit(tile_b, k, tile_v, tile_k)),
         name="mhw_sweep_fused",
         interpret=backend.interpret("mhw_sweep_fused", requested=interpret),
-    )(vstart, vcount, rows.reshape(1, b), z0.reshape(1, b), ndk, slot, coin,
+    )(*work_list(vstart, vcount, nv), rows.reshape(1, b), z0.reshape(1, b),
+      ndk, slot, coin,
       u_mix, u_sparse, u_acc, prob, alias, mass.reshape(nv, 1, tile_v),
       stale, n_wk, n_k.reshape(1, -1), prior.reshape(1, -1))[0]
 
@@ -302,25 +341,22 @@ def mhw_sweep_fused(prob: jax.Array, alias: jax.Array, mass: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _pdp_fused_kernel(vstart_ref, vcount_ref, rows_ref, e_ref, ownf0_ref,
-                      ownf1_ref, ndk_ref, slot_ref, coin_ref, umix_ref,
-                      usp_ref, uacc_ref, prob_ref, alias_ref, mass_ref,
-                      stale_ref, logf_ref, prior_ref, out_ref, logf_s,
-                      stale_s, prob_s, alias_s, *, tile_v: int,
-                      n_vtiles: int, tile_k: int, n_etiles: int,
-                      fold_in: bool):
-    bi = pl.program_id(0)
-    vi = pl.program_id(1)
-    ei = pl.program_id(2)          # e-tile over the 2K joint outcomes
-    tid = jnp.clip(vstart_ref[bi] + jnp.minimum(vi, vcount_ref[bi] - 1),
-                   0, n_vtiles - 1)
-    in_tile, onehot = _tile_onehot(_vec(rows_ref), tid * tile_v, tile_v)
+def _pdp_fused_kernel(pb_ref, pt_ref, live_ref, first_ref, rows_ref, e_ref,
+                      ownf0_ref, ownf1_ref, ndk_ref, slot_ref, coin_ref,
+                      umix_ref, usp_ref, uacc_ref, prob_ref, alias_ref,
+                      mass_ref, stale_ref, logf_ref, prior_ref, out_ref,
+                      logf_s, stale_s, prob_s, alias_s, *, tile_v: int,
+                      tile_k: int, n_etiles: int, fold_in: bool):
+    p = pl.program_id(0)
+    ei = pl.program_id(1)          # e-tile over the 2K joint outcomes
+    live = live_ref[p] > 0
+    in_tile, onehot = _tile_onehot(_vec(rows_ref), pt_ref[p] * tile_v, tile_v)
 
-    @pl.when((vi == 0) & (ei == 0))
+    @pl.when((first_ref[p] > 0) & (ei == 0))
     def _init():
         out_ref[...] = e_ref[...]
 
-    @pl.when(vi < vcount_ref[bi])
+    @pl.when(live)
     def _stage():
         # The (V, 2K) joint-outcome tables stream one e-tile per step.
         esl = pl.ds(ei * tile_k, tile_k)
@@ -329,7 +365,7 @@ def _pdp_fused_kernel(vstart_ref, vcount_ref, rows_ref, e_ref, ownf0_ref,
         prob_s[:, esl] = _gather_rows(onehot, prob_ref[...])
         alias_s[:, esl] = _gather_rows(onehot, alias_ref[...])
 
-    @pl.when((vi < vcount_ref[bi]) & (ei == n_etiles - 1))
+    @pl.when(live & (ei == n_etiles - 1))
     def _body():
         e0 = _vec(e_ref)                           # (TILE_B,) joint outcome
         k_topics = ndk_ref.shape[-1]
@@ -411,13 +447,13 @@ def pdp_sweep_fused(prob: jax.Array, alias: jax.Array, mass: jax.Array,
     own_f0, own_f1 = own_log_factors(stirl, m_wk, s_wk, m_k, s_k, rows, e0,
                                      **hyper)
 
-    kernel = functools.partial(_pdp_fused_kernel, tile_v=tile_v, n_vtiles=nv,
+    kernel = functools.partial(_pdp_fused_kernel, tile_v=tile_v,
                                tile_k=tile_k, n_etiles=ne, fold_in=fold_in)
-    bmap, bmap2, fullmap, vmapk, vmap_mass = _index_maps(nv)
+    bmap, bmap2, fullmap, vmapk, vmap_mass = _index_maps(ne)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(nb, nv, ne),
+        num_scalar_prefetch=4,
+        grid=(n_pairs(nb, nv), ne),
         in_specs=[
             pl.BlockSpec((1, tile_b), bmap),            # rows
             pl.BlockSpec((1, tile_b), bmap),            # e0
@@ -452,6 +488,7 @@ def pdp_sweep_fused(prob: jax.Array, alias: jax.Array, mass: jax.Array,
             vmem_limit_bytes=_vmem_limit(tile_b, e_out, tile_v, tile_k)),
         name="pdp_sweep_fused",
         interpret=backend.interpret("pdp_sweep_fused", requested=interpret),
-    )(vstart, vcount, *(x.reshape(1, bsz) for x in (rows, e0, own_f0, own_f1)),
+    )(*work_list(vstart, vcount, nv),
+      *(x.reshape(1, bsz) for x in (rows, e0, own_f0, own_f1)),
       ndk, slot, coin, u_mix, u_sparse, u_acc, prob, alias,
       mass.reshape(nv, 1, tile_v), stale, log_f, prior.reshape(1, -1))[0]

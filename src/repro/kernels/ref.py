@@ -59,13 +59,14 @@ def alias_sample_sorted_ref(prob: jax.Array, alias: jax.Array,
 
 def mhw_sweep_sorted_ref(prob, alias, mass, stale, n_wk, n_k, prior, rows,
                          z0, ndk, slot, coin, u_mix, u_sparse, u_acc, *,
-                         beta, beta_bar):
+                         beta, beta_bar, fold_in=False):
     """Oracle for ``kernels.mhw_fused.mhw_sweep_fused`` (lm families:
     LDA with prior = α·1, HDP with prior = b1·θ0) — delegates to the
     pure-jnp chain semantics owned by ``repro.core.mhw``."""
     return mhw_mod.sorted_chain(prob, alias, mass, stale, n_wk, n_k, prior,
                                 rows, z0, ndk, slot, coin, u_mix, u_sparse,
-                                u_acc, beta=beta, beta_bar=beta_bar)
+                                u_acc, beta=beta, beta_bar=beta_bar,
+                                fold_in=fold_in)
 
 
 def pdp_sweep_sorted_ref(prob, alias, mass, stale, m_wk, s_wk, m_k, s_k,

@@ -42,6 +42,16 @@ def _windows(rows, v, tile_v, tile_b):
     return jnp.asarray(vstart), jnp.asarray(vcount)
 
 
+def _case(*dims, tile_k=None, fold_in=None):
+    """A fused-kernel case: its dims, then ``tile_k``, then ``fold_in``
+    where the test takes it.  Named by its dims, plus ``tk<n>`` and
+    ``fold_in`` when set, so the untiled training cases keep their ids."""
+    flags = () if fold_in is None else (fold_in,)
+    tags = [str(d) for d in dims] + ([f"tk{tile_k}"] if tile_k else []) \
+        + (["fold_in"] if fold_in else [])
+    return pytest.param(*dims, tile_k, *flags, id="-".join(tags))
+
+
 @pytest.mark.parametrize("v,k,b,tile_v,tile_b,lo,hi,n_pad", [
     (64, 32, 512, 16, 128, 0, 64, 0),      # dense occupancy
     (128, 16, 256, 16, 64, 32, 48, 0),     # one narrow band: most tiles empty
@@ -63,14 +73,72 @@ def test_alias_sample_sorted_exact(v, k, b, tile_v, tile_b, lo, hi, n_pad):
     assert bool(jnp.all(out_k == out_r))
 
 
+def _work_rows(case, v, b):
+    key = jax.random.PRNGKey(b)
+    if case == "padding-tail":
+        return _sorted_rows(key, b, 0, v, v, n_pad=200)
+    if case == "one-vocab-tile":
+        return _sorted_rows(key, b, 16, 24, v)
+    if case == "spans-every-tile":
+        return jnp.sort(jnp.arange(b, dtype=jnp.int32) % v)
+    return jnp.full((b,), v, jnp.int32)          # all padding
+
+
+@pytest.mark.parametrize("case,b,tile_b", [
+    ("padding-tail", 512, 64),
+    ("one-vocab-tile", 256, 64),
+    ("spans-every-tile", 128, 128),
+    ("all-padding", 256, 64),
+])
+def test_work_list(case, b, tile_b):
+    """The fused kernels' grid lists every (batch tile, vocab tile) pair of
+    each window once, batch-tile major; every batch tile has an entry that
+    initialises its output; and no non-live entry moves a table block, so
+    the pipeline copies nothing for it."""
+    v, tile_v, nk = 64, 8, 4
+    nb, nv = b // tile_b, v // tile_v
+    vstart, vcount = _windows(_work_rows(case, v, b), v, tile_v, tile_b)
+    pb, pt, live, first = (np.asarray(a) for a in
+                           mhw_fused.work_list(vstart, vcount, nv))
+    vs, vc = np.asarray(vstart), np.asarray(vcount)
+    n = mhw_fused.n_pairs(nb, nv)
+    assert pb.shape == pt.shape == live.shape == first.shape == (n,)
+
+    want = [(bi, t) for bi in range(nb) for t in range(vs[bi], vs[bi] + vc[bi])]
+    got = [(int(b_), int(t)) for b_, t, lv in zip(pb, pt, live) if lv]
+    assert got == want
+    assert len(got) <= nb + nv - 1 <= n
+    # one first entry per batch tile, ahead of its other entries
+    assert sorted(pb[first == 1].tolist()) == list(range(nb))
+    for bi in range(nb):
+        assert first[np.flatnonzero(pb == bi)[0]] == 1
+    assert np.all(np.diff(pb) >= 0)
+
+    # each step's table block, through the kernels' own index map
+    vmapk = mhw_fused._index_maps(nk)[3]
+    prev = None
+    for p in range(n):
+        for ki in range(nk):
+            blk = tuple(int(x) for x in vmapk(p, ki, pb, pt, live, first))
+            if not live[p] and prev is not None:
+                assert blk == prev, (p, ki)
+            prev = blk
+
+
 @pytest.mark.parametrize("prior_kind", ["lda", "hdp"])
-@pytest.mark.parametrize("v,k,b,tile_v,tile_b,lo,hi,n_pad,steps", [
-    (60, 16, 384, 12, 128, 0, 60, 0, 2),
-    (120, 32, 256, 12, 64, 24, 60, 0, 3),   # most vocab tiles empty
-    (60, 16, 256, 12, 64, 0, 7, 61, 2),     # skew + padding
+@pytest.mark.parametrize("v,k,b,tile_v,tile_b,lo,hi,n_pad,steps,tile_k,"
+                         "fold_in", [
+    _case(60, 16, 384, 12, 128, 0, 60, 0, 2, fold_in=False),
+    # most vocab tiles empty
+    _case(120, 32, 256, 12, 64, 24, 60, 0, 3, fold_in=False),
+    _case(60, 16, 256, 12, 64, 0, 7, 61, 2, fold_in=False),  # skew + padding
+    # K-tiled over mostly-empty vocab tiles with all-padding batch tiles
+    _case(240, 32, 512, 12, 64, 24, 60, 200, 2, tile_k=8, fold_in=False),
+    # serving: folded-in documents, own token removed from ndk only
+    _case(120, 32, 256, 12, 64, 24, 60, 70, 3, tile_k=16, fold_in=True),
 ])
 def test_mhw_fused_kernel_vs_oracle(v, k, b, tile_v, tile_b, lo, hi, n_pad,
-                                    steps, prior_kind):
+                                    steps, tile_k, fold_in, prior_kind):
     """The fused draw+accept kernel is bit-identical to mhw.sorted_chain —
     with the uniform LDA prior α·1 and a non-uniform HDP prior b1·θ0."""
     key = jax.random.PRNGKey(v * k + b)
@@ -102,10 +170,11 @@ def test_mhw_fused_kernel_vs_oracle(v, k, b, tile_v, tile_b, lo, hi, n_pad,
     out_k = mhw_fused.mhw_sweep_fused(
         tabs.prob, tabs.alias, tabs.mass, stale, n_wk, n_k, prior, rows, z0,
         ndk, slot, *uni, vstart, vcount, tile_v=tile_v, tile_b=tile_b,
-        n_steps=steps, beta=beta, beta_bar=beta_bar)
+        tile_k=tile_k, n_steps=steps, beta=beta, beta_bar=beta_bar,
+        fold_in=fold_in)
     out_r = ref.mhw_sweep_sorted_ref(
         tabs.prob, tabs.alias, tabs.mass, stale, n_wk, n_k, prior, rows, z0,
-        ndk, slot, *uni, beta=beta, beta_bar=beta_bar)
+        ndk, slot, *uni, beta=beta, beta_bar=beta_bar, fold_in=fold_in)
     assert bool(jnp.all(out_k == out_r)), \
         f"{int(jnp.sum(out_k != out_r))} of {b} draws differ"
     # padding sentinels keep their init state
@@ -113,13 +182,15 @@ def test_mhw_fused_kernel_vs_oracle(v, k, b, tile_v, tile_b, lo, hi, n_pad,
         assert bool(jnp.all(out_k[-n_pad:] == z0[-n_pad:]))
 
 
-@pytest.mark.parametrize("v,k,b,tile_v,tile_b,lo,hi,n_pad,steps", [
-    (64, 8, 384, 16, 128, 0, 64, 0, 2),
-    (128, 8, 256, 16, 64, 32, 48, 0, 3),    # most vocab tiles empty
-    (64, 8, 256, 16, 64, 0, 9, 47, 2),      # skew + padding
+@pytest.mark.parametrize("v,k,b,tile_v,tile_b,lo,hi,n_pad,steps,tile_k", [
+    _case(64, 8, 384, 16, 128, 0, 64, 0, 2),
+    _case(128, 8, 256, 16, 64, 32, 48, 0, 3),    # most vocab tiles empty
+    _case(64, 8, 256, 16, 64, 0, 9, 47, 2),      # skew + padding
+    # e-tiled over mostly-empty vocab tiles with all-padding batch tiles
+    _case(256, 8, 512, 16, 64, 40, 72, 200, 2, tile_k=4),
 ])
 def test_pdp_fused_kernel_vs_oracle(v, k, b, tile_v, tile_b, lo, hi, n_pad,
-                                    steps):
+                                    steps, tile_k):
     """The fused PDP kernel (2K joint outcomes, in-VMEM Stirling factors)
     is bit-identical to pdp.sorted_chain_pdp."""
     key = jax.random.PRNGKey(v * k + b + 1)
@@ -147,8 +218,9 @@ def test_pdp_fused_kernel_vs_oracle(v, k, b, tile_v, tile_b, lo, hi, n_pad,
     out_k = mhw_fused.pdp_sweep_fused(
         tabs.prob, tabs.alias, tabs.mass, stale, m_wk, s_wk, shared.m_k,
         shared.s_k, stirl, prior, rows, e0, ndk, slot, *uni, vstart, vcount,
-        tile_v=tile_v, tile_b=tile_b, n_steps=steps, b_conc=cfg.concentration,
-        a_disc=cfg.discount, gamma=cfg.gamma, gamma_bar=cfg.gamma * v)
+        tile_v=tile_v, tile_b=tile_b, tile_k=tile_k, n_steps=steps,
+        b_conc=cfg.concentration, a_disc=cfg.discount, gamma=cfg.gamma,
+        gamma_bar=cfg.gamma * v)
     out_r = ref.pdp_sweep_sorted_ref(
         tabs.prob, tabs.alias, tabs.mass, stale, m_wk, s_wk, shared.m_k,
         shared.s_k, stirl, prior, rows, e0, ndk, slot, *uni,
@@ -372,7 +444,7 @@ def test_family_sorted_matches_scan_perplexity(name):
 # K-tiling: the tile_k staging axis (DESIGN.md §12)
 # ---------------------------------------------------------------------------
 
-def _mhw_inputs(v=60, k=16, b=256, lo=0, hi=60, steps=2):
+def _mhw_inputs(v=60, k=16, b=256, lo=0, hi=60, steps=2, n_pad=0):
     key = jax.random.PRNGKey(v * k + b)
     alpha, beta = 0.1, 0.01
     beta_bar = beta * v
@@ -381,7 +453,7 @@ def _mhw_inputs(v=60, k=16, b=256, lo=0, hi=60, steps=2):
     prior = jnp.full((k,), alpha, jnp.float32)
     stale = prior[None, :] * (n_wk + beta) / (n_k[None, :] + beta_bar)
     tabs = ops.build_tables(stale, tile_r=segment.pick_tile(v, 8))
-    rows = _sorted_rows(jax.random.fold_in(key, 1), b, lo, hi, v)
+    rows = _sorted_rows(jax.random.fold_in(key, 1), b, lo, hi, v, n_pad)
     z0 = jax.random.randint(jax.random.fold_in(key, 2), (b,), 0, k,
                             jnp.int32)
     ndk = jax.random.gamma(jax.random.fold_in(key, 3), 0.5, (b, k))
@@ -393,14 +465,20 @@ def _mhw_inputs(v=60, k=16, b=256, lo=0, hi=60, steps=2):
             beta, beta_bar, steps)
 
 
-@pytest.mark.parametrize("tile_k", [4, 8, 16])
-def test_mhw_fused_tile_k_bitexact(tile_k):
+@pytest.mark.parametrize("tile_k,v,lo,hi,n_pad", [
+    pytest.param(4, 60, 0, 60, 0, id="4"),
+    pytest.param(8, 60, 0, 60, 0, id="8"),
+    pytest.param(16, 60, 0, 60, 0, id="16"),
+    # mostly-empty vocab tiles and two all-padding batch tiles
+    pytest.param(4, 240, 48, 72, 150, id="4-sparse-padded"),
+])
+def test_mhw_fused_tile_k_bitexact(tile_k, v, lo, hi, n_pad):
     """The K-staging grid axis is pure data movement: for any tile_k the
     fused kernel's draws equal the untiled kernel's and the oracle's,
     bit for bit."""
     (tabs, stale, n_wk, n_k, prior, rows, z0, ndk, slot, uni,
-     beta, beta_bar, steps) = _mhw_inputs()
-    vstart, vcount = _windows(rows, 60, 12, 64)
+     beta, beta_bar, steps) = _mhw_inputs(v=v, lo=lo, hi=hi, n_pad=n_pad)
+    vstart, vcount = _windows(rows, v, 12, 64)
 
     def run(tk):
         return mhw_fused.mhw_sweep_fused(
@@ -416,11 +494,17 @@ def test_mhw_fused_tile_k_bitexact(tile_k):
     assert bool(jnp.all(run(tile_k) == run(None)))
 
 
-@pytest.mark.parametrize("tile_k", [2, 4, 8])
-def test_pdp_fused_tile_k_bitexact(tile_k):
+@pytest.mark.parametrize("tile_k,v,lo,hi,n_pad", [
+    pytest.param(2, 64, 0, 64, 0, id="2"),
+    pytest.param(4, 64, 0, 64, 0, id="4"),
+    pytest.param(8, 64, 0, 64, 0, id="8"),
+    # mostly-empty vocab tiles and two all-padding batch tiles
+    pytest.param(4, 256, 40, 72, 150, id="4-sparse-padded"),
+])
+def test_pdp_fused_tile_k_bitexact(tile_k, v, lo, hi, n_pad):
     """Same staging argument for the PDP kernel's 2K joint-outcome axis
     (e-tiles stage always, K-side stats only for the first nk tiles)."""
-    v, k, b, steps = 64, 8, 256, 2
+    k, b, steps = 8, 256, 2
     key = jax.random.PRNGKey(v * k + b + 1)
     cfg = pdp.PDPConfig(n_topics=k, vocab_size=v, mh_steps=steps,
                         stirling_n_max=128, concentration=5.0)
@@ -431,7 +515,7 @@ def test_pdp_fused_tile_k_bitexact(tile_k):
     tabs, stale = pdp.build_alias(cfg, shared)
     stirl = stirling.as_jax(cfg.stirling_n_max, cfg.discount)
     prior = jnp.full((2 * k,), cfg.alpha, jnp.float32)
-    rows = _sorted_rows(jax.random.fold_in(key, 1), b, 0, v, v)
+    rows = _sorted_rows(jax.random.fold_in(key, 1), b, lo, hi, v, n_pad)
     e0 = jax.random.randint(jax.random.fold_in(key, 2), (b,), 0, 2 * k,
                             jnp.int32)
     ndk = jnp.floor(jax.random.gamma(jax.random.fold_in(key, 3), 0.5,

@@ -130,6 +130,29 @@ def test_trainer_hdp_local_polytope_maintained(corpus):
             assert float(trainer.family.count_local_violations(loc)) == 0.0
 
 
+@pytest.mark.parametrize("name", ["lda", "pdp"])
+def test_trainer_sweep_grid_counters(name, corpus):
+    """The static counters of the fused sweep's grid equal what the hoisted
+    layouts give by hand: (batch tiles + vocab tiles) work-list entries
+    times K tiles per chunk, and the pairs in the vocab windows."""
+    import dataclasses
+    tokens, mask, _ = corpus
+    cfg = dataclasses.replace(_cfg(name), tile_v=16, tile_k=4)
+    trainer = Trainer(cfg, tokens, mask, config=TrainerConfig(
+        layout="sorted", n_clients=2))
+    n_ktiles = trainer.family.n_outcomes(cfg) // 4
+    lays = [lay for ls in trainer.layouts for lay in ls]
+    assert len(lays) == 2 * cfg.sorted_chunks
+    steps = sum((np.asarray(lay.vstart).size + VOCAB // 16) * n_ktiles
+                for lay in lays)
+    live = sum(int(np.asarray(lay.vcount).sum()) for lay in lays)
+    assert trainer.sweep_grid_steps == steps
+    assert trainer.sweep_live_pairs == live
+    assert 0 < live <= steps // n_ktiles
+    scan = Trainer(cfg, tokens, mask, config=TrainerConfig(n_clients=2))
+    assert scan.sweep_grid_steps == scan.sweep_live_pairs == 0
+
+
 def test_trainer_rejects_bad_config(corpus):
     tokens, mask, _ = corpus
     with pytest.raises(ValueError, match="layout"):
