@@ -42,7 +42,7 @@ TILE_K = 128                 # K-tiled staging of the fused kernels
 ROUNDS = 3                   # timed rounds after the warm-up round
 MESH_ROUNDS = 2              # rounds of each four-chip run
 SERVE_SLOTS, SERVE_SWEEPS, PARITY_DOCS = 16, 10, 3
-QUALITY_TOL = 1.25           # benchmarks/bench_serve.py's gate
+QUALITY_TOL = 1.25           # tests/test_serve_engine.py's gate
 MESH_PPL_TOL = 0.05          # mesh vs one-device Trainer, relative
 
 

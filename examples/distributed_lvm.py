@@ -114,7 +114,7 @@ def main() -> None:
         print(f"rejoins={trainer.rejoins} pull_failures="
               f"{trainer.pull_failures}")
     print(f"total {time.time() - t0:.1f}s, "
-          f"~{res.tokens_per_s / 1e3:.1f}k tokens/s/round")
+          f"{np.median(res.iter_times) * 1e3:.1f} ms/round (median)")
 
     # Record the run's summary curves next to the Trainer's snapshots.
     path = ckpt.save(snap_dir, f"{args.model}_run", args.rounds, {

@@ -16,6 +16,7 @@ kernels, ``--layout scan`` the sequential oracle.
 from __future__ import annotations
 
 import argparse
+import statistics
 
 import jax
 import jax.numpy as jnp
@@ -71,7 +72,8 @@ def main() -> None:
     for i, ppl in enumerate(res.perplexities):
         tpw = res.topics_per_word[i]
         print(f"eval {i}: perplexity={ppl:8.2f}  topics/word={tpw:5.2f}")
-    print(f"throughput: {res.tokens_per_s / 1e3:8.1f}k tokens/s")
+    print(f"round time: {statistics.median(res.iter_times) * 1e3:8.1f} ms "
+          f"(median of {len(res.iter_times)} rounds)")
 
     err = trainer.consistency_error()
     print("done — sufficient-statistics consistency:",

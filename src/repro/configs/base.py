@@ -3,8 +3,7 @@
 Every assigned architecture is a ``ModelConfig`` (exact hyper-parameters
 from its source paper / model card, cited in the per-arch module).  Configs
 are plain frozen dataclasses — hashable, so they can be static jit args —
-and carry everything the model builder, trainer, server, dry-run and
-roofline need.
+and carry everything the model builder, trainer and server need.
 """
 
 from __future__ import annotations

@@ -30,7 +30,7 @@ and the client-iterated round cannot drift apart.
 
 Failure injection (paper §5.4): a boolean per-client ``alive`` mask zeroes a
 failed client's contribution for the round — the recovery path (reload from
-snapshot, re-pull, continue) is exercised in tests/benchmarks.
+snapshot, re-pull, continue) is exercised in tests.
 """
 
 from __future__ import annotations

@@ -79,13 +79,12 @@ class LDAConfig:
     # within-document Gauss-Seidel effect of the scan layout is mostly
     # retained (1 = fully parallel Jacobi sweep).
     sorted_chunks: int = 4
-    # Full-table build path.  The fused kernel (dense term computed
-    # in-register, kernels/alias_build.py) measures ~2× slower than
-    # materialize-then-build at current (V, K) — BENCH_throughput.json
-    # shows 39.7ms fused vs 20.0ms unfused per build — so unfused stays
-    # the default until the roofline item validates fused at production
-    # sizes.  (The *partial* gather-fused rebuild is unaffected: it wins
-    # by scaling with changed rows, not V.)
+    # Full-table build path: the fused kernel (dense term computed
+    # in-register, kernels/alias_build.py) or materialize-then-build.  The
+    # choice between them waits for a chip measurement of both full builds
+    # (PERF.md §7 rows 4 and 15); until then unfused stays the default.
+    # (The *partial* gather-fused rebuild is unaffected: it wins by
+    # scaling with changed rows, not V.)
     fused_alias_build: bool = False
 
 

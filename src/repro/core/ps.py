@@ -21,7 +21,7 @@ as *delta compression*: the magnitude-priority filter keeps the top-k rows
 by L1 delta mass, and the uniform-sampling anti-starvation term keeps a
 random subset of the remainder.  The compressed representation (indices,
 values) is what crosses the interconnect — visible as smaller collectives
-in the lowered HLO (EXPERIMENTS.md §Perf).
+in the lowered HLO.
 """
 
 from __future__ import annotations

@@ -15,7 +15,7 @@ three ways:
   ``lax.scan``, projection under ``lax.cond`` (so the cadence does not
   retrace), and the incremental alias producer fused at the tail,
 * by the Python reference loop ``Trainer._step_python`` — kept un-compiled
-  as the dispatch-per-op baseline the benchmarks compare against,
+  as the dispatch-per-op parity oracle,
 
 so the three drivers cannot drift apart.  (A fourth consumer,
 ``Trainer._step_remote`` — the ``transport="tcp"`` loop against
@@ -106,8 +106,8 @@ class RoundConfig:
     cache on the full ``TrainerConfig`` would retrace the identical round
     program whenever one of them changes (e.g. a baseline run vs. the
     same run with fault injection + snapshots — exactly the pairs
-    ``bench_failover`` compares).  This reduced static makes those pairs
-    share one trace by construction."""
+    tests/test_fault.py's recovery test compares).  This reduced static
+    makes those pairs share one trace by construction."""
 
     layout: str
     method: str

@@ -1,11 +1,10 @@
 """``Trainer``: the one driver loop for every ModelFamily (paper §5).
 
 Replaces the hand-rolled per-model driver loops that used to live in
-``examples/quickstart.py``, ``examples/distributed_lvm.py`` and
-``benchmarks/bench_{lda,pdp,hdp}.py``, and the per-model adapter classes of
-``core/distributed.py``: model specifics enter only through the
-``repro.core.family`` registry, so LDA / PDP / HDP — and any future family —
-run the identical lifecycle:
+``examples/quickstart.py`` and ``examples/distributed_lvm.py``, and the
+per-model adapter classes of ``core/distributed.py``: model specifics enter
+only through the ``repro.core.family`` registry, so LDA / PDP / HDP — and
+any future family — run the identical lifecycle:
 
     pull    — ask the parameter server for a snapshot under the configured
               consistency policy (BSP: fresh every round; SSP: a versioned
@@ -41,9 +40,8 @@ residuals) is donated so XLA updates it in place, and ``step()`` never
 blocks — rounds pipeline asynchronously and the Trainer synchronizes only
 at evaluation points.  ``TrainerConfig.compiled=False`` keeps the PR-2
 Python reference loop (one dispatch per op, blocking per round) for parity
-tests and as the benchmark baseline; it supports every consistency policy
-through the same server methods, so it stays the parity oracle for all of
-them.
+tests; it supports every consistency policy through the same server
+methods, so it stays the parity oracle for all of them.
 
 The Trainer also owns the alias-table maintenance (the l/n staleness rule
 of §3.3 — the producer half of the paper's §5.1 producer/consumer design),
@@ -53,7 +51,7 @@ in three schedules:
   ``alias_refresh_every`` rounds and reused in between;
 * pull-coupled (SSP): the proposal is part of the pulled cache, so tables
   rebuild exactly when the versioned snapshot refreshes — this skipped
-  work is the measured SSP throughput win (benchmarks/bench_consistency);
+  work is what SSP saves over BSP;
 * incremental (``alias_rebuild_threshold`` set): every compiled round ends
   by rebuilding *only* the token-type rows whose accumulated push mass
   exceeds the threshold (the server's per-shard changed-row accounting,
@@ -75,13 +73,9 @@ just a maximally stale client taking its blocking refresh.
 
 The loop is semantically the single-device simulation of
 ``core.distributed.make_round_fn`` (clients iterated instead of
-shard_mapped) — both drive the same round body in ``engine.round``; RNG
-streams are keyed identically to the historical
-``benchmarks.common.run_multiclient``.  One deliberate behavior change
-from that loop: projection now runs uniformly per ``project_every`` for
-*every* family (the old loop never projected LDA) — matching the
-distributed round's paper-production default; pass ``project_every=0``
-to disable.
+shard_mapped) — both drive the same round body in ``engine.round``.
+Projection runs per ``project_every`` for *every* family — the distributed
+round's paper-production default; pass ``project_every=0`` to disable.
 """
 
 from __future__ import annotations
@@ -201,22 +195,6 @@ class RunResult:
     topics_per_word: list[float] = field(default_factory=list)
     iter_times: list[float] = field(default_factory=list)
     violations: list[float] = field(default_factory=list)
-    tokens: int = 0
-
-    @property
-    def tokens_per_s(self) -> float:
-        """Training throughput over the recorded eval segments.
-
-        Returns ``float("nan")`` before any eval segment has been timed
-        (``iter_times`` empty — e.g. a fresh ``RunResult`` or a run that
-        has not reached its first evaluation point): a benchmark script
-        averaging or logging throughput must not silently record 0.0 as
-        if it were a measurement — NaN propagates loudly instead.
-        """
-        if not self.iter_times:
-            return float("nan")
-        t = float(np.mean(self.iter_times))
-        return self.tokens / max(t, 1e-9)
 
 
 class Trainer:
@@ -807,8 +785,7 @@ class Trainer:
         device sync every round.  Semantically identical to the compiled
         round (same RNG keying and server methods — integer count
         statistics match bit-exactly for every consistency policy); kept
-        as the parity oracle and the dispatch-overhead baseline measured
-        in benchmarks/bench_throughput.py."""
+        as the parity oracle."""
         fam, cfg, tcfg = self.family, self.cfg, self.tcfg
         srv, pol = self.server, self.server.policy
         r = self.round_idx
@@ -1033,7 +1010,7 @@ class Trainer:
         fam, cfg = self.family, self.cfg
         eval_t = self.tokens[:eval_docs]
         eval_m = self.mask[:eval_docs]
-        res = RunResult(tokens=self.n_tokens)
+        res = RunResult()
         first = self.round_idx
         seg_start = time.perf_counter()
         seg_rounds = 0

@@ -13,9 +13,7 @@ cadence for every token-type row.  On TPU the thread pool dissolves into a
 
 The fused variant computes the dense LDA term α(n_wk+β)/(n_t+β̄) from the
 raw sufficient statistics *inside* the kernel, saving one V×K HBM round
-trip versus materializing the dense matrix and then building tables
-(measured in the ``alias_build`` section of benchmarks/bench_throughput.py,
-fused vs. materialize-then-build).
+trip versus materializing the dense matrix and then building tables.
 
 Incremental rebuilds (the delta-driven producer of the paper's §5.1
 producer/consumer design) use the *rows* variants: only the token-type rows

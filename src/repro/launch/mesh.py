@@ -1,22 +1,12 @@
-"""Production mesh construction.
+"""Host mesh construction.
 
-Defined as FUNCTIONS (not module constants) so importing never touches jax
-device state.  Single pod: (data=16, model=16) = 256 chips (v5e pod).
-Multi-pod: (pod=2, data=16, model=16) = 512 chips; the ``pod`` axis is an
-outer data-parallel axis whose collectives cross DCN.
+Defined as a FUNCTION (not a module constant) so importing never touches
+jax device state.
 """
 
 from __future__ import annotations
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(
-        shape, axes,
-        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
@@ -27,9 +17,3 @@ def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     return jax.make_mesh(
         (data, model), ("data", "model"),
         axis_types=(jax.sharding.AxisType.Auto,) * 2)
-
-
-# TPU v5e hardware constants (roofline denominators).
-PEAK_FLOPS_BF16 = 197e12          # per chip
-HBM_BW = 819e9                    # bytes/s per chip
-ICI_BW = 50e9                     # bytes/s per link (≈ per-chip effective)

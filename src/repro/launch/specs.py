@@ -2,8 +2,8 @@
 
 ``input_specs`` returns ShapeDtypeStruct stand-ins (weak-type-correct,
 shardable, no device allocation) for every model input of the workload, and
-the matching PartitionSpecs.  This is what both the multi-pod dry-run and
-the roofline analysis lower against.
+the matching PartitionSpecs, which the lowering tests
+(tests/test_lowering_modes.py) compile against.
 """
 
 from __future__ import annotations

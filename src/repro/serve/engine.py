@@ -411,8 +411,8 @@ def fold_in_perplexity(snap: InferenceSnapshot,
     """Held-out perplexity of documents under their *harvested* topic
     proportions and the frozen per-topic word distributions — the
     serving-side counterpart of ``family.perplexity`` (which folds in
-    with its own internal chains).  The benchmark's quality gate compares
-    the two."""
+    with its own internal chains).  The quality gate in
+    tests/test_serve_engine.py compares the two."""
     phi = np.asarray(snap.language_model(), np.float32)  # (V, K)
     k = thetas.shape[1]
     pw = np.einsum("dk,dlk->dl", np.asarray(thetas, np.float32),

@@ -1,6 +1,6 @@
 """Sharding rules: parameter/activation PartitionSpecs for train and serve.
 
-Two modes (DESIGN.md §5, EXPERIMENTS.md §Perf):
+Two modes (DESIGN.md §5):
 
 ``mode="megatron"`` (paper-faithful baseline — TP over ``model``):
 - Tensor parallelism over the ``model`` axis, FSDP over the ``data`` axis

@@ -1,7 +1,7 @@
 """Stale-synchronous, filter-compressed gradient sync — the paper's
 parameter-server communication pattern (eventual consistency + magnitude-
-priority filters, §5.3) applied to data-parallel SGD.  This is the
-*beyond-paper* transfer recorded separately in EXPERIMENTS.md.
+priority filters, §5.3) applied to data-parallel SGD — a *beyond-paper*
+transfer.
 
 Mechanics (per client = data shard, expressed with shard_map):
   - each client keeps a full parameter replica and an error-feedback
@@ -14,9 +14,7 @@ Mechanics (per client = data shard, expressed with shard_map):
   - nothing is ever dropped: residual_update carries withheld mass forward,
     the eventual-consistency guarantee in exact form.
 
-This trades gradient freshness for a ~V/k reduction in sync bytes; the
-convergence benchmark (benchmarks/bench_stale_sync.py) quantifies the
-trade on a real LM.
+This trades gradient freshness for a ~V/k reduction in sync bytes.
 """
 
 from __future__ import annotations

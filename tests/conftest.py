@@ -1,9 +1,9 @@
 """Shared test fixtures.
 
 NOTE: XLA_FLAGS device-count forcing is deliberately NOT set here — smoke
-tests and benchmarks must see the single real CPU device.  Multi-device
-tests spawn subprocesses (tests/test_distributed.py) or use the dry-run
-entry point, which sets the flag before importing jax.
+tests must see the single real CPU device.  Multi-device tests spawn
+subprocesses (e.g. tests/test_distributed_round.py) that set the flag before
+importing jax.
 """
 
 from __future__ import annotations

@@ -27,6 +27,8 @@ import numpy as np
 import pytest
 
 from repro.core.fault import FaultEvent, FaultPlan, healthy
+from repro.core.lda import LDAConfig
+from repro.data.synthetic import CorpusConfig, make_topic_corpus
 from repro.engine import Trainer, TrainerConfig
 from tests.conftest import make_family_cfg, make_synthetic_corpus
 
@@ -348,3 +350,42 @@ def test_fault_plan_rounds_trace_once(corpus):
     t._sync()
     assert t.round_traces == traced_once
     assert np.isfinite(t.perplexity(tokens[:16], mask[:16]))
+
+
+# ---------------------------------------------------------------------------
+# Recovery quality
+# ---------------------------------------------------------------------------
+
+MAX_DEGRADATION = 0.05
+
+
+@pytest.fixture(scope="module")
+def recovery_corpus():
+    return make_topic_corpus(CorpusConfig(
+        n_topics=8, vocab_size=400, n_docs=128, doc_len=48, seed=7))
+
+
+@pytest.mark.parametrize("consistency", ["bsp", "ssp:2", "async"])
+def test_kill_rejoin_final_perplexity_within_five_percent(
+        consistency, recovery_corpus, tmp_path):
+    """Client 1 of 4 crashes over rounds 3-6 of 12 and rejoins from its
+    last periodic snapshot; final held-out perplexity ends within 5 % of
+    the same run with no fault."""
+    tokens, mask, _ = recovery_corpus
+    cfg = LDAConfig(n_topics=8, vocab_size=400, alpha=0.1, beta=0.01,
+                    mh_steps=2)
+    n_rounds = 12
+
+    def final_perplexity(**fault):
+        t = Trainer(cfg, tokens, mask, config=TrainerConfig(
+            n_clients=4, consistency=consistency, **fault))
+        for _ in range(n_rounds):
+            t.step()
+        return t.perplexity(tokens[:32], mask[:32]), t
+
+    base, _ = final_perplexity()
+    killed, t = final_perplexity(fault_plan=FaultPlan.crash(1, 3, 6),
+                                 snapshot_every=2,
+                                 snapshot_dir=str(tmp_path))
+    assert t.rejoins == 1
+    assert killed / base - 1.0 <= MAX_DEGRADATION, (killed, base)

@@ -1,7 +1,5 @@
 """Lowering-path regression tests: every sharding mode must lower+compile
-a reduced arch on a small forced-device mesh (the 512-device production
-sweep is exercised by launch/dryrun.py; this guards the same code path in
-CI time)."""
+a reduced arch on a small forced-device mesh."""
 
 from __future__ import annotations
 

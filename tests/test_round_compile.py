@@ -178,15 +178,3 @@ def test_incremental_requires_compiled(corpus):
     with pytest.raises(ValueError, match="compiled"):
         Trainer(_cfg("lda"), tokens, mask, config=TrainerConfig(
             compiled=False, alias_rebuild_threshold=0.0))
-
-
-def test_tokens_per_s_nan_before_eval_segments():
-    """Before any eval segment is timed there is no throughput number:
-    tokens_per_s must be NaN (loud in downstream logs/means), never a
-    silent 0.0 a benchmark script could record as a measurement."""
-    import math
-
-    from repro.engine import RunResult
-    assert math.isnan(RunResult(tokens=1000).tokens_per_s)
-    r = RunResult(tokens=1000, iter_times=[0.5])
-    assert r.tokens_per_s == pytest.approx(2000.0)

@@ -16,10 +16,11 @@ from jax._src.array import ArrayImpl
 from repro.core import family as fam_mod
 from repro.data import segment
 from repro.data.synthetic import CorpusConfig, make_topic_corpus
+from repro.engine import Trainer, TrainerConfig
 from repro.kernels import ops
 from repro.serve import (FoldInEngine, InferRequest, ServeConfig,
-                         fold_in_perplexity, freeze, reference_fold_in,
-                         result_checksum)
+                         fold_in_perplexity, freeze, from_trainer,
+                         reference_fold_in, result_checksum)
 from repro.serve.engine import InferResult
 
 MAX_LEN = 32
@@ -312,3 +313,38 @@ def test_fold_in_perplexity_finite(snapshot):
     # uniform-random tokens score worse than the vocab size on a peaked
     # model — only finiteness and a loose ceiling are meaningful here
     assert np.isfinite(ppl) and 1.0 < ppl < snapshot.vocab_size ** 2
+
+
+QUALITY_TOL = 1.25
+
+
+def test_fold_in_perplexity_within_tolerance_of_training_eval():
+    """Held-out documents folded in through the engine score a perplexity
+    at most 1.25x the training-time evaluator's (``family.perplexity``)
+    on the same documents.  A fold-in chain that drifted from the model
+    fails here even while it stays deterministic."""
+    n_train, held_out, doc_len = 64, 12, 48
+    tokens, mask, _ = make_topic_corpus(CorpusConfig(
+        n_topics=8, vocab_size=400, n_docs=n_train + held_out,
+        doc_len=doc_len, seed=0))
+    fam = fam_mod.get("lda")
+    cfg = fam.config_cls(n_topics=8, vocab_size=400)
+    trainer = Trainer(cfg, tokens[:n_train], mask[:n_train],
+                      config=TrainerConfig(n_clients=1),
+                      key=jax.random.PRNGKey(0))
+    for _ in range(3):
+        trainer.step()
+    snap = from_trainer(trainer)
+
+    ho_tokens = np.asarray(tokens[n_train:])
+    ho_mask = np.asarray(mask[n_train:], bool)
+    lens = ho_mask.sum(axis=1)
+    reqs = [InferRequest(uid=i, tokens=ho_tokens[i, :lens[i]],
+                         seed=5000 + i) for i in range(held_out)]
+    results = FoldInEngine(snap, ServeConfig(
+        max_slots=4, max_len=doc_len, n_sweeps=4)).run(reqs)
+    thetas = np.stack([results[i].theta for i in range(held_out)])
+    fold_ppl = fold_in_perplexity(snap, thetas, ho_tokens, ho_mask)
+    eval_ppl = float(fam.perplexity(cfg, snap.shared, ho_tokens, ho_mask,
+                                    jax.random.PRNGKey(123)))
+    assert fold_ppl <= QUALITY_TOL * eval_ppl, (fold_ppl, eval_ppl)

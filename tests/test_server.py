@@ -334,8 +334,7 @@ def test_ssp_converges_near_bsp(corpus):
     """Perplexity sanity on the tiny unit corpus: SSP(2) converges (well
     below the random-init plateau) and lands in BSP's neighborhood.  The
     16-doc corpus is deliberately the worst staleness regime — per-round
-    relative drift is huge — so the bound here is loose; the ≤5% gate at
-    the bench's corpus scale lives in benchmarks/bench_consistency.py."""
+    relative drift is huge — so the bound here is loose."""
     tokens, mask, _ = corpus
     ppl = {}
     for consistency in ("bsp", "ssp:2"):

@@ -298,6 +298,25 @@ def _run_sweeps(cfg, tokens, mask, layout, seed, n_sweeps=5, lays=None):
     return local, shared
 
 
+def _sweep_perplexity(cfg, tokens, mask, layout, seed, n_sweeps=5):
+    """Held-out perplexity after ``n_sweeps`` single-client mhw sweeps with
+    ``layout``, for any registered family; deterministic given (corpus,
+    cfg, seed)."""
+    fam = family.family_of(cfg)
+    lays = fam.build_sorted_layouts(cfg, tokens, mask) \
+        if layout == "sorted" else None
+    local, shared = fam.init_state(cfg, tokens, mask, jax.random.PRNGKey(0))
+    for i in range(n_sweeps):
+        tables, stale = fam.build_alias(cfg, shared)
+        local, deltas = fam.sweep(
+            cfg, local, shared, tables, stale, tokens, mask,
+            jax.random.fold_in(jax.random.PRNGKey(seed), i),
+            method="mhw", layout=layout, sorted_layouts=lays)
+        shared = fam.apply_delta(shared, deltas)
+    return float(fam.perplexity(cfg, shared, tokens, mask,
+                                jax.random.PRNGKey(9)))
+
+
 def test_sorted_sweep_statistics_consistent(tiny_corpus):
     """After a sorted sweep, n_dk / the deltas agree with the assignments —
     the sort → sample → unsort round trip is permutation-consistent."""
@@ -329,11 +348,8 @@ def test_sorted_matches_scan_perplexity():
     Averaged over 3 paired sweep-RNG seeds: a single 5-sweep run on this
     corpus carries ~±1.5% MC noise (seed-to-seed spread of the *scan* path
     alone), which would swamp the ~1% systematic effect of the sorted
-    relaxation.  Deterministic given the fixed keys.  The measurement
-    protocol is shared with bench_throughput's artifact cross-check
-    (``common.lda_sweep_perplexity``) so the two cannot drift.
+    relaxation.  Deterministic given the fixed keys.
     """
-    from benchmarks import common
     from repro.data.synthetic import CorpusConfig, make_topic_corpus
     ccfg = CorpusConfig(n_topics=8, vocab_size=300, n_docs=64, doc_len=48,
                         seed=5)
@@ -341,8 +357,7 @@ def test_sorted_matches_scan_perplexity():
     tokens, mask = jnp.asarray(tokens), jnp.asarray(mask)
     cfg = lda.LDAConfig(n_topics=64, vocab_size=300, mh_steps=2)
     means = {
-        layout: sum(common.lda_sweep_perplexity(cfg, tokens, mask, layout,
-                                                seed)
+        layout: sum(_sweep_perplexity(cfg, tokens, mask, layout, seed)
                     for seed in (2, 3, 4)) / 3
         for layout in ("scan", "sorted")
     }
@@ -421,9 +436,7 @@ def test_family_sorted_sweep_statistics_consistent(name, tiny_corpus):
 def test_family_sorted_matches_scan_perplexity(name):
     """Acceptance bar extended to PDP/HDP: sorted and scan layouts agree on
     held-out perplexity after 4 single-client sweeps, seed-averaged (same
-    protocol as the LDA test above, shared with the benchmark artifact via
-    ``common.family_sweep_perplexity``)."""
-    from benchmarks import common
+    protocol as the LDA test above)."""
     from repro.data.synthetic import CorpusConfig, make_topic_corpus
     ccfg = CorpusConfig(n_topics=8, vocab_size=240, n_docs=48, doc_len=32,
                         seed=5)
@@ -431,8 +444,8 @@ def test_family_sorted_matches_scan_perplexity(name):
     tokens, mask = jnp.asarray(tokens), jnp.asarray(mask)
     cfg = make_family_cfg(name, n_topics=16, vocab_size=240)
     means = {
-        layout: sum(common.family_sweep_perplexity(cfg, tokens, mask,
-                                                   layout, seed, n_sweeps=4)
+        layout: sum(_sweep_perplexity(cfg, tokens, mask, layout, seed,
+                                      n_sweeps=4)
                     for seed in (2, 3)) / 2
         for layout in ("scan", "sorted")
     }

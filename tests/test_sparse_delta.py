@@ -14,9 +14,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import alias as alias_mod
 from repro.core import family as family_mod
 from repro.core import ps
 from repro.core import server as server_mod
+from repro.data.synthetic import CorpusConfig, make_topic_corpus
 from repro.engine import round as round_mod
 from tests.conftest import make_family_cfg, make_synthetic_corpus
 
@@ -29,13 +31,28 @@ def corpus():
                                  doc_len=12, seed=3)
 
 
-def _sweep_deltas(name, corpus, key=0):
+# Above this vocabulary the full alias build takes minutes on CPU, so the
+# sweep draws from uniform alias tables (a valid table per row) over the
+# real dense term: that moves which rows a delta touches, not how a
+# delta is encoded or pushed.
+_FULL_BUILD_MAX_V = 8192
+
+
+def _sweep_deltas(name, corpus, key=0, *, vocab=VOCAB, n_topics=4):
     """One real sweep's dense deltas (the thing a client would push)."""
     tokens, mask, _ = corpus
     fam = family_mod.get(name)
-    cfg = make_family_cfg(name, n_topics=4, vocab_size=VOCAB)
+    cfg = make_family_cfg(name, n_topics=n_topics, vocab_size=vocab)
     local, shared = fam.init_state(cfg, tokens, mask, jax.random.PRNGKey(0))
-    tables, stale = fam.build_alias(cfg, shared)
+    if vocab <= _FULL_BUILD_MAX_V:
+        tables, stale = fam.build_alias(cfg, shared)
+    else:
+        tables = alias_mod.AliasTable(
+            prob=jnp.ones((vocab, n_topics), jnp.float32),
+            alias=jnp.broadcast_to(jnp.arange(n_topics, dtype=jnp.int32),
+                                   (vocab, n_topics)),
+            mass=jnp.full((vocab,), float(n_topics), jnp.float32))
+        stale = fam.dense_probs(cfg, shared)
     _, deltas = fam.sweep(cfg, local, shared, tables, stale, tokens, mask,
                           jax.random.PRNGKey(key), method="mhw",
                           layout="scan")
@@ -97,18 +114,32 @@ def test_roundtrip_real_sweep_deltas(name, corpus):
 # push_sparse == push on the core server
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("name", ["lda", "pdp"])
-@pytest.mark.parametrize("n_shards", [1, 3])
-def test_push_sparse_bitexact_with_push(name, n_shards, corpus):
-    fam, cfg, shared, deltas = _sweep_deltas(name, corpus)
+# (family, server shards, V, K); the last case is the largest point of
+# the (V, K) scale ladder on its zipf corpus.
+PUSH_CASES = [pytest.param(name, n_shards, VOCAB, 4,
+                           id=f"{n_shards}-{name}")
+              for n_shards in (1, 3) for name in ("lda", "pdp")] + [
+    pytest.param("lda", 3, 65536, 256, id="3-lda-v65536-k256")]
+
+
+@pytest.mark.parametrize("name,n_shards,vocab,n_topics", PUSH_CASES)
+def test_push_sparse_bitexact_with_push(name, n_shards, vocab, n_topics,
+                                        corpus):
+    if vocab != VOCAB:
+        corpus = make_topic_corpus(CorpusConfig(
+            n_topics=8, vocab_size=vocab, n_docs=48, doc_len=16, seed=5))
+    fam, cfg, shared, deltas = _sweep_deltas(name, corpus, vocab=vocab,
+                                             n_topics=n_topics)
     # Every pushed delta is a (V, ...) row stat: aggregates (n_k, m_k, …)
     # are re-derived by apply_delta (the C2 rule), never shipped.
-    assert all(np.asarray(v).shape[:1] == (VOCAB,) for v in deltas.values())
+    assert all(np.asarray(v).shape[:1] == (vocab,) for v in deltas.values())
 
-    srv = server_mod.make_server(fam, VOCAB, n_shards=n_shards)
+    sp = ps.to_sparse_delta(deltas)
+    assert sp.rows.size < vocab  # the sparse frame ships fewer rows
+
+    srv = server_mod.make_server(fam, vocab, n_shards=n_shards)
     s_dense = srv.push(srv.init_state(shared, n_clients=1), deltas)
-    s_sparse = srv.push_sparse(srv.init_state(shared, n_clients=1),
-                               ps.to_sparse_delta(deltas))
+    s_sparse = srv.push_sparse(srv.init_state(shared, n_clients=1), sp)
 
     a = fam.stats_dict(srv.snapshot(s_dense))
     b = fam.stats_dict(srv.snapshot(s_sparse))
