@@ -406,10 +406,18 @@ class _LMFamilyBase(ModelFamily):
                 * ((shared.n_wk[rows] + cfg.beta)
                    / (shared.n_k[None, :] + beta_bar)))
 
+    def build_alias(self, cfg, shared):
+        """The full build from raw statistics on row tiles
+        (``kernels/alias_build.py`` ``alias_build_fused``): the family
+        supplies only its prior vector."""
+        return ops.build_tables_fused(
+            shared.n_wk, shared.n_k, self.sparse_prior(cfg, shared),
+            beta=cfg.beta, beta_bar=cfg.beta * cfg.vocab_size)
+
     def rebuild_alias_rows(self, cfg, shared, tables, stale, rows, valid):
-        """LM-dense fast path: the scalar-prefetched gather kernel computes
-        prior_e·(n_wk+β)/(n_k+β̄) in-register from the gathered rows and
-        builds the sub-table in one fused launch."""
+        """LM-dense fast path: the full build's kernel over the gathered
+        rows computes prior_e·(n_wk+β)/(n_k+β̄) in-register and builds the
+        sub-table in one fused launch."""
         sub, p_rows = ops.build_tables_gather_fused(
             shared.n_wk, shared.n_k, self.sparse_prior(cfg, shared), rows,
             beta=cfg.beta, beta_bar=cfg.beta * cfg.vocab_size)
@@ -467,9 +475,6 @@ class LDAFamily(_LMFamilyBase):
     def dense_probs(self, cfg, shared) -> Array:
         return lda.dense_probs(cfg, shared)
 
-    def build_alias(self, cfg, shared):
-        return lda.build_alias(cfg, shared)
-
     def sparse_prior(self, cfg, shared) -> Array:
         return jnp.full((cfg.n_topics,), cfg.alpha, jnp.float32)
 
@@ -510,9 +515,6 @@ class HDPFamily(_LMFamilyBase):
 
     def dense_probs(self, cfg, shared) -> Array:
         return hdp.dense_probs(cfg, shared)
-
-    def build_alias(self, cfg, shared):
-        return hdp.build_alias(cfg, shared)
 
     def sparse_prior(self, cfg, shared) -> Array:
         return cfg.b1 * shared.theta0

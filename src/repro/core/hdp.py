@@ -92,8 +92,10 @@ def dense_probs(cfg: HDPConfig, shared: SharedStats) -> Array:
 
 
 def build_alias(cfg: HDPConfig, shared: SharedStats):
-    dp = dense_probs(cfg, shared)
-    return alias_mod.build(dp), dp
+    """Alias tables over the dense term, and the dense term itself: the
+    LM-dense families' full build."""
+    from repro.core import family as family_mod
+    return family_mod.get("hdp").build_alias(cfg, shared)
 
 
 @partial(jax.jit, static_argnames=("cfg", "method", "layout"))

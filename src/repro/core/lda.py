@@ -79,13 +79,6 @@ class LDAConfig:
     # within-document Gauss-Seidel effect of the scan layout is mostly
     # retained (1 = fully parallel Jacobi sweep).
     sorted_chunks: int = 4
-    # Full-table build path: the fused kernel (dense term computed
-    # in-register, kernels/alias_build.py) or materialize-then-build.  The
-    # choice between them waits for a chip measurement of both full builds
-    # (PERF.md §7 rows 4 and 15); until then unfused stays the default.
-    # (The *partial* gather-fused rebuild is unaffected: it wins by
-    # scaling with changed rows, not V.)
-    fused_alias_build: bool = False
 
 
 class SharedStats(NamedTuple):
@@ -137,15 +130,10 @@ def dense_probs(cfg: LDAConfig, shared: SharedStats) -> Array:
 
 
 def build_alias(cfg: LDAConfig, shared: SharedStats) -> tuple[alias_mod.AliasTable, Array]:
-    """Build per-token-type alias tables over the (stale) dense term."""
-    if cfg.fused_alias_build:
-        from repro.kernels import ops
-        tile_r = max(t for t in (8, 4, 2, 1) if cfg.vocab_size % t == 0)
-        return ops.build_tables_fused_lda(
-            shared.n_wk, shared.n_k, alpha=cfg.alpha, beta=cfg.beta,
-            vocab_size=cfg.vocab_size, tile_r=tile_r)
-    dp = dense_probs(cfg, shared)
-    return alias_mod.build(dp), dp
+    """Build per-token-type alias tables over the (stale) dense term, and
+    the dense term itself: the LM-dense families' full build."""
+    from repro.core import family as family_mod
+    return family_mod.get("lda").build_alias(cfg, shared)
 
 
 @partial(jax.jit, static_argnames=("cfg", "method", "layout"))

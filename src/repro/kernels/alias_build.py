@@ -11,22 +11,24 @@ cadence for every token-type row.  On TPU the thread pool dissolves into a
                   across the TILE_R rows of the tile (rows are VPU lanes;
                   every loop step retires one "small" slot per row)
 
-The fused variant computes the dense LDA term α(n_wk+β)/(n_t+β̄) from the
-raw sufficient statistics *inside* the kernel, saving one V×K HBM round
-trip versus materializing the dense matrix and then building tables.
+The LM-dense families (LDA, HDP), whose dense term factorizes as
+prior_e · (n_wk+β)/(n_t+β̄), build from the raw statistics: the term is
+computed *inside* the kernel, saving a V×K HBM round trip, and written
+out once as the stale snapshot.  One kernel body serves the full build
+(:func:`alias_build_fused`, which reads (TILE_R, K) ``n_wk`` tiles
+straight from HBM) and the incremental rebuild of the paper's §5.1
+producer/consumer design (:func:`alias_build_gather_fused`, which gathers
+the R token-type rows whose pushed delta mass drifted and builds them in
+the same tiles, so its cost scales with R, not V).  Other families build
+over a materialized dense term: :func:`alias_build`, and
+:func:`alias_build_rows` over a compacted (R, E) block.
 
-Incremental rebuilds (the delta-driven producer of the paper's §5.1
-producer/consumer design) use the *rows* variants: only the token-type rows
-whose pushed delta mass drifted are rebuilt — :func:`alias_build_rows` over
-a compacted (R, E) block, and :func:`alias_build_gather_fused`, which
-gathers the R changed statistics rows and fuses the dense-term computation
-with the table build (cost scales with R changed rows, not V).
-
-Validated against ``repro.kernels.ref`` in interpret mode (CPU); the block
-shapes keep the working set ≤ a few MB of VMEM for production sizes
-(TILE_R=8, K≤4096 → ~1.5 MB including table state).  The pairing loop is
-O(K) lane operations per slot, so a row costs O(K²) — the price of
-lowering without sort or scatter; incremental rebuilds touch few rows.
+Validated against ``repro.core.alias.build`` in interpret mode (CPU);
+the block shapes keep the working set ≤ a few MB of VMEM for production
+sizes (TILE_R=8, K≤4096 → ~1.5 MB including table state).  The pairing
+loop is O(K) lane operations per slot, so a row costs O(K²) — the price
+of lowering without sort or scatter — but only VPU work: the tile's
+state stays on the chip for all K steps.
 """
 
 from __future__ import annotations
@@ -129,15 +131,6 @@ def _alias_build_kernel(p_ref, prob_ref, alias_ref, mass_ref):
     prob_ref[...], alias_ref[...], mass_ref[...] = _build_rows(p)
 
 
-def _alias_build_fused_kernel(n_wk_ref, n_k_ref, prob_ref, alias_ref,
-                              mass_ref, *, alpha, beta, beta_bar):
-    """Fused: dense term α(n_wk+β)/(n_k+β̄) computed in-register."""
-    n_wk = n_wk_ref[...].astype(jnp.float32)           # (TILE_R, K)
-    n_k = n_k_ref[...].astype(jnp.float32)             # (1, K) broadcast row
-    p = alpha * (n_wk + beta) / (n_k + beta_bar)
-    prob_ref[...], alias_ref[...], mass_ref[...] = _build_rows(p)
-
-
 def _alias_build_tiled_kernel(p_ref, prob_ref, alias_ref, mass_ref,
                               p_s, prob_s, alias_s, *, tile_k: int):
     """Two-phase K-streamed build (grid (nr, 2, nk), lexicographic order):
@@ -224,102 +217,6 @@ def alias_build(p: jax.Array, *, tile_r: int = DEFAULT_TILE_R,
     )(p))
 
 
-def _alias_build_fused_tiled_kernel(n_wk_ref, n_k_ref, prob_ref, alias_ref,
-                                    mass_ref, nwk_s, nk_s, prob_s, alias_s,
-                                    *, tile_k: int, alpha, beta, beta_bar):
-    """K-streamed fused build: phase 0 stages the *raw* statistics
-    k-tiles; phase 1 computes the dense term on the full-K staged rows —
-    the exact expression and shapes of :func:`_alias_build_fused_kernel`,
-    so XLA emits the same rounding and tiled == untiled bit-for-bit —
-    then runs the pairing and flushes one k-tile per step."""
-    pi = pl.program_id(1)
-    ki = pl.program_id(2)
-    ksl = pl.ds(ki * tile_k, tile_k)
-
-    @pl.when(pi == 0)
-    def _stage():
-        nwk_s[:, ksl] = n_wk_ref[...].astype(jnp.float32)
-        nk_s[:, ksl] = n_k_ref[...].astype(jnp.float32)
-
-    @pl.when((pi == 1) & (ki == 0))
-    def _build():
-        p = alpha * (nwk_s[...] + beta) / (nk_s[...] + beta_bar)
-        prob_s[...], alias_s[...], mass_ref[...] = _build_rows(p)
-
-    @pl.when(pi == 1)
-    def _flush():
-        prob_ref[...] = prob_s[:, ksl]
-        alias_ref[...] = alias_s[:, ksl]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("alpha", "beta", "vocab_size", "tile_r",
-                                    "tile_k", "interpret"))
-def alias_build_fused(n_wk: jax.Array, n_k: jax.Array, *, alpha: float,
-                      beta: float, vocab_size: int,
-                      tile_r: int = DEFAULT_TILE_R,
-                      tile_k: int | None = None, interpret: bool | None = None):
-    """Fused dense-term + alias build from raw LDA statistics.
-
-    ``tile_k`` (None ⇒ K) streams inputs and outputs in k-tiles as in
-    :func:`alias_build`; the dense term and the pairing see identical
-    values either way, so the tables are bit-identical."""
-    v, k = n_wk.shape
-    assert v % tile_r == 0
-    out_shape = [
-        jax.ShapeDtypeStruct((v, k), jnp.float32),
-        jax.ShapeDtypeStruct((v, k), jnp.int32),
-        jax.ShapeDtypeStruct((v, 1), jnp.float32),
-    ]
-    interpret = backend.interpret("alias_build_fused", requested=interpret)
-    if tile_k is None or tile_k >= k:
-        kernel = functools.partial(_alias_build_fused_kernel, alpha=alpha,
-                                   beta=beta, beta_bar=beta * vocab_size)
-        return _flat_mass(pl.pallas_call(
-            kernel,
-            grid=(v // tile_r,),
-            in_specs=[
-                pl.BlockSpec((tile_r, k), lambda i: (i, 0)),
-                pl.BlockSpec((1, k), lambda i: (0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((tile_r, k), lambda i: (i, 0)),
-                pl.BlockSpec((tile_r, k), lambda i: (i, 0)),
-                pl.BlockSpec((tile_r, 1), lambda i: (i, 0)),
-            ],
-            out_shape=out_shape,
-            name="alias_build_fused",
-            interpret=interpret,
-        )(n_wk, n_k.reshape(1, -1)))
-    assert k % tile_k == 0, f"K={k} must be a multiple of tile_k={tile_k}"
-    nk = k // tile_k
-    kernel = functools.partial(_alias_build_fused_tiled_kernel,
-                               tile_k=tile_k, alpha=alpha, beta=beta,
-                               beta_bar=beta * vocab_size)
-    return _flat_mass(pl.pallas_call(
-        kernel,
-        grid=(v // tile_r, 2, nk),
-        in_specs=[
-            pl.BlockSpec((tile_r, tile_k), lambda i, pi, ki: (i, ki)),
-            pl.BlockSpec((1, tile_k), lambda i, pi, ki: (0, ki)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile_r, tile_k), lambda i, pi, ki: (i, ki)),
-            pl.BlockSpec((tile_r, tile_k), lambda i, pi, ki: (i, ki)),
-            pl.BlockSpec((tile_r, 1), lambda i, pi, ki: (i, 0)),
-        ],
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((tile_r, k), jnp.float32),   # staged n_wk rows
-            pltpu.VMEM((1, k), jnp.float32),        # staged n_k row
-            pltpu.VMEM((tile_r, k), jnp.float32),   # built prob rows
-            pltpu.VMEM((tile_r, k), jnp.int32),     # built alias rows
-        ],
-        name="alias_build_fused",
-        interpret=interpret,
-    )(n_wk, n_k.reshape(1, -1)))
-
-
 @functools.partial(jax.jit, static_argnames=("tile_r", "tile_k", "interpret"))
 def alias_build_rows(p: jax.Array, *, tile_r: int = DEFAULT_TILE_R,
                      tile_k: int | None = None, interpret: bool | None = None):
@@ -335,64 +232,109 @@ def alias_build_rows(p: jax.Array, *, tile_r: int = DEFAULT_TILE_R,
     return prob[:r], alias[:r], mass[:r]
 
 
-def _alias_build_dense_row_kernel(n_wk_ref, n_k_ref, prior_ref, prob_ref,
-                                  alias_ref, mass_ref, stale_ref, *, beta,
-                                  beta_bar):
-    """One gathered statistics row per program: the dense term
-    prior_e·(n_wk+β)/(n_k+β̄) computed in-register, then the table build;
-    the dense row is written too (the stale-snapshot update)."""
-    n_wk = n_wk_ref[...].astype(jnp.float32)           # (1, K) gathered row
-    n_k = n_k_ref[...].astype(jnp.float32)             # (1, K)
+# Elements of one (tile_r, K) array of the LM-dense build's pairing state,
+# K padded to the 128-lane width: the row tile holds tile_r·K near this.
+# At K=1024 it gives 64 rows, the fastest of 8, 16, 32 and 64 on a v5e:
+# the pairing steps of independent rows overlap (PERF.md §6).
+LM_TILE_ELEMS = 64 * 1024
+
+
+def pick_tile_r(k: int, rows: int) -> int:
+    """Row tile of the LM-dense build: the largest power-of-two multiple
+    of 8 (the sublane count) whose (tile_r, K) tile stays within
+    ``LM_TILE_ELEMS``, and no larger than ``rows`` rounded up to 8."""
+    lanes = -(-k // 128) * 128
+    t = 8
+    while 2 * t * lanes <= LM_TILE_ELEMS:
+        t *= 2
+    return min(t, -(-rows // 8) * 8)
+
+
+def _alias_build_lm_kernel(n_wk_ref, n_k_ref, prior_ref, prob_ref,
+                           alias_ref, mass_ref, stale_ref, *, beta,
+                           beta_bar):
+    """A (tile_r, K) tile of statistics rows: the dense term
+    prior_e·((n_wk+β)/(n_k+β̄)) computed in-register, the table build,
+    and the dense rows written too (the stale snapshot)."""
+    n_wk = n_wk_ref[...].astype(jnp.float32)
+    n_k = n_k_ref[...].astype(jnp.float32)             # (1, K) broadcast row
     # prior · (LM row), division grouped first — the exact operation order
-    # of the families' dense_probs, so partial rebuilds are bit-identical
-    # to a full rebuild of the same statistics.
+    # of the families' dense_probs, so the tables and stale rows are
+    # bit-identical to core.alias.build over dense_probs.
     p = prior_ref[...] * ((n_wk + beta) / (n_k + beta_bar))
-    prob_ref[...], alias_ref[...], mass_ref[...] = _build_rows(p)
     stale_ref[...] = p
+    # max(p, 0) is p, the dense term being non-negative; it keeps the
+    # compiler from contracting the product into the mass sum's adds (an
+    # FMA), which would round the mass unlike a sum of the stored rows.
+    prob_ref[...], alias_ref[...], mass_ref[...] = _build_rows(
+        jnp.maximum(p, 0.0))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("beta", "beta_bar", "interpret"))
-def alias_build_gather_fused(n_wk: jax.Array, n_k: jax.Array,
-                             prior: jax.Array, rows: jax.Array, *,
-                             beta: float, beta_bar: float,
-                             interpret: bool | None = None):
-    """Gather → fused dense-term + alias build over changed rows only.
-
-    ``prior`` is the (K,) per-topic prior-mass vector of the dense proposal
-    (α·1 for LDA, b1·θ0 for HDP), so one kernel serves every family whose
-    dense term factorizes as prior_e · LM row.  ``rows`` is the (R,) int32
-    changed-row selection.  Returns compacted (prob, alias, mass, dense)
-    rows of shapes (R, K)/(R, K)/(R,)/(R, K) for the caller to scatter
-    (``repro.core.alias.update_rows``).
-
-    The R statistics rows are gathered by XLA (R·K elements) into an
-    (R, 1, K) block array: one row per program, with the row dimension
-    squeezed — the one-row block Mosaic accepts — and each row reduced
-    exactly as the full build reduces it, so partial and full rebuilds of
-    the same statistics agree bit-for-bit.
-    """
-    k = n_wk.shape[1]
-    r = rows.shape[0]
-    kernel = functools.partial(_alias_build_dense_row_kernel, beta=beta,
+def _lm_build(n_wk_rows, n_k, prior, *, beta, beta_bar, tile_r, name,
+              interpret):
+    """(R, K) statistics rows → (prob, alias, mass (R,), dense) over
+    (tile_r, K) blocks; R is padded to a tile_r multiple and trimmed."""
+    r, k = n_wk_rows.shape
+    pad = (-r) % tile_r
+    if pad:
+        n_wk_rows = jnp.pad(n_wk_rows, ((0, pad), (0, 0)))
+    kernel = functools.partial(_alias_build_lm_kernel, beta=beta,
                                beta_bar=beta_bar)
-    sq = pl.Squeezed()
-    row = pl.BlockSpec((sq, 1, k), lambda i: (i, 0, 0))
+    tile = pl.BlockSpec((tile_r, k), lambda i: (i, 0))
     full_row = pl.BlockSpec((1, k), lambda i: (0, 0))
     prob, alias, mass, dense = pl.pallas_call(
         kernel,
-        grid=(r,),
-        in_specs=[row, full_row, full_row],
-        out_specs=[row, row, pl.BlockSpec((sq, 1, 1), lambda i: (i, 0, 0)),
-                   row],
+        grid=((r + pad) // tile_r,),
+        in_specs=[tile, full_row, full_row],
+        out_specs=[tile, tile, pl.BlockSpec((tile_r, 1), lambda i: (i, 0)),
+                   tile],
         out_shape=[
-            jax.ShapeDtypeStruct((r, 1, k), jnp.float32),
-            jax.ShapeDtypeStruct((r, 1, k), jnp.int32),
-            jax.ShapeDtypeStruct((r, 1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((r, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((r + pad, k), jnp.float32),
+            jax.ShapeDtypeStruct((r + pad, k), jnp.int32),
+            jax.ShapeDtypeStruct((r + pad, 1), jnp.float32),
+            jax.ShapeDtypeStruct((r + pad, k), jnp.float32),
         ],
-        name="alias_build_gather_fused",
-        interpret=backend.interpret("alias_build_gather_fused",
-                                    requested=interpret),
-    )(n_wk[rows][:, None, :], n_k.reshape(1, -1), prior.reshape(1, -1))
-    return prob[:, 0], alias[:, 0], mass[:, 0, 0], dense[:, 0]
+        name=name,
+        interpret=backend.interpret(name, requested=interpret),
+    )(n_wk_rows, n_k.reshape(1, -1), prior.reshape(1, -1))
+    return prob[:r], alias[:r], mass[:r, 0], dense[:r]
+
+
+@functools.partial(jax.jit, static_argnames=("beta", "beta_bar", "tile_r",
+                                             "interpret"))
+def alias_build_fused(n_wk: jax.Array, n_k: jax.Array, prior: jax.Array, *,
+                      beta: float, beta_bar: float, tile_r: int | None = None,
+                      interpret: bool | None = None):
+    """Full build from raw statistics for every family whose dense term
+    factorizes as prior_e · LM row: (V, K) ``n_wk`` tiles are read straight
+    from HBM, one (tile_r, K) tile a program.  ``prior`` is the (K,)
+    per-topic prior-mass vector (α·1 for LDA, b1·θ0 for HDP); ``tile_r``
+    None ⇒ :func:`pick_tile_r`.  Returns (prob, alias, mass, dense) of
+    shapes (V, K)/(V, K)/(V,)/(V, K)."""
+    v, k = n_wk.shape
+    return _lm_build(n_wk, n_k, prior, beta=beta, beta_bar=beta_bar,
+                     tile_r=tile_r or pick_tile_r(k, v),
+                     name="alias_build_fused", interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("beta", "beta_bar", "tile_r",
+                                             "interpret"))
+def alias_build_gather_fused(n_wk: jax.Array, n_k: jax.Array,
+                             prior: jax.Array, rows: jax.Array, *,
+                             beta: float, beta_bar: float,
+                             tile_r: int | None = None,
+                             interpret: bool | None = None):
+    """Gather → the :func:`alias_build_fused` kernel over changed rows only.
+
+    ``rows`` is the (R,) int32 changed-row selection; XLA gathers those
+    statistics rows (R·K elements) and the kernel runs over them in
+    (tile_r, K) tiles, so each row is built exactly as the full build
+    builds it and partial and full rebuilds of the same statistics agree
+    bit-for-bit.  Returns compacted (prob, alias, mass, dense) rows of
+    shapes (R, K)/(R, K)/(R,)/(R, K) for the caller to scatter
+    (``repro.core.alias.update_rows``).
+    """
+    k = n_wk.shape[1]
+    return _lm_build(n_wk[rows], n_k, prior, beta=beta, beta_bar=beta_bar,
+                     tile_r=tile_r or pick_tile_r(k, rows.shape[0]),
+                     name="alias_build_gather_fused", interpret=interpret)

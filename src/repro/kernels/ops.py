@@ -22,16 +22,15 @@ def build_tables(p: jax.Array, *, tile_r: int = 8) -> AliasTable:
     return AliasTable(prob=prob, alias=alias, mass=mass)
 
 
-def build_tables_fused_lda(n_wk: jax.Array, n_k: jax.Array, *, alpha: float,
-                           beta: float, vocab_size: int, tile_r: int = 8
-                           ) -> tuple[AliasTable, jax.Array]:
-    """Fused dense-term + alias build; also returns the dense term mass-
-    consistent stale matrix (recomputed cheaply for MH point evaluation)."""
-    prob, alias, mass = _build.alias_build_fused(
-        n_wk, n_k, alpha=alpha, beta=beta, vocab_size=vocab_size,
-        tile_r=tile_r)
-    stale_dense = alpha * (n_wk + beta) / (n_k[None, :] + beta * vocab_size)
-    return AliasTable(prob=prob, alias=alias, mass=mass), stale_dense
+def build_tables_fused(n_wk: jax.Array, n_k: jax.Array, prior: jax.Array,
+                       *, beta: float, beta_bar: float
+                       ) -> tuple[AliasTable, jax.Array]:
+    """Full alias build from raw statistics for the LM-dense families
+    (prior_e · (n_wk+β)/(n_k+β̄)); also returns the dense term the kernel
+    computed, the stale matrix the sampler's MH correction reads."""
+    prob, alias, mass, dense = _build.alias_build_fused(
+        n_wk, n_k, prior, beta=beta, beta_bar=beta_bar)
+    return AliasTable(prob=prob, alias=alias, mass=mass), dense
 
 
 def build_tables_rows(p_rows: jax.Array, *, tile_r: int = 8) -> AliasTable:

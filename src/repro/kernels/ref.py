@@ -20,16 +20,18 @@ def alias_build_ref(p: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
     return t.prob, t.alias, t.mass
 
 
-def dense_probs_ref(n_wk: jax.Array, n_k: jax.Array, alpha: float,
-                    beta: float, vocab_size: int) -> jax.Array:
-    """Dense LDA proposal term α(n_wk+β)/(n_k+β̄) — fused into alias_build."""
-    beta_bar = beta * vocab_size
-    return alpha * (n_wk + beta) / (n_k[None, :] + beta_bar)
+def dense_probs_ref(n_wk: jax.Array, n_k: jax.Array, prior: jax.Array,
+                    beta: float, beta_bar: float) -> jax.Array:
+    """Dense term of the LM-dense families, prior_e·((n_wk+β)/(n_k+β̄)),
+    in the families' ``dense_probs`` order — fused into alias_build_fused."""
+    return prior[None, :] * ((n_wk + beta) / (n_k[None, :] + beta_bar))
 
 
-def alias_build_fused_ref(n_wk, n_k, alpha, beta, vocab_size):
-    """Oracle for the fused dense-term + alias-table build."""
-    return alias_build_ref(dense_probs_ref(n_wk, n_k, alpha, beta, vocab_size))
+def alias_build_fused_ref(n_wk, n_k, prior, beta, beta_bar):
+    """Oracle for the fused dense-term + alias-table build:
+    (prob, alias, mass, dense)."""
+    dp = dense_probs_ref(n_wk, n_k, prior, beta, beta_bar)
+    return (*alias_build_ref(dp), dp)
 
 
 def alias_sample_ref(prob: jax.Array, alias: jax.Array, rows: jax.Array,

@@ -16,11 +16,14 @@ Guards the API-unification contract:
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import alias as alias_mod
 from repro.core import family, hdp, projection
 from tests.conftest import make_family_cfg, make_synthetic_corpus
 
@@ -140,6 +143,35 @@ def test_dense_proposal_factorization_shapes(name):
     a = fam.accept_ratio(jnp.asarray(1.0), jnp.asarray(0.5),
                          jnp.asarray(0.25), jnp.asarray(0.75))
     assert float(a) == pytest.approx(1.0 - 0.5 + 0.25 - 0.75)
+
+
+@pytest.mark.parametrize("beta", [None, 0.0], ids=["beta", "zero_row"])
+@pytest.mark.parametrize("name", ["lda", "hdp"])
+def test_lm_build_alias_equals_reference(name, beta):
+    """The LM-dense families' full build (the fused row-tile kernel)
+    equals the plain reference, core.alias.build over dense_probs, bit for
+    bit — prob, alias, mass — and its stale matrix is dense_probs.  Row 0
+    holds no tokens; with β = 0 its dense row is all zero and takes the
+    uniform fallback."""
+    fam = family.get(name)
+    cfg = _cfg(name)
+    if beta is not None:
+        cfg = dataclasses.replace(cfg, beta=beta)
+    tokens, mask, _ = make_synthetic_corpus(8, 64, 24, 10, seed=3)
+    tokens = jnp.where(tokens == 0, 1, tokens)
+    _, shared = fam.init_state(cfg, tokens, mask, jax.random.PRNGKey(0))
+    assert bool(jnp.all(shared.n_k > 0))
+    dp = fam.dense_probs(cfg, shared)
+    want = alias_mod.build(dp)
+    tables, stale = fam.build_alias(cfg, shared)
+    for got, ref in zip((*tables, stale), (*want, dp)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    if beta == 0.0:
+        assert float(tables.mass[0]) == 0.0
+        np.testing.assert_array_equal(np.asarray(tables.alias[0]),
+                                      np.arange(cfg.n_topics))
+        np.testing.assert_array_equal(np.asarray(tables.prob[0]),
+                                      np.ones(cfg.n_topics))
 
 
 @pytest.mark.parametrize("name", ["lda", "pdp", "hdp"])

@@ -29,20 +29,34 @@ def test_alias_build_kernel_vs_ref(v, k, tile_r):
     np.testing.assert_allclose(implied_distribution(tr), target, atol=2e-5)
 
 
+def _lm_stats(key, v, k, dtype=jnp.float32):
+    n_wk = jnp.floor(jax.random.gamma(key, 1.0, (v, k)) * 4).astype(dtype)
+    n_wk = n_wk.at[1].set(0)          # a type with no tokens yet
+    prior = 0.05 + jax.random.uniform(jax.random.fold_in(key, 1), (k,))
+    return n_wk, n_wk.astype(jnp.float32).sum(0), prior
+
+
+def _assert_same(got, want):
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_alias_build_fused_kernel(dtype):
+    """The fused full build (dense term computed in-register from the raw
+    statistics, any input dtype) equals core.alias.build over the dense
+    term and writes that dense term as the stale snapshot, bit for bit."""
     v, k = 32, 16
-    n_wk = (jax.random.gamma(jax.random.PRNGKey(0), 1.0, (v, k)) * 10).astype(dtype)
-    n_k = n_wk.sum(0)
-    tabs, stale = ops.build_tables_fused_lda(
-        n_wk.astype(jnp.float32), n_k.astype(jnp.float32),
-        alpha=0.1, beta=0.01, vocab_size=v)
-    dp = ref.dense_probs_ref(n_wk.astype(jnp.float32), n_k.astype(jnp.float32),
-                             0.1, 0.01, v)
-    target = np.asarray(dp / dp.sum(-1, keepdims=True))
-    np.testing.assert_allclose(implied_distribution(tabs), target, atol=2e-5)
-    np.testing.assert_allclose(np.asarray(tabs.mass), np.asarray(dp.sum(-1)),
-                               rtol=1e-5)
+    n_wk, n_k, prior = _lm_stats(jax.random.PRNGKey(0), v, k, dtype)
+    kw = dict(beta=0.01, beta_bar=0.01 * v)
+    got = alias_build.alias_build_fused(n_wk, n_k, prior, **kw)
+    want = ref.alias_build_fused_ref(n_wk.astype(jnp.float32), n_k, prior,
+                                     **kw)
+    _assert_same(got, want)
+    target = np.asarray(want[3] / want[3].sum(-1, keepdims=True))
+    np.testing.assert_allclose(
+        implied_distribution(alias_mod.AliasTable(*got[:3])), target,
+        atol=2e-5)
 
 
 @pytest.mark.parametrize("v,k,b,tile_v,tile_b", [
@@ -105,17 +119,31 @@ def test_alias_build_tile_k_bitexact(tile_k):
         assert bool(jnp.all(a == b))
 
 
-@pytest.mark.parametrize("tile_k", [4, 8, 16])
-def test_alias_build_fused_tile_k_bitexact(tile_k):
-    """The fused LDA-term build stages *raw* n_wk/n_k tiles and computes
-    the dense term on full-K scratch — so XLA cannot round the
-    elementwise term differently per block shape (the 1-ulp trap)."""
-    v, k = 32, 16
-    key = jax.random.PRNGKey(11)
-    n_wk = jnp.floor(jax.random.gamma(key, 1.0, (v, k)) * 4)
-    n_k = n_wk.sum(0)
-    kw = dict(alpha=0.1, beta=0.01, vocab_size=v, tile_r=8)
-    want = alias_build.alias_build_fused(n_wk, n_k, **kw)
-    got = alias_build.alias_build_fused(n_wk, n_k, tile_k=tile_k, **kw)
-    for a, b in zip(want, got):
-        assert bool(jnp.all(a == b))
+@pytest.mark.parametrize("tile_r", [8, 16, 32])
+def test_alias_build_fused_tile_r_bitexact(tile_r):
+    """Full and gathered builds at every row tile equal the reference bit
+    for bit — so equal each other — with V a multiple of no tile but 8
+    (the last tile is padded) and an all-zero dense row (β = 0, a type
+    with no tokens), which takes the uniform fallback."""
+    v, k = 40, 16
+    n_wk, n_k, prior = _lm_stats(jax.random.PRNGKey(11), v, k)
+    kw = dict(beta=0.0, beta_bar=0.0)
+    want = ref.alias_build_fused_ref(n_wk, n_k, prior, **kw)
+    _assert_same(alias_build.alias_build_fused(n_wk, n_k, prior,
+                                               tile_r=tile_r, **kw), want)
+    rows = jax.random.permutation(jax.random.PRNGKey(12), v)[:27]
+    got = alias_build.alias_build_gather_fused(n_wk, n_k, prior, rows,
+                                               tile_r=tile_r, **kw)
+    _assert_same(got, [w[rows] for w in want])
+    assert float(want[2][1]) == 0.0
+    np.testing.assert_array_equal(np.asarray(want[0][1]), np.ones(k))
+    np.testing.assert_array_equal(np.asarray(want[1][1]), np.arange(k))
+
+
+@pytest.mark.parametrize("k,rows,tile_r", [
+    (1024, 131072, 64), (1024, 64, 64), (1024, 20, 24), (128, 131072, 512),
+    (16, 40, 40), (16, 3, 8), (4096, 131072, 16), (8192, 64, 8),
+])
+def test_pick_tile_r(k, rows, tile_r):
+    """Row tile from K (lanes padded to 128) and the rows to build."""
+    assert alias_build.pick_tile_r(k, rows) == tile_r

@@ -104,8 +104,19 @@ def test_pdp_sweep_fused_lowers(one_chip):
         interpret=False), "pdp_sweep_fused")
 
 
+def test_alias_build_fused_lowers(one_chip):
+    """The LM-dense full alias build over the whole (V, K) table at the
+    row tile ``pick_tile_r`` gives K=1024."""
+    args = _shapes(one_chip, ((V, K), jnp.float32), ((K,), jnp.float32),
+                   ((K,), jnp.float32))
+    _assert_lowered(alias_build.alias_build_fused.lower(
+        *args, beta=0.01, beta_bar=0.01 * V, interpret=False),
+        "alias_build_fused")
+
+
 def test_alias_build_gather_fused_lowers(one_chip):
-    """The incremental alias rebuild of 64 changed rows at K=1024."""
+    """The incremental alias rebuild of 64 changed rows at K=1024, on the
+    full build's row tiles."""
     args = _shapes(one_chip, ((V, K), jnp.float32), ((K,), jnp.float32),
                    ((K,), jnp.float32), ((64,), jnp.int32))
     _assert_lowered(alias_build.alias_build_gather_fused.lower(
