@@ -333,11 +333,14 @@ class Trainer:
                 if c in local_set else None
                 for c, (t, m) in enumerate(self.shards))
         # Static counters of the fused sweep kernel's grid over one round's
-        # chunks: the steps it walks, and the (batch tile, vocab tile)
-        # pairs among them that hold draws.
+        # chunks: the steps it walks, the (batch tile, vocab tile) pairs
+        # among them that hold draws, and the single-pass column blocks a
+        # live step's staging contracts its one-hot with.
         self.sweep_grid_steps = self.sweep_live_pairs = 0
+        self.sweep_stage_parts = 0
         if self.layouts is not None:
             e = self.family.n_outcomes(model_cfg)
+            self.sweep_stage_parts = mhw_fused.stage_parts(e)
             n_ktiles = e // min(self.family.sorted_tile_k(model_cfg) or e, e)
             lays = [lay for ls in self.layouts if ls is not None
                     for lay in ls]

@@ -26,11 +26,12 @@ vocab tile, last K tile — so the pipeline issues no copy for them, and
 ``pl.when`` skips their body.
 
 Every data-dependent read is written in a form Mosaic lowers: the per-token
-table rows are a one-hot (TILE_B, TILE_V) × (TILE_V, tile_k) MXU
-contraction at HIGHEST precision, and every lane read inside the chain is a
-one-hot select-and-sum (``mhw._gather_k``).  Both are exact — one non-zero
-term per output — so interpreted (CPU) and lowered (TPU) kernels read the
-same values the oracles gather.
+table rows are one single-pass bfloat16 MXU contraction of a one-hot
+(TILE_B, TILE_V) matrix with exact bfloat16 parts of the table blocks
+(:func:`_stage_rows`), and every lane read inside the chain is a one-hot
+select-and-sum (``mhw._gather_k``).  Both are exact — one non-zero term per
+output — so interpreted (CPU) and lowered (TPU) kernels read the same
+values the oracles gather.
 
 Two kernels instantiate the ``ModelFamily`` dense-proposal factorization
 (p(e) ∝ (doc_e + prior_e)·f_e, see ``repro.core.mhw``):
@@ -76,8 +77,39 @@ _VMEM_CEIL = 100 * 2 ** 20
 
 
 def _vmem_limit(tile_b: int, e: int, tile_v: int, tile_k: int) -> int:
-    need = 4 * (_LIVE_ROWS * tile_b * e + 16 * tile_v * tile_k)
+    # + the staging contraction: its bfloat16 one-hot and parts block and
+    # its float32 product.
+    parts = stage_parts(e)
+    need = (4 * (_LIVE_ROWS * tile_b * e + 16 * tile_v * tile_k)
+            + 2 * tile_v * (tile_b + parts * tile_k)
+            + 4 * parts * tile_b * tile_k)
     return int(min(max(2 * need, _VMEM_FLOOR), _VMEM_CEIL))
+
+
+# What a live step stages: three float32 tables (n_wk or PDP's log factors,
+# stale, prob), then the int32 alias indices.
+_STAGED_DTYPES = (jnp.float32,) * 3 + (jnp.int32,)
+
+
+def _n_parts(dtype, e: int) -> int:
+    """Exact bfloat16 parts a table block of ``dtype`` splits into: three
+    for a float, and for integer indices below ``e`` one base-256 digit
+    per 8 bits of e − 1 (two up to e = 65,536)."""
+    if jnp.issubdtype(dtype, jnp.floating):
+        return 3
+    assert e <= 2 ** 24, f"E={e}: indices past 2^24 are not exact in f32"
+    return max(1, -(-(e - 1).bit_length() // 8))
+
+
+def _check_staged(*tables):
+    assert tuple(t.dtype for t in tables) == _STAGED_DTYPES, \
+        [t.dtype for t in tables]
+
+
+def stage_parts(e: int) -> int:
+    """Single-pass (tile_v, tile_k) column blocks one live step contracts
+    its one-hot with, over E outcomes: 11 at E = 1024 or 2048."""
+    return sum(_n_parts(dt, e) for dt in _STAGED_DTYPES)
 
 
 def n_pairs(nb: int, nv: int) -> int:
@@ -158,15 +190,54 @@ def _tile_onehot(rows, row_lo, tile_v: int):
     return in_tile, lane == local[:, None]
 
 
-def _gather_rows(onehot, tile):
-    """Rows of a resident (TILE_V, C) table tile picked by a one-hot
-    (TILE_B, TILE_V) matrix: an MXU contraction with one non-zero product
-    per output, exact in f32 at HIGHEST precision (integer tables are
-    carried as exact f32 and cast back)."""
-    out = jnp.dot(onehot.astype(jnp.float32), tile.astype(jnp.float32),
-                  precision=jax.lax.Precision.HIGHEST,
+def _bf16_parts(block, e: int):
+    """A table block as :func:`_n_parts` float32 values, each exact in
+    bfloat16.  A float's hi and mid parts are the top 8 significant bits
+    (the bits a bfloat16 keeps, cut by a mask, not rounded) of what the
+    parts before them leave; lo, the rest, has at most 8.  An integer
+    block's parts are its base-256 digits, lowest first."""
+    if jnp.issubdtype(block.dtype, jnp.floating):
+        x, parts = block, []
+        for _ in range(2):
+            bits = jax.lax.bitcast_convert_type(x, jnp.int32) & -65536
+            parts.append(jax.lax.bitcast_convert_type(bits, jnp.float32))
+            x = x - parts[-1]
+        return parts + [x]
+    return [((block >> (8 * i)) & 255).astype(jnp.float32)
+            for i in range(_n_parts(block.dtype, e))]
+
+
+def _stage_rows(onehot, blocks, e: int):
+    """Rows of resident (TILE_V, tile_k) table blocks picked by a one-hot
+    (TILE_B, TILE_V) bool matrix, in one single-pass MXU contraction.
+
+    The bfloat16 one-hot meets every block's exact bfloat16 parts
+    (:func:`_bf16_parts`, integer blocks holding indices below ``e``) side
+    by side, accumulating in float32.  Each output has one non-zero
+    product, so every part comes back exactly, and ``(hi + mid) + lo`` or
+    the digits' Σ 256^i·d_i rebuilds the block's rows bit for bit: zero and
+    every finite float32 of magnitude ≥ 2^-100 (below that lo leaves
+    bfloat16's normal range), and every index below ``e``.  Returns the
+    rows of each block, (TILE_B, tile_k) in its dtype."""
+    parts = [_bf16_parts(blk, e) for blk in blocks]
+    rhs = jnp.concatenate([p.astype(jnp.bfloat16) for ps in parts
+                           for p in ps], axis=1)
+    out = jnp.dot(onehot.astype(jnp.bfloat16), rhs,
                   preferred_element_type=jnp.float32)
-    return out.astype(tile.dtype)
+    tile_k = blocks[0].shape[1]
+    rows, col = [], 0
+    for blk, ps in zip(blocks, parts):
+        cols = [out[:, (col + i) * tile_k:(col + i + 1) * tile_k]
+                for i in range(len(ps))]
+        col += len(ps)
+        if jnp.issubdtype(blk.dtype, jnp.floating):
+            rows.append((cols[0] + cols[1]) + cols[2])
+        else:
+            acc = cols[-1]
+            for c in cols[-2::-1]:
+                acc = acc * 256.0 + c
+            rows.append(acc.astype(blk.dtype))
+    return rows
 
 
 def _dense_mass(onehot, mass_row):
@@ -198,10 +269,11 @@ def _mhw_fused_kernel(pb_ref, pt_ref, live_ref, first_ref, rows_ref, z_ref,
         # same gathered rows concatenate to exactly the rows the untiled
         # kernel gathers, so tiling cannot perturb the chain.
         ksl = pl.ds(ki * tile_k, tile_k)
-        nwk_s[:, ksl] = _gather_rows(onehot, nwk_ref[...])
-        stale_s[:, ksl] = _gather_rows(onehot, stale_ref[...])
-        prob_s[:, ksl] = _gather_rows(onehot, prob_ref[...])
-        alias_s[:, ksl] = _gather_rows(onehot, alias_ref[...])
+        staged = _stage_rows(onehot, (nwk_ref[...], stale_ref[...],
+                                      prob_ref[...], alias_ref[...]),
+                             alias_s.shape[-1])
+        for s_ref, rows in zip((nwk_s, stale_s, prob_s, alias_s), staged):
+            s_ref[:, ksl] = rows
 
     @pl.when(live & (ki == n_ktiles - 1))
     def _body():
@@ -284,6 +356,7 @@ def mhw_sweep_fused(prob: jax.Array, alias: jax.Array, mass: jax.Array,
     v, k = prob.shape
     b = rows.shape[0]
     tile_v, tile_b, tile_k = _check_tiles(v, k, b, tile_v, tile_b, tile_k)
+    _check_staged(n_wk, stale, prob, alias)
     nb, nv, nk = b // tile_b, v // tile_v, k // tile_k
     assert vstart.shape == (nb,) and vcount.shape == (nb,)
     if beta_bar is None:
@@ -360,10 +433,11 @@ def _pdp_fused_kernel(pb_ref, pt_ref, live_ref, first_ref, rows_ref, e_ref,
     def _stage():
         # The (V, 2K) joint-outcome tables stream one e-tile per step.
         esl = pl.ds(ei * tile_k, tile_k)
-        logf_s[:, esl] = _gather_rows(onehot, logf_ref[...])
-        stale_s[:, esl] = _gather_rows(onehot, stale_ref[...])
-        prob_s[:, esl] = _gather_rows(onehot, prob_ref[...])
-        alias_s[:, esl] = _gather_rows(onehot, alias_ref[...])
+        staged = _stage_rows(onehot, (logf_ref[...], stale_ref[...],
+                                      prob_ref[...], alias_ref[...]),
+                             alias_s.shape[-1])
+        for s_ref, rows in zip((logf_s, stale_s, prob_s, alias_s), staged):
+            s_ref[:, esl] = rows
 
     @pl.when(live & (ei == n_etiles - 1))
     def _body():
@@ -446,6 +520,7 @@ def pdp_sweep_fused(prob: jax.Array, alias: jax.Array, mass: jax.Array,
     log_f = fresh_log_factors(stirl, m_wk, s_wk, m_k, s_k, **hyper)
     own_f0, own_f1 = own_log_factors(stirl, m_wk, s_wk, m_k, s_k, rows, e0,
                                      **hyper)
+    _check_staged(log_f, stale, prob, alias)
 
     kernel = functools.partial(_pdp_fused_kernel, tile_v=tile_v,
                                tile_k=tile_k, n_etiles=ne, fold_in=fold_in)

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from repro.core import family, lda, mhw, pdp, stirling
 from repro.data import segment
@@ -42,13 +43,14 @@ def _windows(rows, v, tile_v, tile_b):
     return jnp.asarray(vstart), jnp.asarray(vcount)
 
 
-def _case(*dims, tile_k=None, fold_in=None):
+def _case(*dims, tile_k=None, fold_in=None, tables=None):
     """A fused-kernel case: its dims, then ``tile_k``, then ``fold_in``
-    where the test takes it.  Named by its dims, plus ``tk<n>`` and
-    ``fold_in`` when set, so the untiled training cases keep their ids."""
-    flags = () if fold_in is None else (fold_in,)
+    and the kind of statistics (``tables``, "gamma" unless set) where the
+    test takes them.  Named by its dims, plus ``tk<n>``, ``fold_in`` and
+    ``tables`` when set, so the untiled training cases keep their ids."""
+    flags = () if fold_in is None else (fold_in, tables or "gamma")
     tags = [str(d) for d in dims] + ([f"tk{tile_k}"] if tile_k else []) \
-        + (["fold_in"] if fold_in else [])
+        + (["fold_in"] if fold_in else []) + ([tables] if tables else [])
     return pytest.param(*dims, tile_k, *flags, id="-".join(tags))
 
 
@@ -125,9 +127,71 @@ def test_work_list(case, b, tile_b):
             prev = blk
 
 
+def _stage_case(kind, key, shape):
+    """(blocks, E) for a staging case: table blocks as a live step holds
+    them, and the outcome count that bounds integer (alias) entries."""
+    u = jax.random.uniform(key, shape)
+    sign = jnp.where(jax.random.bernoulli(jax.random.fold_in(key, 1), 0.5,
+                                          shape), -1.0, 1.0)
+    zero = jax.random.bernoulli(jax.random.fold_in(key, 2), 0.1, shape)
+    if kind == "f32-signed-wide":      # both signs over 2^-100 … 2^100
+        mant = 1.0 + jax.random.uniform(jax.random.fold_in(key, 3), shape)
+        x = sign * mant * jnp.exp2(jnp.round(u * 200.0 - 100.0))
+        return [jnp.where(zero, 0.0, x)], 1024
+    if kind == "counts-2^24":          # integer-valued counts up to 2^24 − 1
+        x = jnp.floor(u * 2.0 ** 24)
+        return [x.at[0, :2].set(jnp.array([2.0 ** 24 - 1, 0.0]))], 1024
+    if kind == "stale-1e-7":           # the stale table's magnitudes
+        return [u * 2e-7 + 1e-9], 1024
+    if kind.startswith("alias-E"):     # indices up to E − 1
+        e = int(kind[len("alias-E"):])
+        x = jnp.floor(u * e).astype(jnp.int32)
+        return [x.at[0, 0].set(e - 1)], e
+    if kind == "pdp-log-factors":      # negative finite log factors
+        return [-jnp.exp(u * 30.0 - 20.0)], 2048
+    assert kind == "live-step"         # n_wk, stale, prob, alias together
+    ks = jax.random.split(key, 4)
+    return [jnp.floor(jax.random.gamma(ks[0], 0.3, shape) * 1e4),
+            jax.random.uniform(ks[1], shape) * 1e-6,
+            jax.random.uniform(ks[2], shape),
+            jax.random.randint(ks[3], shape, 0, 1024, jnp.int32)], 1024
+
+
+@pytest.mark.parametrize("kind", [
+    "f32-signed-wide", "counts-2^24", "stale-1e-7", "alias-E1024",
+    "alias-E2048", "pdp-log-factors", "live-step"])
+def test_stage_rows_exact(kind):
+    """A live step's single-pass bf16 staging returns ``table[rows]`` bit
+    for bit: each output has one non-zero product and each bf16 part is
+    exact.  Rows outside the vocab tile stage zeros."""
+    tile_v, c, b = 64, 16, 48
+    key = jax.random.PRNGKey(len(kind))
+    blocks, e = _stage_case(kind, key, (tile_v, c))
+    rows = jax.random.randint(jax.random.fold_in(key, 7), (b,), 0, tile_v,
+                              jnp.int32).at[:3].set(jnp.array([0, 63, 64]))
+
+    def kernel(rows_ref, *refs):
+        _, onehot = mhw_fused._tile_onehot(rows_ref[0], 0, tile_v)
+        staged = mhw_fused._stage_rows(
+            onehot, [r[...] for r in refs[:len(blocks)]], e)
+        for o_ref, got in zip(refs[len(blocks):], staged):
+            o_ref[...] = got
+
+    outs = pl.pallas_call(
+        kernel, interpret=True,
+        out_shape=[jax.ShapeDtypeStruct((b, c), x.dtype) for x in blocks],
+    )(rows.reshape(1, b), *blocks)
+    in_tile = np.asarray(rows < tile_v)[:, None]
+    for blk, got in zip(blocks, outs):
+        want = np.where(in_tile, np.asarray(blk)[np.minimum(rows, 63)], 0)
+        assert got.dtype == blk.dtype
+        np.testing.assert_array_equal(np.asarray(got).view(np.int32),
+                                      want.astype(blk.dtype).view(np.int32))
+
+
 @pytest.mark.parametrize("prior_kind", ["lda", "hdp"])
 @pytest.mark.parametrize("v,k,b,tile_v,tile_b,lo,hi,n_pad,steps,tile_k,"
-                         "fold_in", [
+                         "fold_in,tables", [
     _case(60, 16, 384, 12, 128, 0, 60, 0, 2, fold_in=False),
     # most vocab tiles empty
     _case(120, 32, 256, 12, 64, 24, 60, 0, 3, fold_in=False),
@@ -136,15 +200,22 @@ def test_work_list(case, b, tile_b):
     _case(240, 32, 512, 12, 64, 24, 60, 200, 2, tile_k=8, fold_in=False),
     # serving: folded-in documents, own token removed from ndk only
     _case(120, 32, 256, 12, 64, 24, 60, 70, 3, tile_k=16, fold_in=True),
+    # integer-valued counts near 2^24 and a stale table near 1e-7: the
+    # widest values the staging's bf16 parts must carry exactly
+    _case(120, 32, 256, 12, 64, 24, 60, 0, 2, tile_k=16, fold_in=False,
+          tables="counts-2^24"),
 ])
 def test_mhw_fused_kernel_vs_oracle(v, k, b, tile_v, tile_b, lo, hi, n_pad,
-                                    steps, tile_k, fold_in, prior_kind):
+                                    steps, tile_k, fold_in, tables,
+                                    prior_kind):
     """The fused draw+accept kernel is bit-identical to mhw.sorted_chain —
     with the uniform LDA prior α·1 and a non-uniform HDP prior b1·θ0."""
     key = jax.random.PRNGKey(v * k + b)
     alpha, beta = 0.1, 0.01
     beta_bar = beta * v
     n_wk = jax.random.gamma(key, 1.0, (v, k)) * 5
+    if tables == "counts-2^24":
+        n_wk = jnp.floor(2.0 ** 24 - 1 - n_wk * 1e3)
     n_k = n_wk.sum(0)
     lm = (n_wk + beta) / (n_k[None, :] + beta_bar)
     if prior_kind == "lda":
@@ -154,6 +225,8 @@ def test_mhw_fused_kernel_vs_oracle(v, k, b, tile_v, tile_b, lo, hi, n_pad,
                                       jnp.ones((k,)))
         prior = 2.0 * theta0
     stale = prior[None, :] * lm
+    if tables == "counts-2^24":
+        stale = stale * (1e-7 / jnp.mean(stale))
     tabs = ops.build_tables(stale, tile_r=segment.pick_tile(v, 8))
 
     rows = _sorted_rows(jax.random.fold_in(key, 1), b, lo, hi, v, n_pad)

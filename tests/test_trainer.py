@@ -20,6 +20,7 @@ import pytest
 from repro.core import ps
 from repro.core.fault import FaultPlan
 from repro.engine import Trainer, TrainerConfig
+from repro.kernels import mhw_fused
 from tests.conftest import make_family_cfg, make_synthetic_corpus
 
 VOCAB = 96
@@ -149,8 +150,23 @@ def test_trainer_sweep_grid_counters(name, corpus):
     assert trainer.sweep_grid_steps == steps
     assert trainer.sweep_live_pairs == live
     assert 0 < live <= steps // n_ktiles
+    # three float32 tables in hi/mid/lo parts, and the alias indices below
+    # E in base-256 digits (one at this E)
+    assert trainer.family.n_outcomes(cfg) <= 256
+    assert trainer.sweep_stage_parts == 3 * 3 + 1
     scan = Trainer(cfg, tokens, mask, config=TrainerConfig(n_clients=2))
     assert scan.sweep_grid_steps == scan.sweep_live_pairs == 0
+    assert scan.sweep_stage_parts == 0
+
+
+@pytest.mark.parametrize("e,parts", [(16, 10), (256, 10), (257, 11),
+                                     (1024, 11), (2048, 11), (65536, 11),
+                                     (65537, 12)])
+def test_sweep_stage_parts(e, parts):
+    """``Trainer.sweep_stage_parts``'s formula: 3 parts for each of a live
+    step's three float32 tables, one base-256 digit per 8 bits of E − 1
+    for the alias: 11 for LDA and HDP at K = 1024 and PDP's 2K = 2048."""
+    assert mhw_fused.stage_parts(e) == parts
 
 
 def test_trainer_rejects_bad_config(corpus):
